@@ -19,6 +19,7 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from repro.analysis.violations import Violation
@@ -123,8 +124,8 @@ RULES: Dict[str, Rule] = {
         Rule(
             "HALO001",
             "halo-consistency",
-            "window reach derived from the compiled index map equals "
-            "OperatorSpec.radius (+1 under NMS) equals the sharded "
+            "every input window the compiled Element index map reads covers "
+            "OperatorSpec.radius (+1 under NMS), which equals the sharded "
             "exchange width (tiling.window_radius is the single source)",
             "PR 4 / PR 8",
         ),
@@ -297,7 +298,7 @@ def check_contraction_fences(jaxpr, *, location: str) -> List[Violation]:
                 eqn.outvars[0]
             ):
                 for iv in eqn.invars:
-                    p = producers.get(iv) if isinstance(iv, jax.core.Var) else None
+                    p = producers.get(iv) if isinstance(iv, jax_core.Var) else None
                     if p is not None and p.primitive.name == "mul" and _is_float(iv):
                         out.append(
                             Violation(
@@ -447,7 +448,7 @@ def _pipeline_scratch(pc) -> Tuple[Optional[object], Optional[object]]:
     ]
     if not sems or not rings:
         return None, None
-    ring = max(rings, key=lambda a: a.shape[2])
+    ring = max(rings, key=lambda a: a.shape[-1])
     return ring, sems[0]
 
 
@@ -574,6 +575,16 @@ def _eval_index_map(bm, grid_indices: Tuple[int, ...]) -> List[int]:
     return [int(o) for o in out]
 
 
+def _element_window(bm) -> Optional[Tuple[int, int]]:
+    """(tile_h, tile_w) of a halo'd input window — a BlockSpec whose block
+    dims are all ``pl.Element`` (element-offset index map) over an
+    ``(N, [C,] H, W)`` array — or ``None`` for any other block mapping."""
+    dims = tuple(bm.block_shape)
+    if len(dims) < 3 or not all(type(d).__name__ == "Element" for d in dims):
+        return None
+    return dims[-2].block_size, dims[-1].block_size
+
+
 def check_halo_window(
     jaxpr,
     *,
@@ -583,30 +594,36 @@ def check_halo_window(
     block_h: int,
     block_w: int,
     image_hw: Optional[Tuple[int, int]] = None,
-    align: Tuple[int, int] = (1, 1),
     plan=None,
 ) -> List[Violation]:
-    """HALO001: the halo the kernel *compiled with* — recovered by
-    evaluating its Unblocked BlockSpec index map at an interior grid
-    point — equals ``window_radius(spec.radius, nms)`` (with ``plan``:
+    """HALO001: every grid step's input window — recovered by evaluating
+    its ``pl.Element`` index map — covers the block plus
+    ``window_radius(spec.radius, nms)`` (with ``plan``:
     ``window_radius(plan.linear_reach, plan.nms)``, the composed reach of
-    the fused stage chain), and the sharded halo exchange is sized
-    identically.
+    the fused stage chain) clipped to the image, and the sharded halo
+    exchange is sized identically.
 
-    At interior grid step (k, j) = (1, 1) the clamp in
-    :func:`repro.kernels.tiling.window_origin` is inactive, so
-    ``row0 = block_h - r`` and the reach falls straight out of the index
-    map. Requires a grid of at least 3×3 blocks (AnalysisError otherwise:
-    that is a misconfigured sweep, not an engine bug).
+    Windows are tile-aligned, so they may reach further than the stencil;
+    the reported reach is the margin around interior grid step
+    (k, j) = (1, 1), and the first/last steps are probed too, where the
+    clamp in :func:`repro.kernels.tiling.window_origin` is active.
+    Requires a grid of at least 3×3 blocks (AnalysisError otherwise: that
+    is a misconfigured sweep, not an engine bug).
     """
     from repro.kernels.tiling import window_radius, window_shape
     from repro.sharding import halo as halo_mod
 
     if plan is not None:
         expected = window_radius(plan.linear_reach, nms or plan.nms)
+        src = f"linear_reach={plan.linear_reach}, nms={nms or plan.nms}"
     else:
         expected = window_radius(spec.radius, nms)
+        src = f"radius={spec.radius}, nms={nms}"
     out: List[Violation] = []
+
+    def vio(message, *detail):
+        out.append(Violation("HALO001", location, message, detail=detail))
+
     for pc in find_pallas_eqns(jaxpr):
         gm = pc.params["grid_mapping"]
         grid = tuple(gm.grid)
@@ -619,102 +636,68 @@ def check_halo_window(
             )
         windows = 0
         for bm in gm.block_mappings:
-            if type(bm.indexing_mode).__name__ != "Unblocked":
-                continue
-            shape = tuple(bm.block_shape)
-            if len(shape) < 3 or shape[1] <= block_h:
+            tile = _element_window(bm)
+            if tile is None or tile[0] <= block_h:
                 continue  # not a halo'd input window
             windows += 1
+            th, tw = tile
+            h, w = bm.array_aval.shape[-2:]
             offs = _eval_index_map(bm, (0, 1, 1))
-            r_h = block_h - offs[1]
-            r_w = block_w - offs[2]
-            if r_h != expected or r_w != expected:
-                src = (f"linear_reach={plan.linear_reach}, nms={nms or plan.nms}"
-                       if plan is not None else f"radius={spec.radius}, nms={nms}")
-                out.append(
-                    Violation(
-                        "HALO001",
-                        location,
-                        f"kernel window reach ({r_h}, {r_w}) != "
-                        f"window_radius({src}) "
-                        f"= {expected}",
-                        detail=(
-                            ("derived", f"({r_h}, {r_w})"),
-                            ("expected", str(expected)),
-                        ),
-                    )
-                )
+            row0, col0 = offs[-2], offs[-1]
+            r_h = min(block_h - row0, row0 + th - 2 * block_h)
+            r_w = min(block_w - col0, col0 + tw - 2 * block_w)
+            if r_h < expected or r_w < expected:
+                vio(f"kernel window reach ({r_h}, {r_w}) < "
+                    f"window_radius({src}) = {expected}",
+                    ("derived", f"({r_h}, {r_w})"),
+                    ("expected", str(expected)))
                 continue
+            for k, j in ((0, 0), (grid[1] - 1, grid[2] - 1)):
+                offs = _eval_index_map(bm, (0, k, j))
+                row0, col0 = offs[-2], offs[-1]
+                need_r = (max(k * block_h - expected, 0),
+                          min((k + 1) * block_h + expected, h))
+                need_c = (max(j * block_w - expected, 0),
+                          min((j + 1) * block_w + expected, w))
+                if not (row0 <= need_r[0] and need_r[1] <= row0 + th
+                        and col0 <= need_c[0] and need_c[1] <= col0 + tw):
+                    vio(f"window at grid step ({k}, {j}) "
+                        f"[{row0}:{row0 + th}, {col0}:{col0 + tw}] misses "
+                        f"the r={expected} stencil rows {need_r} / cols "
+                        f"{need_c}",
+                        ("step", f"({k}, {j})"),
+                        ("expected", str(expected)))
             if image_hw is not None:
-                th, tw = window_shape(
-                    image_hw[0],
-                    image_hw[1],
-                    block_h,
-                    block_w,
-                    expected,
-                    align=align,
-                )
-                if (shape[1], shape[2]) != (th, tw):
-                    out.append(
-                        Violation(
-                            "HALO001",
-                            location,
-                            f"window tile {(shape[1], shape[2])} != "
-                            f"window_shape(...) = {(th, tw)} for r={expected}",
-                            detail=(
-                                ("tile", str((shape[1], shape[2]))),
-                                ("expected", str((th, tw))),
-                            ),
-                        )
-                    )
+                want = window_shape(image_hw[0], image_hw[1], block_h,
+                                    block_w, expected)
+                if tile != want:
+                    vio(f"window tile {tile} != window_shape(...) = {want} "
+                        f"for r={expected}",
+                        ("tile", str(tile)), ("expected", str(want)))
         if not windows:
             # Manual-DMA kernels take their input as an opaque ANY-space
-            # ref (no Unblocked window to probe); the halo geometry is
-            # baked into the copy ring instead: each slot holds exactly
-            # one window_shape(...) tile, so the ring's trailing dims
-            # carry the compiled reach.
+            # ref (no Element window to probe); the halo geometry is baked
+            # into the copy ring instead: each slot holds exactly one
+            # window_shape(...) tile, so the ring's trailing dims carry the
+            # compiled reach.
             ring, _sem = _pipeline_scratch(pc)
             if ring is None:
-                out.append(
-                    Violation(
-                        "HALO001",
-                        location,
-                        "no halo'd Unblocked input window (and no DMA ring) "
-                        "on the pallas_call — the stencil cannot be reading "
-                        "its halo",
-                        detail=(("windows", "0"),),
-                    )
-                )
+                vio("no halo'd Element input window (and no DMA ring) on the "
+                    "pallas_call — the stencil cannot be reading its halo",
+                    ("windows", "0"))
             elif image_hw is not None:
-                th, tw = window_shape(
-                    image_hw[0], image_hw[1], block_h, block_w, expected,
-                    align=align,
-                )
-                got = tuple(ring.shape[1:3])
-                if got != (th, tw):
-                    out.append(
-                        Violation(
-                            "HALO001",
-                            location,
-                            f"DMA ring slot tile {got} != window_shape(...) "
-                            f"= {(th, tw)} for r={expected}",
-                            detail=(
-                                ("tile", str(got)),
-                                ("expected", str((th, tw))),
-                            ),
-                        )
-                    )
+                want = window_shape(image_hw[0], image_hw[1], block_h,
+                                    block_w, expected)
+                got = tuple(ring.shape[-2:])
+                if got != want:
+                    vio(f"DMA ring slot tile {got} != window_shape(...) "
+                        f"= {want} for r={expected}",
+                        ("tile", str(got)), ("expected", str(want)))
         exch = halo_mod.exchange_radius(spec, nms, plan=plan)
         if exch != expected:
-            out.append(
-                Violation(
-                    "HALO001",
-                    location,
-                    f"sharded exchange width {exch} != kernel window radius "
-                    f"{expected}",
-                    detail=(("exchange", str(exch)), ("expected", str(expected))),
-                )
-            )
+            vio(f"sharded exchange width {exch} != kernel window radius "
+                f"{expected}",
+                ("exchange", str(exch)), ("expected", str(expected)))
     return out
 
 

@@ -186,7 +186,6 @@ def _combo_violations(
                 block_h=TRACE_BLOCK[0],
                 block_w=TRACE_BLOCK[1],
                 image_hw=TRACE_SHAPE[1:],
-                align=(1, 1),
             )
             out += rules.check_vmem_budget(
                 location=location,
@@ -245,7 +244,6 @@ def _plan_violations(
             block_h=TRACE_BLOCK[0],
             block_w=TRACE_BLOCK[1],
             image_hw=TRACE_SHAPE[1:],
-            align=(1, 1),
             plan=plan,
         )
         out += rules.check_vmem_budget(

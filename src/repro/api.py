@@ -341,7 +341,7 @@ class StreamState:
         thin magnitude when ``nms``, else the magnitude): the splice source
         for delta-skipped tiles.
       * ``bmax``    — the previous per-block maxima ``(B, gh, gw)``: cached
-        SMEM output of the fused kernel, spliced per-tile so the global
+        block-max output of the fused kernel, spliced per-tile so the global
         peak (normalization + hysteresis thresholds) stays exact.
       * ``seed``    — the temporal seed-strength map (``config.temporal``;
         ``None`` otherwise): 1.0 at last frame's edges, geometrically

@@ -50,13 +50,7 @@ from repro.core.sobel import magnitude as rss_magnitude
 from repro.core.sobel import sobel_components as core_components
 from repro.kernels import edge as ekern
 from repro.kernels import tuning
-from repro.kernels.tiling import (
-    ALIGN_INTERPRET,
-    ALIGN_TPU_GRAY,
-    ALIGN_TPU_RGB,
-    window_radius,
-    window_shape,
-)
+from repro.kernels.tiling import window_radius
 
 if TYPE_CHECKING:  # no runtime import: repro.api imports this module
     from repro.api import EdgeConfig, EdgeResult, StreamState
@@ -557,42 +551,33 @@ def stream_block_shape(
     return bh, bw
 
 
-def _stream_align(backend: str, rgb: bool) -> Tuple[int, int]:
-    if backend == "pallas-tpu":
-        return ALIGN_TPU_RGB if rgb else ALIGN_TPU_GRAY
-    return ALIGN_INTERPRET
-
-
 def _block_reduce_max(x: jnp.ndarray, bh: int, bw: int) -> jnp.ndarray:
     """(B, H, W) -> (B, gh, gw) per-tile max (ragged tails are partial
-    windows). Identical values to the kernel's masked SMEM maxima because
-    the magnitude is non-negative and max is exact."""
+    windows). Identical values to the kernel's masked block maxima because
+    the magnitude is non-negative and max is exact.
+
+    A reshape and a max, not ``reduce_window``: XLA:TPU stages a
+    ``reduce_window`` over 64x256 windows in scoped VMEM and runs out of it
+    at 2 x 2048^2."""
     b, h, w = x.shape
     gh, gw = -(-h // bh), -(-w // bw)
-    return jax.lax.reduce_window(
-        x, jnp.float32(0.0), jax.lax.max,
-        (1, bh, bw), (1, bh, bw),
-        ((0, 0), (0, gh * bh - h), (0, gw * bw - w)),
-    )
+    x = jnp.pad(x, ((0, 0), (0, gh * bh - h), (0, gw * bw - w)))
+    return jnp.max(x.reshape(b, gh, bh, gw, bw), axis=(2, 4))
 
 
-def _window_reach(n: int, b: int, g: int, t: int, r: int) -> Tuple[int, int]:
-    """(up, down) reach, in whole blocks, of any tile's input window along
-    one axis of length ``n`` tiled by ``b`` into ``g`` blocks, with clamped
-    window extent ``t`` and stencil radius ``r``.
+def _block_reach(n: int, b: int, r: int) -> Tuple[int, int]:
+    """(up, down) reach, in whole blocks, of the pixels a tile's valid
+    outputs read along one axis of length ``n`` tiled by ``b``.
 
-    Covers all three window regimes of ``tiling.window_origin``: interior
-    (up ``r``, down ``t - b - r``), clamped at 0 (down up to ``t - b``) and
-    clamped at ``n - t`` (up up to ``t - s`` where ``s`` is the ragged
-    extent of the last block). Over-reach only costs recompute of an
-    unchanged tile — never correctness — so the bounds round up.
+    A valid output reads only its ``r``-neighborhood (boundary rules map
+    overhang back inside that neighborhood), so ``ceil(r / b)`` blocks each
+    way — whatever larger aligned window the kernel DMAs, the cells outside
+    the stencil are never selected.
     """
-    if g <= 1:
+    if -(-n // b) <= 1:
         return 0, 0
-    s = n - (g - 1) * b
-    up = max(-(-r // b), -(-(t - s) // b))
-    down = -(-(t - b) // b)
-    return max(0, up), max(0, down)
+    reach = -(-r // b)
+    return reach, reach
 
 
 def _dilate_blocks(
@@ -646,14 +631,8 @@ def stream_delta(
             else config.spec.radius,
             config.nms,
         )
-        backend = resolve_backend(config.backend)
-        th, tw = window_shape(
-            h, w, bh, bw, r_in, align=_stream_align(backend, rgb)
-        )
         changed = _dilate_blocks(
-            blocks,
-            _window_reach(h, bh, gh, th, r_in),
-            _window_reach(w, bw, gw, tw, r_in),
+            blocks, _block_reach(h, bh, r_in), _block_reach(w, bw, r_in)
         )
     skipped = jnp.int32(gh * gw) - jnp.sum(
         changed.astype(jnp.int32), axis=(-2, -1)

@@ -12,15 +12,15 @@ size-specialized kernels this module replaced:
 
   * paper's CUDA-block tile ownership + 2r overlap (§4.3.1)  ->  2-D tiled
     grid; step (k, j) owns a ``block_h x block_w`` output tile and reads a
-    clamped, possibly overlapping ``pl.Unblocked`` window of the raw
-    unpadded frame (``repro.kernels.tiling``); the halo radius r comes from
+    clamped, possibly overlapping, tile-aligned ``pl.Element`` window of
+    the raw frame (``repro.kernels.tiling``); the halo radius r comes from
     the operator spec (r=1/2/3 for 3x3/5x5/7x7).
   * warp-shuffle register taps (§4.3.3)  ->  static strided slices of the
     VMEM-resident tile feeding the VPU.
   * explicit prefetch (§4.3.4)  ->  Pallas's automatic double buffering
     (``pipeline_depth=0``, the default), or — the paper's trick made
     explicit — a manual HBM->VMEM DMA ring (``pipeline_depth >= 2``): the
-    input stays in ``pltpu.ANY`` memory and each grid step issues
+    input stays in ``pl.ANY`` memory and each grid step issues
     ``pltpu.make_async_copy`` for the window ``depth - 1`` steps ahead
     into a ``(depth, tile_h, tile_w)`` VMEM scratch ring, so tile k+1's
     halo load overlaps tile k's compute under our control (DESIGN.md §11).
@@ -42,7 +42,9 @@ The kernel is a megakernel for the full edge-detection pipeline: raw u8
 gray or RGB frame in (BT.601 luma per-tile in VMEM), in-kernel boundary
 rule, multi-directional magnitude out — optionally per-direction gradient
 components (``out_components``) and a per-block max (``with_max``) for
-one-pass normalization.
+one-pass normalization. RGB frames are handed to the kernel planar,
+``(N, 3, H, W)``, so the window's last two dims are the image's and align
+to Mosaic's tile exactly as a grayscale window does.
 
 ``out_nms`` appends the direction-aware non-maximum suppression stage
 (``repro.core.nms``) to the same pass: the halo window grows from
@@ -73,11 +75,11 @@ from repro.core.nms import nms_sector, nms_thin
 from repro.core.sobel import magnitude, plan_components, spec_components
 from repro.kernels import tuning
 from repro.kernels.tiling import (
-    ALIGN_INTERPRET,
-    ALIGN_TPU_GRAY,
-    ALIGN_TPU_RGB,
+    as_f32,
     extend_tile,
     luma,
+    pad_to_windows,
+    padded_shape,
     tile_vmem_bytes,
     valid_mask,
     window_origin,
@@ -89,15 +91,58 @@ from repro.kernels.tiling import (
 __all__ = [
     "edge_pallas",
     "edge_stream_pallas",
-    "default_interpret",
     "default_block_shape",
     "kernel_dtype",
 ]
 
+# Per-block maxima leave the kernel as one 128-lane row per grid step (the
+# scalar broadcast across it): Mosaic lowers a vector store of a keepdims
+# reduction, not a vector-to-scalar store into SMEM.
+_LANES = 128
 
-def default_interpret() -> bool:
-    """Interpret (CPU emulation) unless running on a real TPU."""
-    return jax.default_backend() != "tpu"
+
+def _bmax_spec() -> pl.BlockSpec:
+    return pl.BlockSpec((1, 1, 1, _LANES), lambda i, k, j: (i, k, 0, j))
+
+
+def _bmax_shape(n: int, gh: int, gw: int) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((n, gh, 1, gw * _LANES), jnp.float32)
+
+
+def _bmax_unpack(rows: jnp.ndarray) -> jnp.ndarray:
+    """``(N, gh, 1, gw * 128)`` lane rows -> ``(N, gh, gw)`` block maxima
+    (a max over 128 copies of one value: exact, and no slice)."""
+    n, gh, _, lanes = rows.shape
+    return jnp.max(rows.reshape(n, gh, lanes // _LANES, _LANES), axis=-1)
+
+
+def _bmax_pack(bmax: jnp.ndarray) -> jnp.ndarray:
+    """``(N, gh, gw)`` block maxima -> the kernel's lane-row layout."""
+    n, gh, gw = bmax.shape
+    rows = jnp.broadcast_to(bmax[..., None], (n, gh, gw, _LANES))
+    return rows.reshape(n, gh, 1, gw * _LANES)
+
+
+def _block_max(mag, k, j, *, h, w, bh, bw):
+    """Masked per-block max of a ``(bh, bw)`` magnitude tile, as a
+    ``(1, 128)`` row (the magnitude is >= 0, so masking to 0 is exact)."""
+    masked = jnp.where(valid_mask(k, j, h, w, bh, bw), mag, jnp.float32(0.0))
+    return jnp.broadcast_to(jnp.max(masked, keepdims=True), (1, _LANES))
+
+
+def _planar(x: jnp.ndarray, rgb: bool) -> jnp.ndarray:
+    """The kernels' input layout: ``(N, H, W)``, or RGB as ``(N, 3, H, W)``
+    planes (interleaved channels would make the window's column dim the
+    second-minor one, which Mosaic cannot align)."""
+    return jnp.moveaxis(x, -1, 1) if rgb else x
+
+
+def _to_compute(x: jnp.ndarray, acc_dtype) -> jnp.ndarray:
+    """Gray window -> the kernel compute dtype (f32, or the integer lane's
+    i16/i32, widening through i32 as Mosaic requires)."""
+    if not acc_dtype:
+        return as_f32(x)
+    return x.astype(jnp.int32).astype(jnp.dtype(acc_dtype))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -186,15 +231,12 @@ def _emit_outputs(
         return spec_components(y, spec, hh, ww, variant, directions,
                                sink=sink)
 
-    def as_f32(comps):
-        return tuple(c.astype(jnp.float32) for c in comps)
+    def comps_f32(comps):
+        return tuple(as_f32(c) for c in comps)
 
     def block_max(mag):
         """Masked per-block max of the (un-thinned) center magnitude."""
-        masked = jnp.where(
-            valid_mask(k, j, h, w, bh, bw), mag, jnp.float32(0.0)
-        )
-        return jnp.max(masked)
+        return _block_max(mag, k, j, h=h, w=w, bh=bh, bw=bw)
 
     if out_nms:
         # NMS needs a 1-px magnitude neighborhood: grow the halo to
@@ -205,7 +247,7 @@ def _emit_outputs(
             x, k, j, h=h, w=w, block_h=bh, block_w=bw, r=reach + 1,
             padding=padding,
         )
-        comps_ext = as_f32(components(y, bh + 2, bw + 2))
+        comps_ext = comps_f32(components(y, bh + 2, bw + 2))
         mag_ext = magnitude(comps_ext)
         comps = tuple(
             jax.lax.slice(g, (1, 1), (1 + bh, 1 + bw)) for g in comps_ext
@@ -220,26 +262,26 @@ def _emit_outputs(
             o += 1
             o_refs[o][0] = mag
         if with_max:
-            o_refs[o + 1][0, k, j] = block_max(mag)
+            o_refs[o + 1][0, 0] = block_max(mag)
         return
 
     y = extend_tile(
         x, k, j, h=h, w=w, block_h=bh, block_w=bw, r=reach,
         padding=padding,
     )
-    comps = as_f32(components(y, bh, bw))
+    comps = comps_f32(components(y, bh, bw))
     if out_components:
         o_refs[0][0] = jnp.stack(comps, axis=0)     # (directions, bh, bw)
         if with_max:
             # Per-block maxima ride along with the components, so callers
             # needing components AND the peak pay no second whole-image
             # reduction read (dispatch's fused normalization fast path).
-            o_refs[1][0, k, j] = block_max(magnitude(comps))
+            o_refs[1][0, 0] = block_max(magnitude(comps))
         return
     mag = magnitude(comps)
     o_refs[0][0] = mag
     if with_max:
-        o_refs[1][0, k, j] = block_max(mag)
+        o_refs[1][0, 0] = block_max(mag)
 
 
 def _kernel(
@@ -249,7 +291,7 @@ def _kernel(
 ):
     k = pl.program_id(1)
     j = pl.program_id(2)
-    x = luma(x_ref[0]) if rgb else x_ref[0].astype(_compute_dtype(acc_dtype))
+    x = luma(x_ref[0], axis=0) if rgb else _to_compute(x_ref[0], acc_dtype)
     _emit_outputs(
         x, o_refs, k, j,
         spec=spec, variant=variant, directions=directions, bh=bh, bw=bw,
@@ -279,7 +321,7 @@ def _pipelined_kernel(
 ):
     """Manual double-buffered DMA body (``pipeline_depth >= 2``).
 
-    The input stays in ``pltpu.ANY`` (HBM); a ``(depth, th, tw[, 3])``
+    The input stays in ``pl.ANY`` (HBM); a ``(depth, [3,] th, tw)``
     VMEM scratch ring plus a ``depth``-wide DMA semaphore array implement
     the paper's prefetch explicitly. Grid step j (j fastest, sequential
     under ``dimension_semantics=("arbitrary",)*3``):
@@ -292,7 +334,7 @@ def _pipelined_kernel(
 
     Each window's copy is started exactly once and waited exactly once;
     the window offsets are ``tiling.window_origin`` — the very function
-    the automatic path's ``pl.Unblocked`` index map uses — so both paths
+    the automatic path's ``pl.Element`` index map uses — so both paths
     read byte-identical windows and the outputs are bit-exact across
     ``pipeline_depth`` settings. Analyzer rule PIPE001 checks the
     start/wait pairing and ring depth on the traced jaxpr.
@@ -311,9 +353,12 @@ def _pipelined_kernel(
     reach = plan.linear_reach if plan is not None else spec.radius
     r_in = window_radius(reach, out_nms)
 
+    hp, wp = padded_shape(h, w, bh, bw, r_in)
+
     def window_copy(j2, slot):
-        row0, col0 = window_origin(k, j2, h, w, bh, bw, r_in, th, tw)
-        src = x_hbm.at[i, pl.ds(row0, th), pl.ds(col0, tw)]
+        row0, col0 = window_origin(k, j2, hp, wp, bh, bw, r_in, th, tw)
+        win = (pl.ds(row0, th), pl.ds(col0, tw))
+        src = x_hbm.at[(i, slice(None)) + win if rgb else (i,) + win]
         return pltpu.make_async_copy(src, buf.at[slot], sem.at[slot])
 
     @pl.when(j == 0)
@@ -328,7 +373,7 @@ def _pipelined_kernel(
     slot = jax.lax.rem(j, depth)
     window_copy(j, slot).wait()
     x_win = buf[slot]
-    x = luma(x_win) if rgb else x_win.astype(_compute_dtype(acc_dtype))
+    x = luma(x_win, axis=0) if rgb else _to_compute(x_win, acc_dtype)
 
     sink = None
     if n_sink:
@@ -365,7 +410,8 @@ def _stream_kernel(
     The delta dispatcher marks each tile changed/unchanged in an SMEM mask
     (``(N, gh, gw)`` int32, one flag per grid step). A changed tile runs
     the exact same math as :func:`_kernel`'s primary path; an unchanged
-    tile splices the cached output tile and per-block max instead — one
+    tile splices the cached output tile and per-block max (both per-block
+    maxima in the lane-row layout of ``_bmax_spec``) instead — one
     ``lax.cond`` per grid step, so Mosaic branches over the whole tile
     compute and the skipped tile costs only the (unavoidable) window DMA
     plus a VMEM copy. Splice == recompute bit-exactly because an unchanged
@@ -377,13 +423,10 @@ def _stream_kernel(
     changed = mask_ref[0, k, j] != 0
 
     def block_max(mag):
-        masked = jnp.where(
-            valid_mask(k, j, h, w, bh, bw), mag, jnp.float32(0.0)
-        )
-        return jnp.max(masked)
+        return _block_max(mag, k, j, h=h, w=w, bh=bh, bw=bw)
 
     def fresh(x_raw):
-        x = luma(x_raw) if rgb else x_raw.astype(jnp.float32)
+        x = luma(x_raw, axis=0) if rgb else as_f32(x_raw)
         if out_nms:
             y = extend_tile(
                 x, k, j, h=h, w=w, block_h=bh, block_w=bw,
@@ -407,11 +450,11 @@ def _stream_kernel(
         return mag, block_max(mag)
 
     def cached(_x_raw):
-        return prev_ref[0], prevmax_ref[0, k, j]
+        return prev_ref[0], prevmax_ref[0, 0]
 
     out, bmax = jax.lax.cond(changed, fresh, cached, x_ref[0])
     o_ref[0] = out
-    omax_ref[0, k, j] = bmax
+    omax_ref[0, 0] = bmax
 
 
 # ---------------------------------------------------------------------------
@@ -473,7 +516,7 @@ def edge_pallas(
         center components alongside the thin map.
       * ``out_mag`` (``out_nms`` only): the un-thinned ``(N, H, W)``
         magnitude — the peak source for the sharded engine, which cannot
-        use the SMEM block maxima (its local valid mask differs).
+        use the kernel's block maxima (its local valid mask differs).
       * ``with_max``: a ``(N, gh, gw)`` per-block max (gh/gw = grid dims) of
         the un-thinned magnitude, for one-pass normalization — available in
         every mode, including alongside ``out_components``.
@@ -560,17 +603,12 @@ def edge_pallas(
     gh, gw = pl.cdiv(h, bh), pl.cdiv(w, bw)
     grid = (n, gh, gw)
 
-    if interpret:
-        align = ALIGN_INTERPRET
-    else:
-        align = ALIGN_TPU_RGB if rgb else ALIGN_TPU_GRAY
     # NMS compares the magnitude against a 1-px neighborhood, so its input
     # window carries one extra ring on top of the (composed) stencil halo.
     reach = plan.linear_reach if plan is not None else spec.radius
     r_in = window_radius(reach, out_nms)
-    in_spec = window_spec(
-        h, w, bh, bw, r_in, align=align, channels=3 if rgb else None
-    )
+    in_spec = window_spec(h, w, bh, bw, r_in, channels=3 if rgb else None)
+    x = _planar(x, rgb)
 
     plane = pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j))
     plane_shape = jax.ShapeDtypeStruct((n, h, w), jnp.float32)
@@ -592,17 +630,9 @@ def edge_pallas(
     else:
         out_specs, out_shape = [plane], [plane_shape]
     if with_max:
-        # One whole-(gh, gw) SMEM block per image; each grid step stores
-        # its scalar block max — cheap, and legal under Mosaic's block
-        # alignment rules (dims equal to the array dims).
-        out_specs.append(
-            pl.BlockSpec(
-                (1, gh, gw),
-                lambda i, k, j: (i, 0, 0),
-                memory_space=pltpu.SMEM,
-            )
-        )
-        out_shape.append(jax.ShapeDtypeStruct((n, gh, gw), jnp.float32))
+        # Each grid step stores its block max as one 128-lane row.
+        out_specs.append(_bmax_spec())
+        out_shape.append(_bmax_shape(n, gh, gw))
 
     common = dict(
         spec=spec,
@@ -626,14 +656,14 @@ def edge_pallas(
         # clamped window itself (same window_origin offsets as in_spec's
         # index map — byte-identical reads). The grid must run sequentially
         # for cross-step prefetch to be legal, hence "arbitrary" semantics.
-        th, tw = window_shape(h, w, bh, bw, r_in, align=align)
+        th, tw = window_shape(h, w, bh, bw, r_in)
         n_sink = _sink_slots(variant, directions)
         # Gradient row-pass sink extents are relative to the gradient
         # stage's input tile — bh/bw plus the NMS ring plus the *gradient*
         # radius (pre-stages have already consumed the rest of the reach).
         eh = bh + (2 if out_nms else 0) + 2 * spec.radius
         ew = bw + (2 if out_nms else 0)
-        buf_shape = (pipeline_depth, th, tw) + ((3,) if rgb else ())
+        buf_shape = (pipeline_depth,) + ((3,) if rgb else ()) + (th, tw)
         scratch = [
             pltpu.VMEM(buf_shape, x.dtype),
             pltpu.SemaphoreType.DMA((pipeline_depth,)),
@@ -663,15 +693,15 @@ def edge_pallas(
         out = pl.pallas_call(
             kernel,
             grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * 3
             ),
             interpret=interpret,
-        )(x)
+        )(pad_to_windows(x, bh, bw, r_in))
     else:
         kernel = functools.partial(_kernel, **common)
         out = pl.pallas_call(
@@ -682,6 +712,9 @@ def edge_pallas(
             out_shape=out_shape,
             interpret=interpret,
         )(x)
+    out = list(out)
+    if with_max:
+        out[-1] = _bmax_unpack(out[-1])
     if len(out) == 1:
         return out[0]
     return tuple(out)
@@ -752,15 +785,9 @@ def edge_stream_pallas(
         )
     grid = (n, gh, gw)
 
-    if interpret:
-        align = ALIGN_INTERPRET
-    else:
-        align = ALIGN_TPU_RGB if rgb else ALIGN_TPU_GRAY
     r_in = window_radius(spec.radius, out_nms)
-    in_spec = window_spec(
-        h, w, bh, bw, r_in, align=align, channels=3 if rgb else None
-    )
-    grid_spec = pl.BlockSpec(
+    in_spec = window_spec(h, w, bh, bw, r_in, channels=3 if rgb else None)
+    mask_spec = pl.BlockSpec(
         (1, gh, gw), lambda i, k, j: (i, 0, 0), memory_space=pltpu.SMEM
     )
     plane = pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j))
@@ -778,14 +805,20 @@ def edge_stream_pallas(
         rgb=rgb,
         out_nms=out_nms,
     )
-    return pl.pallas_call(
+    primary, bmax = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[grid_spec, in_spec, plane, grid_spec],
-        out_specs=[plane, grid_spec],
+        in_specs=[mask_spec, in_spec, plane, _bmax_spec()],
+        out_specs=[plane, _bmax_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((n, h, w), jnp.float32),
-            jax.ShapeDtypeStruct((n, gh, gw), jnp.float32),
+            _bmax_shape(n, gh, gw),
         ],
         interpret=interpret,
-    )(mask.astype(jnp.int32), x, prev_primary, prev_bmax)
+    )(
+        mask.astype(jnp.int32),
+        _planar(x, rgb),
+        prev_primary,
+        _bmax_pack(prev_bmax),
+    )
+    return primary, _bmax_unpack(bmax)

@@ -41,6 +41,9 @@ Latency methodology: compile iterations (the initial warm-up and the
 re-warm after a reshard) are excluded from the percentile window, and every
 stamped request is ``block_until_ready`` on the *full* result pytree, so
 p50/p95 reflect steady-state serving.
+
+``main`` keeps JAX's persistent compilation cache where
+``repro.launch.compile_cache`` says.
 """
 from __future__ import annotations
 
@@ -51,6 +54,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _percentile(xs, q):
@@ -72,7 +76,17 @@ def _parse_chaos(args):
     return plan
 
 
-def serve_image(cfg, args) -> None:
+def image_edge_config(cfg, *, edges: bool = False):
+    """The resolved ``EdgeConfig`` image serving runs for ``cfg``:
+    magnitude with per-image peaks, or (``edges``) binary edge maps —
+    fused NMS in the kernel pass, hysteresis linking post-gather."""
+    overrides = dict(with_max=True)
+    if edges:
+        overrides.update(nms=True, hysteresis=True)
+    return cfg.edge_config(**overrides).resolved()
+
+
+def serve_image(cfg, args, on_result=None) -> dict:
     """Edge-detection serving: one request = one batch of frames.
 
     Each request runs under the degradation ladder (``serve/guard.py``):
@@ -82,6 +96,10 @@ def serve_image(cfg, args) -> None:
     straggle individual devices (``slow@dK:MS``) — straggling devices are
     flagged by ``StepMonitor`` and, after repeated strikes, excluded from
     the mesh entirely (another replan), so the fleet heals itself.
+
+    ``on_result(req, out)`` sees every served request's result. Returns
+    the run's record: ``health``, ``compile_s`` (the first warm-up),
+    ``lat_ms``/``xfer_ms`` per request and ``wall`` seconds.
     """
     import jax.numpy as jnp
 
@@ -95,12 +113,7 @@ def serve_image(cfg, args) -> None:
     from repro.sharding.partition import layout_logical_axes
 
     chaos = _parse_chaos(args)
-    overrides = dict(with_max=True)
-    if args.edges:
-        # Detector traffic: fused NMS in the kernel pass, hysteresis linking
-        # post-gather — requests return binary edge maps, not magnitude.
-        overrides.update(nms=True, hysteresis=True)
-    edge_cfg = cfg.edge_config(**overrides).resolved()
+    edge_cfg = image_edge_config(cfg, edges=args.edges)
     backend = resolve_backend(edge_cfg.backend)
     fb_cfg = edge_cfg.replace(backend="xla") if backend != "xla" else None
     shard_spec = args.shard if args.shard is not None else cfg.sobel_shard
@@ -183,7 +196,9 @@ def serve_image(cfg, args) -> None:
         return mesh
 
     mesh = build_step([all_devices[i] for i in pop])
+    t_warm = time.perf_counter()
     warm(mesh, req=0)
+    compile_s = time.perf_counter() - t_warm
 
     lat_ms = []
     xfer_ms = []
@@ -237,11 +252,15 @@ def serve_image(cfg, args) -> None:
                 warm(mesh, req=req)
         lat_ms.append(base_s * 1e3 + lag * 1e3)
         px_total += frames.shape[0] * cfg.image_h * cfg.image_w
+        if on_result is not None:
+            on_result(req, out)
     wall = time.perf_counter() - t_all
+    report = dict(health=health, compile_s=compile_s, lat_ms=lat_ms,
+                  xfer_ms=xfer_ms, wall=wall)
     if not lat_ms:  # --requests 0: nothing but the warm-up ran
         print(f"0 requests served in {wall:.2f}s (warm-up only; "
               "use --requests >= 1 for steady-state numbers)")
-        return
+        return report
     mps = px_total / 1e6 / (sum(lat_ms) / 1e3)
     tag = " (served through reshard)" if health.replans else ""
     if args.edges:
@@ -261,9 +280,10 @@ def serve_image(cfg, args) -> None:
         raise SystemExit(
             f"chaos run left {health.unaccounted} request(s) unaccounted"
         )
+    return report
 
 
-def serve_streams(cfg, args) -> None:
+def serve_streams(cfg, args, *, collect: bool = False):
     """Streaming video serving: N concurrent camera streams, fps-paced.
 
     Each stream is a synthetic camera (``data.synthetic.video_frame``)
@@ -276,6 +296,8 @@ def serve_streams(cfg, args) -> None:
     fault kind applies (stream stragglers are ``slow@s<sid>:MS``, frame
     corruption ``corrupt@<sid>:<frame>``); the run ends with the engine's
     health ledger and fails hard if any submitted frame went unaccounted.
+    ``collect`` keeps each stream's outputs on its stats record. Returns
+    ``(engine, stats)``.
     """
     from repro.data.synthetic import video_frame
     from repro.serve import StreamEngine, StreamRequest
@@ -302,7 +324,8 @@ def serve_streams(cfg, args) -> None:
             return video_frame(cfg, stream=sid, step=i, motion=args.motion)
         return frame
 
-    engine = StreamEngine(edge_cfg, max_streams=args.slots, chaos=chaos)
+    engine = StreamEngine(edge_cfg, max_streams=args.slots, chaos=chaos,
+                          collect=collect)
     for sid in range(args.streams):
         engine.submit(StreamRequest(sid=sid, frames=source(sid), fps=args.fps))
     t0 = time.perf_counter()
@@ -337,6 +360,7 @@ def serve_streams(cfg, args) -> None:
         raise SystemExit(
             f"chaos run left {engine.health.unaccounted} frame(s) unaccounted"
         )
+    return engine, stats
 
 
 def serve_lm(cfg, args) -> None:
@@ -361,7 +385,8 @@ def serve_lm(cfg, args) -> None:
     print(f"{len(done)} requests, {toks} tokens, {dt:.2f}s -> {toks/dt:.1f} tok/s")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI (``python -m repro.launch.serve --help``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -396,7 +421,12 @@ def main() -> None:
                          "'loss@4;fail@step:1x2;slow@s1:40;corrupt@0:3=nan'; "
                          "the run prints a health ledger and exits non-zero "
                          "if any submitted frame goes unaccounted")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke).replace(dtype="float32")
     if cfg.family == "image":

@@ -226,9 +226,13 @@ class StepGuard:
                         self._sleep(delay)
                     continue
                 if not self.degraded and self.fallback is not None:
+                    # The traceback is the only record of why the primary
+                    # backend was abandoned (e.g. a kernel the compiler
+                    # refused), so it goes into the log with the warning.
                     log.warning(
-                        "%s failing persistently (%s); degrading to the "
-                        "fallback backend permanently", site, err,
+                        "%s failing persistently (%s: %s); degrading to the "
+                        "fallback backend permanently", site,
+                        type(err).__name__, err, exc_info=err,
                     )
                     self.degraded = True
                     self.failovers += 1

@@ -5,7 +5,7 @@ level; this module solves the same problem one level up, where a frame is
 too big for one device. A frame is split into ``rows x cols`` spatial bands
 over the image mesh ``(data, row, col)`` and each device computes its band
 with a halo of ``OperatorSpec.radius`` pixels exchanged from its neighbors
-— the device-level analogue of the in-kernel ``pl.Unblocked`` halo windows
+— the device-level analogue of the in-kernel ``pl.Element`` halo windows
 (``repro.kernels.tiling``).
 
 Exactness contract — per-shard outputs are **bit-identical** to the
@@ -41,7 +41,6 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.tiling import PAD_MODES, boundary_index, window_radius
@@ -332,12 +331,12 @@ def sharded_edge(
     if need_peak:
         out_specs.append(P("data"))
 
-    outs = shard_map(
+    outs = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(in_spec,),
         out_specs=tuple(out_specs),
-        check_rep=False,
+        check_vma=False,
     )(x)
 
     outs = list(outs)

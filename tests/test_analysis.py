@@ -58,7 +58,6 @@ def _all_trace_rules(
         block_h=block_h,
         block_w=block_w,
         image_hw=image_hw,
-        align=(1, 1),
     )
     vios += analysis.check_vmem_budget(
         location=loc,
@@ -89,7 +88,7 @@ def test_clean_fused_kernel_passes_all_rules():
 
 def test_clean_pipelined_int_kernel_passes_all_rules():
     """The manual-DMA + integer-lane kernel satisfies the full rule set,
-    including PIPE001 and the ring-based HALO001 probe (no Unblocked
+    including PIPE001 and the ring-based HALO001 probe (no Element
     window exists on the ANY-space input)."""
     spec = get_operator("sobel5")
     x = jnp.zeros((1, 64, 96), jnp.uint8)
@@ -195,17 +194,27 @@ def test_bad_oversized_block_trips_vmem001_only():
 
 def test_bad_off_by_one_halo_trips_halo001_only():
     """A kernel compiled with an r=1 window while the operator needs
-    r=2: the exact off-by-one the index-map probe exists to catch."""
+    r=2: the exact off-by-one the index-map probe exists to catch. (The
+    repo's own tile-aligned windows carry slack, so the bad window is an
+    exact-fit Element window written by hand.)"""
     h, w, bh, bw = 64, 96, 16, 32
 
     def kernel(x_ref, o_ref):
         o_ref[...] = x_ref[:, 1:17, 1:33].astype(jnp.float32)
 
+    def origin(i, k, j):
+        return (i, jnp.clip(k * bh - 1, 0, h - bh - 2),
+                jnp.clip(j * bw - 1, 0, w - bw - 2))
+
+    r1_window = pl.BlockSpec(
+        (pl.Element(1), pl.Element(bh + 2), pl.Element(bw + 2)), origin
+    )
+
     def bad(x):
         return pl.pallas_call(
             kernel,
             grid=(1, h // bh, w // bw),
-            in_specs=[window_spec(h, w, bh, bw, 1)],  # sobel5 needs r=2
+            in_specs=[r1_window],  # sobel5 needs r=2
             out_specs=pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j)),
             out_shape=jax.ShapeDtypeStruct((1, h, w), jnp.float32),
             interpret=True,
@@ -292,7 +301,7 @@ def _toy_pipelined_jaxpr(*, wait=True, depth=2, sem_depth=None):
         return pl.pallas_call(
             kernel,
             grid=(1, h // bh, w // bw),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j)),
             out_shape=jax.ShapeDtypeStruct((1, h, w), jnp.float32),
             scratch_shapes=[
@@ -370,24 +379,25 @@ def test_bad_narrow_accumulation_trips_dtype001_only():
 def test_bad_wrong_radius_ring_trips_halo001():
     """HALO001's ring branch: a manual-DMA kernel whose ring slots are
     sized for r=1 cannot be feeding an r=2 stencil — probed against the
-    sobel3-pipelined trace under the sobel5 contract."""
+    sobel3-pipelined trace under the sobel5 contract. (block_h=14: a
+    height at which the tile-aligned r=1 and r=2 windows differ.)"""
     x = jnp.zeros((1, 64, 96), jnp.uint8)
     jaxpr = jax.make_jaxpr(
         lambda a: ekern.edge_pallas(
-            a, operator="sobel3", block_h=16, block_w=32, pipeline_depth=2,
+            a, operator="sobel3", block_h=14, block_w=32, pipeline_depth=2,
             interpret=True,
         )
     )(x)
     vios = analysis.check_halo_window(
         jaxpr, location="t", spec=get_operator("sobel5"), nms=False,
-        block_h=16, block_w=32, image_hw=(64, 96), align=(1, 1),
+        block_h=14, block_w=32, image_hw=(64, 96),
     )
     assert _rule_ids(vios) == {"HALO001"}
     assert "DMA ring slot tile" in vios[0].message
     # ...and under its own (sobel3) contract the same trace is clean.
     assert analysis.check_halo_window(
         jaxpr, location="t", spec=get_operator("sobel3"), nms=False,
-        block_h=16, block_w=32, image_hw=(64, 96), align=(1, 1),
+        block_h=14, block_w=32, image_hw=(64, 96),
     ) == []
 
 

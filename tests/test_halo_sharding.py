@@ -201,10 +201,7 @@ def test_image_rules_and_specs():
     from repro.sharding.partition import image_spec, layout_logical_axes
     from repro.sharding.rules import logical_to_spec
 
-    try:
-        mesh = AbstractMesh((2, 2, 2), ("data", "row", "col"))
-    except TypeError:
-        mesh = AbstractMesh((("data", 2), ("row", 2), ("col", 2)))
+    mesh = AbstractMesh((2, 2, 2), ("data", "row", "col"))
 
     assert layout_logical_axes("NHWC") == ("batch", "height", "width", "channel")
     assert layout_logical_axes("NTHW") == ("batch", None, "height", "width")
@@ -213,10 +210,7 @@ def test_image_rules_and_specs():
     assert image_spec("NHWC", mesh, (8, 64, 64, 3)) == P("data", "row", "col")
 
     # image batches on the legacy LM mesh still spread their rows
-    try:
-        lm = AbstractMesh((4, 2), ("data", "model"))
-    except TypeError:
-        lm = AbstractMesh((("data", 4), ("model", 2)))
+    lm = AbstractMesh((4, 2), ("data", "model"))
     assert logical_to_spec(("batch", "height", "width"), lm, (8, 64, 64)) == P(
         "data", "model"
     )

@@ -34,7 +34,12 @@ def ksobel(img, *, size=5, directions=0, variant="v2", params=None,
 
 
 @pytest.mark.parametrize("variant", ["direct", "separable", "v1", "v2"])
-@pytest.mark.parametrize("shape,block_h", [((1, 64, 128), 16), ((2, 96, 73), 32)])
+@pytest.mark.parametrize(
+    "shape,block_h",
+    [((1, 64, 128), 16), ((2, 96, 73), 32),
+     # the chip geometry at ragged and 1080p sizes (default 256-wide blocks)
+     ((1, 237, 413), 64), ((1, 1080, 1920), 64)],
+)
 def test_kernel_matches_oracle(variant, shape, block_h, rng):
     img = jnp.asarray(_img(rng, shape))
     out = np.asarray(ksobel(img, variant=variant, block_h=block_h))

@@ -8,10 +8,7 @@ from repro.launch.specs import cache_logical_axes, cell_plan, input_specs
 from repro.models import Model
 from repro.sharding.rules import get_rules, logical_to_spec
 
-try:
-    MESH = AbstractMesh((2, 4, 8), ("pod", "data", "model"))
-except TypeError:  # jax<=0.4.x signature: AbstractMesh(((name, size), ...))
-    MESH = AbstractMesh((("pod", 2), ("data", 4), ("model", 8)))
+MESH = AbstractMesh((2, 4, 8), ("pod", "data", "model"))
 
 
 def test_basic_mapping():

@@ -44,7 +44,10 @@ def dispatch_sobel(img, *, backend=None, variant="v2", block_h=None, block_w=Non
 @pytest.mark.parametrize("variant", ["direct", "separable", "v1", "v2"])
 @pytest.mark.parametrize(
     "shape,block",
-    [((1, 57, 83), (8, 16)), ((2, 96, 73), (32, 32)), ((1, 64, 128), (16, 64))],
+    [((1, 57, 83), (8, 16)), ((2, 96, 73), (32, 32)), ((1, 64, 128), (16, 64)),
+     # chip-sized blocks: tile-aligned windows, declared row padding
+     # (237 is not a multiple of 8), and a 1080p frame of 17x8 blocks
+     ((1, 237, 413), (64, 256)), ((1, 1080, 1920), (64, 256))],
 )
 def test_2d_tiling_bit_exact(variant, shape, block, rng):
     img = jnp.asarray(_img(rng, shape))
@@ -141,12 +144,80 @@ def test_edge_detect_backend_parity(rng):
 # ---------------------------------------------------------------------------
 
 def test_window_shape_geometry():
-    # Exact stencil window in interpret mode; clamped to the image when the
-    # image is smaller; rounded up to the Mosaic alignment on hardware.
-    assert tiling.window_shape(512, 640, 64, 128, 2) == (68, 132)
+    # One geometry on every backend: the stencil window rounded up to the
+    # Mosaic (8, 128) tile plus one tile of slack for the aligned-down
+    # origin; clamped to the whole axis when one window covers it.
+    assert tiling.window_shape(512, 640, 64, 128, 2) == (80, 384)
     assert tiling.window_shape(5, 7, 64, 128, 2) == (5, 7)
-    assert tiling.window_shape(512, 640, 64, 128, 2, align=tiling.ALIGN_TPU_GRAY) == (72, 256)
-    assert tiling.window_shape(512, 640, 64, 128, 1, align=tiling.ALIGN_TPU_RGB) == (66, 136)
+    assert tiling.window_shape(2048, 2048, 64, 256, 3) == (80, 512)
+    # Only a multi-window axis that is not a tile multiple is padded.
+    assert tiling.padded_shape(237, 413, 64, 256, 2) == (240, 413)
+    assert tiling.padded_shape(1080, 1920, 64, 256, 2) == (1080, 1920)
+    # Origins are tile-aligned, cover the stencil, and clamp in bounds.
+    hp, wp, bh, bw, r = 240, 1920, 64, 256, 2
+    th, tw = tiling.window_shape(237, wp, bh, bw, r)
+    for k in range(4):
+        for j in range(8):
+            row0, col0 = (int(v) for v in tiling.window_origin(
+                k, j, hp, wp, bh, bw, r, th, tw))
+            assert row0 % 8 == 0 and col0 % 128 == 0
+            assert 0 <= row0 <= hp - th and 0 <= col0 <= wp - tw
+            assert row0 <= max(k * bh - r, 0)
+            assert row0 + th >= min(k * bh + bh + r, 237)
+            assert col0 <= max(j * bw - r, 0)
+            assert col0 + tw >= min(j * bw + bw + r, wp)
+
+
+@pytest.mark.parametrize(
+    "k,j,fast",
+    [(1, 1, True), (30, 6, True), (0, 3, False), (5, 0, False),
+     (31, 3, False), (5, 7, False)],
+)
+def test_interior_tiles_take_the_static_slice(k, j, fast):
+    """On sobel-hd's 2048^2 / 64x256 geometry, tiles whose stencil lies
+    inside the frame and whose aligned window is not clamped take the
+    static-slice fast path; edge tiles take the selection matmul. The window
+    is NaN outside the stencil: the matmul spreads it (0 * NaN), the slice
+    never reads it."""
+    h = w = 2048
+    bh, bw, r = 64, 256, 2
+    th, tw = tiling.window_shape(h, w, bh, bw, r)
+    row0, col0 = (int(v) for v in tiling.window_origin(
+        k, j, h, w, bh, bw, r, th, tw))
+    win = np.full((th, tw), np.nan, np.float32)
+    win[max(k * bh - r, 0) - row0:min(k * bh + bh + r, h) - row0,
+        max(j * bw - r, 0) - col0:min(j * bw + bw + r, w) - col0] = 1.0
+    y = tiling.extend_tile(jnp.asarray(win), k, j, h=h, w=w, block_h=bh,
+                           block_w=bw, r=r)
+    assert bool(np.isfinite(np.asarray(y)).all()) == fast
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested jaxprs (kernel bodies, cond
+    branches) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_selection_matmul_runs_at_highest_precision():
+    """The boundary tiles' one-hot selection is exact only at HIGHEST: the
+    MXU's default f32 contraction rounds its operands to bf16, which keeps
+    integer pixels up to 256 but not fractional ones (RGB luma, f32
+    frames)."""
+    import jax
+
+    jaxpr = jax.make_jaxpr(
+        lambda a: edge_pallas(a, block_h=16, block_w=128, interpret=True)
+    )(jnp.zeros((1, 40, 300), jnp.float32))
+    dots = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        assert e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
 
 
 def test_boundary_index_matches_numpy_pad():
