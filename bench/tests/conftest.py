@@ -1,0 +1,13 @@
+"""Tests of the benchmark's own code. They run on the CPU, at tiny sizes:
+
+    JAX_PLATFORMS=cpu python -m pytest bench/tests
+"""
+import os
+import pathlib
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
