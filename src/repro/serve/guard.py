@@ -40,6 +40,7 @@ import random
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.runtime.chaos import FaultPlan
@@ -174,7 +175,9 @@ class StepGuard:
     (succeeded after >= 1 retry), ``"degraded"`` (served by the
     fallback). A :class:`~repro.runtime.chaos.FaultPlan` fires its
     ``site``/``fallback_site`` per attempt, which is how injected kernel
-    failures reach per-request granularity under ``jax.jit``.
+    failures reach per-request granularity under ``jax.jit``. Each attempt
+    is a profiler span ``repro.guard.attempt``, each sleep between retries
+    one ``repro.guard.backoff``.
     """
 
     def __init__(
@@ -209,9 +212,10 @@ class StepGuard:
             runner = self.fallback if self.degraded else self.primary
             site = self.fallback_site if self.degraded else self.site
             try:
-                if self.chaos is not None:
-                    self.chaos.fire(site)
-                out = runner(*args, **kw)
+                with jax.profiler.TraceAnnotation("repro.guard.attempt"):
+                    if self.chaos is not None:
+                        self.chaos.fire(site)
+                    out = runner(*args, **kw)
             except Exception as err:  # noqa: BLE001 — the ladder IS the handler
                 self.last_error = f"{type(err).__name__}: {err}"
                 attempts += 1
@@ -223,7 +227,8 @@ class StepGuard:
                         site, err, attempts, fp.max_retries_per_step, delay,
                     )
                     if delay:
-                        self._sleep(delay)
+                        with jax.profiler.TraceAnnotation("repro.guard.backoff"):
+                            self._sleep(delay)
                     continue
                 if not self.degraded and self.fallback is not None:
                     # The traceback is the only record of why the primary

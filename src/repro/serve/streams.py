@@ -22,9 +22,16 @@ state is temporal edge state instead of a KV cache:
     static group takes ``dispatch.edge_stream_cached`` — no kernel launch
     at all, just the cheap epilogue — while a partially changed group runs
     the masked-grid kernel that recomputes only flagged tiles.
-  * **Split timing.** Host→device transfer and engine compute are timed
-    separately (``block_until_ready`` on the device-put before the compute
-    window opens), so the reported p50/p99 measure the engine, not PCIe.
+  * **Split timing.** Each group serve records two host-clock times on its
+    members' :class:`StreamStats`: ``transfer_ms`` covers the host
+    ``np.stack`` of the members' frames and their transfer to the device
+    (``block_until_ready`` on the device-put); ``compute_ms`` covers the
+    state concatenation, the delta test and the compute, but no queue
+    wait. The step's phases are also profiler spans named
+    ``repro.stream.*`` (``repro.guard.*`` for the retry ladder), on the
+    device trace's clock: ``step`` (metadata ``step``, ``frames``,
+    ``groups``), ``intake``, ``stack``, ``h2d``, ``concat``, ``delta``,
+    ``compute``, ``split``, ``account`` and ``police``.
 
 Batched streams share their group's step latency — a reported per-stream
 percentile is the latency of the batch the frame rode in, which is the
@@ -147,10 +154,6 @@ class StreamStats:
     @property
     def budget_ms(self) -> float:
         return 1e3 / self.fps
-
-    def percentile(self, q: float, *, which: str = "compute") -> float:
-        xs = self.compute_ms if which == "compute" else self.transfer_ms
-        return float(np.percentile(np.asarray(xs), q)) if xs else 0.0
 
 
 @dataclasses.dataclass
@@ -363,37 +366,43 @@ class StreamEngine:
 
     def step(self) -> bool:
         """Serve every due stream once; returns False when fully drained."""
-        self._admit()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return bool(self.queue)
-        if self.chaos is not None:
-            loss = self.chaos.device_loss(self.engine_step)
-            if loss is not None:
-                # Single-host streaming: recovery is a re-jit on the
-                # surviving population (the mesh replan analog lives in the
-                # sharded serve loop, launch/serve.py).
-                self._make_jits()
-                self.health.replans += 1
-        self.clock = min(self.slots[i].next_due for i in active)
-        due = [i for i in active
-               if self.slots[i].next_due <= self.clock + 1e-9]
-        groups: Dict[tuple, List[int]] = collections.defaultdict(list)
-        for i in due:
-            groups[self.slots[i].group_key()].append(i)
-        for members in groups.values():
-            self._serve_group(members)
-        self._police_stragglers()
-        self.engine_step += 1
-        for i in due:
-            slot = self.slots[i]
-            if slot is None:
-                continue                            # retired in this step
-            slot.next_due += 1.0 / slot.req.fps
-            slot.pending = self._pull(slot)
-            if slot.pending is None:
-                self._retire(i)
-        return True
+        with jax.profiler.TraceAnnotation("repro.stream.step",
+                                          step=self.engine_step) as span:
+            with jax.profiler.TraceAnnotation("repro.stream.intake"):
+                self._admit()
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            if not active:
+                return bool(self.queue)
+            if self.chaos is not None:
+                loss = self.chaos.device_loss(self.engine_step)
+                if loss is not None:
+                    # Single-host streaming: recovery is a re-jit on the
+                    # surviving population (the mesh replan analog lives in
+                    # the sharded serve loop, launch/serve.py).
+                    self._make_jits()
+                    self.health.replans += 1
+            self.clock = min(self.slots[i].next_due for i in active)
+            due = [i for i in active
+                   if self.slots[i].next_due <= self.clock + 1e-9]
+            groups: Dict[tuple, List[int]] = collections.defaultdict(list)
+            for i in due:
+                groups[self.slots[i].group_key()].append(i)
+            span.set_metadata(frames=len(due), groups=len(groups))
+            for members in groups.values():
+                self._serve_group(members)
+            with jax.profiler.TraceAnnotation("repro.stream.police"):
+                self._police_stragglers()
+            self.engine_step += 1
+            with jax.profiler.TraceAnnotation("repro.stream.intake"):
+                for i in due:
+                    slot = self.slots[i]
+                    if slot is None:
+                        continue                    # retired in this step
+                    slot.next_due += 1.0 / slot.req.fps
+                    slot.pending = self._pull(slot)
+                    if slot.pending is None:
+                        self._retire(i)
+            return True
 
     def _police_stragglers(self) -> None:
         """Feed the monitor's verdicts to the mitigation policy.
@@ -426,21 +435,26 @@ class StreamEngine:
         """
         rgb = layout.endswith("C")
         if state.initialized:
-            changed, _skipped = self._jit_delta(frames, state, cfg, rgb=rgb)
-            static = not bool(jax.device_get(jnp.any(changed)))
+            with jax.profiler.TraceAnnotation("repro.stream.delta"):
+                changed, _skipped = self._jit_delta(frames, state, cfg,
+                                                    rgb=rgb)
+                static = not bool(jax.device_get(jnp.any(changed)))
         else:
             changed, static = None, False
-        if static:
-            # Whole group unchanged: skip the kernel launch outright — the
-            # cached maps ARE this frame's outputs; only the (temporal)
-            # epilogue runs. Bit-identical to the masked kernel on the
-            # same frames, and the XLA backend's real delta win.
-            result, new_state = self._jit_cached(cfg, state, layout=layout)
-        else:
-            result, new_state = self._jit_step(
-                frames, cfg, state, layout=layout, changed=changed
-            )
-        jax.block_until_ready(result)
+        with jax.profiler.TraceAnnotation("repro.stream.compute"):
+            if static:
+                # Whole group unchanged: skip the kernel launch outright —
+                # the cached maps ARE this frame's outputs; only the
+                # (temporal) epilogue runs. Bit-identical to the masked
+                # kernel on the same frames, and the XLA backend's real
+                # delta win.
+                result, new_state = self._jit_cached(cfg, state,
+                                                     layout=layout)
+            else:
+                result, new_state = self._jit_step(
+                    frames, cfg, state, layout=layout, changed=changed
+                )
+            jax.block_until_ready(result)
         return result, new_state, static
 
     def _serve_group(self, members: List[int]) -> None:
@@ -448,14 +462,16 @@ class StreamEngine:
         layout = slots[0].layout
 
         t0 = time.perf_counter()
-        frames = jax.device_put(
-            kernel_dtype(jnp.asarray(np.stack([s.pending for s in slots])))
-        )
-        jax.block_until_ready(frames)
+        with jax.profiler.TraceAnnotation("repro.stream.stack"):
+            host = np.stack([s.pending for s in slots])
+        with jax.profiler.TraceAnnotation("repro.stream.h2d"):
+            frames = jax.device_put(kernel_dtype(jnp.asarray(host)))
+            jax.block_until_ready(frames)
         transfer_ms = (time.perf_counter() - t0) * 1e3
 
         t1 = time.perf_counter()
-        state = self._group_state(slots, frames)
+        with jax.profiler.TraceAnnotation("repro.stream.concat"):
+            state = self._group_state(slots, frames)
         (result, new_state, cached), kind, attempts = self._guard(
             frames, state, layout
         )
@@ -480,31 +496,34 @@ class StreamEngine:
             delays = [0.0] * len(slots)
         group_ms = compute_ms + lag * 1e3
 
-        skipped = np.asarray(result.skipped)
-        for b, s in enumerate(slots):
-            s.state = jax.tree.map(lambda a, b=b: a[b:b + 1], new_state)
-            st = s.stats
-            st.frames += 1
-            st.tiles_per_frame = s.state.tiles
-            if cached:
-                st.cached_steps += 1
-            if st.frames > 1:            # frame 0 is the cold cache fill
-                st.skipped_tiles += int(skipped[b])
-            st.transfer_ms.append(transfer_ms)
-            st.compute_ms.append(group_ms)
-            self.monitor.record(
-                f"s{s.req.sid}", compute_ms / 1e3 + delays[b]
-            )
-            self._account(kind, s, s.pending_idx, attempts=attempts,
-                          latency_ms=group_ms,
-                          detail=self._guard.last_error or "" if attempts
-                          else "")
-            if st.frames > self.guard_policy.warm_frames:
-                budget = self.guard_policy.deadline_ms or st.budget_ms
-                if s.shedder.observe(group_ms, budget):
-                    self.health.deadline_violations += 1
-            if self.collect:
-                st.outputs.append(self._host_outputs(result, b))
+        with jax.profiler.TraceAnnotation("repro.stream.split"):
+            for b, s in enumerate(slots):
+                s.state = jax.tree.map(lambda a, b=b: a[b:b + 1], new_state)
+        with jax.profiler.TraceAnnotation("repro.stream.account"):
+            skipped = np.asarray(result.skipped)
+            for b, s in enumerate(slots):
+                st = s.stats
+                st.frames += 1
+                st.tiles_per_frame = s.state.tiles
+                if cached:
+                    st.cached_steps += 1
+                if st.frames > 1:        # frame 0 is the cold cache fill
+                    st.skipped_tiles += int(skipped[b])
+                st.transfer_ms.append(transfer_ms)
+                st.compute_ms.append(group_ms)
+                self.monitor.record(
+                    f"s{s.req.sid}", compute_ms / 1e3 + delays[b]
+                )
+                self._account(kind, s, s.pending_idx, attempts=attempts,
+                              latency_ms=group_ms,
+                              detail=self._guard.last_error or "" if attempts
+                              else "")
+                if st.frames > self.guard_policy.warm_frames:
+                    budget = self.guard_policy.deadline_ms or st.budget_ms
+                    if s.shedder.observe(group_ms, budget):
+                        self.health.deadline_violations += 1
+                if self.collect:
+                    st.outputs.append(self._host_outputs(result, b))
 
     def _group_state(self, slots: List[_Slot], frames) -> StreamState:
         """Concatenate the members' states for one batched call."""

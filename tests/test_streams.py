@@ -21,6 +21,9 @@ Wall-clock latency assertions are gated behind the fast-host convention
 (``REPRO_SLOW_HOST=1`` skips them); structure and counter assertions always
 run.
 """
+import glob
+import os
+
 import numpy as np
 import pytest
 
@@ -461,3 +464,84 @@ class TestStreamEngine:
         assert st.cached_steps >= 8
         steady = st.compute_ms[3:]
         assert np.median(steady) < st.compute_ms[0]
+
+
+# ----------------------------------------------------------------- spans --
+
+def _program_spans(log_dir):
+    """``(name, start_ns, end_ns, metadata)`` of the ``repro.*`` host
+    events in the trace written under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    start = int(ev.start_ns)
+                    out.append((ev.name, start, start + int(ev.duration_ns),
+                                dict(ev.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced_engine(tmp_path_factory):
+    """Two streams of four frames under the profiler, the second group
+    serve failing once (so the guard retries it after a backoff), and the
+    program's spans read back from the trace."""
+    cfg = EdgeConfig(nms=True, hysteresis=True, backend="xla",
+                     block_h=16, block_w=16)
+    eng = StreamEngine(cfg, chaos=FaultPlan.parse("fail@step:1x1"))
+    for sid in range(2):
+        eng.submit(StreamRequest(sid=sid, frames=_list_source(
+            [_frame(h=32, w=48, seed=300 + 4 * sid + t) for t in range(4)])))
+    log_dir = str(tmp_path_factory.mktemp("stream-trace"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    return eng, _program_spans(log_dir)
+
+
+class TestStreamSpans:
+    PHASES = ("intake", "stack", "h2d", "concat", "delta", "compute",
+              "split", "account", "police")
+
+    def test_phase_spans_fall_inside_a_step(self, traced_engine):
+        _, spans = traced_engine
+        steps = [(s, e) for n, s, e, _ in spans
+                 if n == "repro.stream.step"]
+        phases = [(n, s, e) for n, s, e, _ in spans
+                  if n.startswith("repro.") and n != "repro.stream.step"]
+        names = {n for n, _, _ in phases}
+        assert {f"repro.stream.{p}" for p in self.PHASES} <= names
+        for n, s, e in phases:
+            assert any(a <= s and e <= b for a, b in steps), n
+
+    def test_step_carries_its_metadata(self, traced_engine):
+        eng, spans = traced_engine
+        meta = sorted((m for n, _, _, m in spans
+                       if n == "repro.stream.step"), key=lambda m: m["step"])
+        # four serving steps, then the one that finds the engine drained
+        assert [m["step"] for m in meta] == list(range(eng.engine_step + 1))
+        served = [m for m in meta if "frames" in m]
+        assert len(served) == 4
+        assert all(m["frames"] == 2 and m["groups"] == 1 for m in served)
+
+    def test_backoff_only_where_a_retry_happened(self, traced_engine):
+        eng, spans = traced_engine
+        retried = {o.step for o in eng.outcomes if o.kind == "retried"}
+        assert retried == {1}
+        steps = {m["step"]: (s, e) for n, s, e, m in spans
+                 if n == "repro.stream.step"}
+        backoffs = [(s, e) for n, s, e, _ in spans
+                    if n == "repro.guard.backoff"]
+        assert len(backoffs) == 1
+        a, b = steps[1]
+        assert a <= backoffs[0][0] and backoffs[0][1] <= b
+        attempts = [(s, e) for n, s, e, _ in spans
+                    if n == "repro.guard.attempt" and a <= s and e <= b]
+        assert len(attempts) == 2
