@@ -9,14 +9,23 @@ state is temporal edge state instead of a KV cache:
   * **Slots + admission.** ``max_streams`` slots; :class:`StreamRequest`\\ s
     queue and are admitted as slots free up (a stream leaves when its frame
     source is exhausted). Streams join and leave mid-run without disturbing
-    their neighbors — every slot owns an isolated
+    their neighbors — every slot owns an isolated row of a
     :class:`~repro.api.StreamState`.
   * **Continuous frame batching.** Each step serves every *due* stream
     (fps-paced on a deterministic virtual clock), grouping same-resolution
     streams into one batched :func:`~repro.api.edge_detect_stream` call —
-    ragged resolutions simply land in different groups. Per-slot states are
-    concatenated for the call and split back after it, so batching is an
-    execution detail, never a semantic one.
+    ragged resolutions simply land in different groups. A group's state
+    stays batched on the device between steps: each slot points at the
+    batched state the last call returned and at its row in it. When a
+    group's members are exactly that batch's rows, in order, the batch is
+    passed whole to the next call — no device op. Only when membership
+    changes (a stream joins or retires, a straggler leaves its group, a
+    due subset of streams with different fps) are the members' rows
+    sliced out and concatenated for the call
+    (``StreamStats.state_gathers`` counts those serves). A retired
+    stream's row stays in its batch until the group's next regather, so a
+    batch holds at most one frame's state per stream that has left it.
+    Batching is an execution detail, never a semantic one.
   * **Delta-skip dispatch.** Before computing, the engine runs the per-tile
     change test (``dispatch.stream_delta``) and host-checks it: a fully
     static group takes ``dispatch.edge_stream_cached`` — no kernel launch
@@ -26,12 +35,14 @@ state is temporal edge state instead of a KV cache:
     members' :class:`StreamStats`: ``transfer_ms`` covers the host
     ``np.stack`` of the members' frames and their transfer to the device
     (``block_until_ready`` on the device-put); ``compute_ms`` covers the
-    state concatenation, the delta test and the compute, but no queue
-    wait. The step's phases are also profiler spans named
-    ``repro.stream.*`` (``repro.guard.*`` for the retry ladder), on the
-    device trace's clock: ``step`` (metadata ``step``, ``frames``,
-    ``groups``), ``intake``, ``stack``, ``h2d``, ``concat``, ``delta``,
-    ``compute``, ``split``, ``account`` and ``police``.
+    choice of the group's state (passed whole, regathered or zero-filled),
+    the delta test and the compute, but no queue wait. The step's phases
+    are also profiler spans named ``repro.stream.*`` (``repro.guard.*``
+    for the retry ladder), on the device trace's clock: ``step`` (metadata
+    ``step``, ``frames``, ``groups``), ``intake``, ``stack``, ``h2d``,
+    ``concat`` (the choice of state; metadata ``whole``: 1 when the batch
+    was passed whole, else 0), ``delta``, ``compute``, ``split`` (pointing
+    the members at the new batch), ``account`` and ``police``.
 
 Batched streams share their group's step latency — a reported per-stream
 percentile is the latency of the batch the frame rode in, which is the
@@ -128,7 +139,10 @@ class StreamStats:
     ``frames`` counts frames actually served (on any ladder rung);
     ``submitted`` counts every frame pulled from the source, so
     ``submitted == frames + shed + quarantined`` always holds — the
-    per-stream slice of the engine's health invariant.
+    per-stream slice of the engine's health invariant. ``state_gathers``
+    counts the group serves at which this stream's state had to be sliced
+    out of one batch and concatenated into another, instead of being
+    passed whole (the cold first serve counts as neither).
     """
 
     sid: int
@@ -141,6 +155,7 @@ class StreamStats:
     tiles_per_frame: int = 0
     skipped_tiles: int = 0
     cached_steps: int = 0            # steps served with no kernel launch
+    state_gathers: int = 0           # serves that regathered the state
     transfer_ms: List[float] = dataclasses.field(default_factory=list)
     compute_ms: List[float] = dataclasses.field(default_factory=list)
     outputs: List[dict] = dataclasses.field(default_factory=list)  # collect=True
@@ -160,7 +175,6 @@ class StreamStats:
 class _Slot:
     req: StreamRequest
     it: Iterator[np.ndarray]
-    state: Optional[StreamState]
     stats: StreamStats
     next_due: float
     shedder: Shedder
@@ -170,10 +184,12 @@ class _Slot:
     dtype: Optional[np.dtype] = None       # pinned by the first good frame
     layout: str = "HW"
     solo: bool = False                     # excluded straggler: own group
+    batch: Optional[StreamState] = None    # group state holding this stream
+    row: int = 0                           # this stream's row in ``batch``
 
     def group_key(self) -> tuple:
         key = (self.pending.shape, str(self.pending.dtype),
-               self.state is None or not self.state.initialized)
+               self.batch is None or not self.batch.initialized)
         # An excluded straggler is batched alone so its injected/organic
         # slowness drags only itself, not its former groupmates.
         return key + (("solo", self.req.sid),) if self.solo else key
@@ -345,7 +361,7 @@ class StreamEngine:
                 continue
             req = self.queue.popleft()
             slot = _Slot(
-                req=req, it=req.frame_iter(), state=None,
+                req=req, it=req.frame_iter(),
                 stats=StreamStats(sid=req.sid, fps=req.fps),
                 next_due=self.clock,
                 shedder=Shedder(shed_after=self.guard_policy.shed_after),
@@ -470,8 +486,9 @@ class StreamEngine:
         transfer_ms = (time.perf_counter() - t0) * 1e3
 
         t1 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("repro.stream.concat"):
-            state = self._group_state(slots, frames)
+        with jax.profiler.TraceAnnotation("repro.stream.concat") as span:
+            state, how = self._group_state(slots, frames)
+            span.set_metadata(whole=int(how == "whole"))
         (result, new_state, cached), kind, attempts = self._guard(
             frames, state, layout
         )
@@ -498,15 +515,17 @@ class StreamEngine:
 
         with jax.profiler.TraceAnnotation("repro.stream.split"):
             for b, s in enumerate(slots):
-                s.state = jax.tree.map(lambda a, b=b: a[b:b + 1], new_state)
+                s.batch, s.row = new_state, b
         with jax.profiler.TraceAnnotation("repro.stream.account"):
             skipped = np.asarray(result.skipped)
             for b, s in enumerate(slots):
                 st = s.stats
                 st.frames += 1
-                st.tiles_per_frame = s.state.tiles
+                st.tiles_per_frame = s.batch.tiles
                 if cached:
                     st.cached_steps += 1
+                if how == "gather":
+                    st.state_gathers += 1
                 if st.frames > 1:        # frame 0 is the cold cache fill
                     st.skipped_tiles += int(skipped[b])
                 st.transfer_ms.append(transfer_ms)
@@ -525,16 +544,30 @@ class StreamEngine:
                 if self.collect:
                     st.outputs.append(self._host_outputs(result, b))
 
-    def _group_state(self, slots: List[_Slot], frames) -> StreamState:
-        """Concatenate the members' states for one batched call."""
-        if slots[0].state is None:
+    def _group_state(self, slots: List[_Slot], frames):
+        """The members' state for one batched call, and how it was made.
+
+        ``"init"``: cold members get a zero state. ``"whole"``: the members
+        are exactly the rows of one batch, in order, so that batch — the
+        state the previous call returned — is passed as it is, with no
+        device op. ``"gather"``: anything else (a stream joined or retired,
+        a straggler left its group, a due subset of one group key); each
+        member's row is sliced out and the rows are concatenated.
+        """
+        batch = slots[0].batch
+        if batch is None:
             h, w = (frames.shape[1:3])
             rgb = frames.ndim == 4
             return StreamState.init(
                 len(slots), h, w, self.config, rgb=rgb, dtype=frames.dtype
-            )
-        states = [s.state for s in slots]
-        return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *states)
+            ), "init"
+        if batch.bmax.shape[0] == len(slots) and all(
+                s.batch is batch and s.row == b for b, s in enumerate(slots)):
+            return batch, "whole"
+        rows = [jax.tree.map(lambda a, r=s.row: a[r:r + 1], s.batch)
+                for s in slots]
+        return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
+                            *rows), "gather"
 
     @staticmethod
     def _host_outputs(result, b: int) -> dict:

@@ -15,7 +15,9 @@ The contracts under test, in dependency order:
      floor.
   5. The engine's slots are isolated: ragged resolutions, mid-run
      join/leave, and grouping never corrupt a neighbor stream's state —
-     every engine output equals the same stream served solo.
+     every engine output equals the same stream served solo, whether a
+     group's batched state is passed whole to the next step or
+     regathered because the group's members changed.
 
 Wall-clock latency assertions are gated behind the fast-host convention
 (``REPRO_SLOW_HOST=1`` skips them); structure and counter assertions always
@@ -466,6 +468,151 @@ class TestStreamEngine:
         assert np.median(steady) < st.compute_ms[0]
 
 
+# ------------------------------------------------------- batched state --
+
+BATCH_CFG = EdgeConfig(nms=True, hysteresis=True, backend="xla",
+                       block_h=16, block_w=16)
+
+
+def _solo_outputs(fs, fps=30.0):
+    solo = StreamEngine(BATCH_CFG, collect=True)
+    solo.submit(StreamRequest(sid=0, frames=_list_source(fs), fps=fps))
+    return solo.run()[0].outputs
+
+
+def _assert_equal_solo(stats, streams, fps=None):
+    for sid, fs in streams.items():
+        want = _solo_outputs(fs, (fps or {}).get(sid, 30.0))
+        got = stats[sid].outputs
+        assert len(got) == len(want) == len(fs)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g["magnitude"], w["magnitude"])
+            np.testing.assert_array_equal(g["edges"], w["edges"])
+
+
+def _run_counting_gathers(eng):
+    """Drive ``eng`` to the end. Returns, per serving step, the groups it
+    served (tuples of sids in row order) and the sids whose
+    ``state_gathers`` rose in it."""
+    groups, gathers = [], []
+    serve = eng._serve_group
+
+    def recording(members):
+        groups[-1].append(tuple(eng.slots[i].req.sid for i in members))
+        serve(members)
+
+    eng._serve_group = recording
+
+    def counts():
+        stats = [s.stats for s in eng.slots if s is not None] + eng.finished
+        return {st.sid: st.state_gathers for st in stats}
+
+    before = counts()
+    while True:
+        groups.append([])
+        if not eng.step():
+            groups.pop()
+            break
+        after = counts()
+        gathers.append({sid for sid, n in after.items()
+                        if n != before.get(sid, 0)})
+        before = after
+    return groups, gathers
+
+
+def _moving(seed, n, h=40, w=48):
+    return [_frame(h=h, w=w, seed=seed + t) for t in range(n)]
+
+
+class TestBatchedState:
+    def test_genlocked_group_passes_state_whole(self):
+        """Four genlocked streams: from the second step on, the step call
+        gets the very state object the previous call returned (no slice,
+        no concat), across the masked and the cached step alike."""
+        # frames 0-2 move, 3-5 repeat frame 2: the last steps take the
+        # cached (no-kernel) call
+        streams = {sid: [_frame(seed=400 + 10 * sid + min(t, 2))
+                         for t in range(6)] for sid in range(4)}
+        eng = StreamEngine(BATCH_CFG, collect=True)
+        calls = []
+
+        def recording(fn, state_pos):
+            def call(*args, **kw):
+                result, new_state = fn(*args, **kw)
+                calls.append((fn, args[state_pos], new_state))
+                return result, new_state
+            return call
+
+        step, cached = eng._jit_step, eng._jit_cached
+        eng._jit_step = recording(step, 2)
+        eng._jit_cached = recording(cached, 1)
+        for sid, fs in streams.items():
+            eng.submit(StreamRequest(sid=sid, frames=_list_source(fs)))
+        stats = eng.run()
+        assert len(calls) == 6
+        assert [fn for fn, _, _ in calls] == [step] * 3 + [cached] * 3
+        for (_, _, returned), (_, passed, _) in zip(calls, calls[1:]):
+            assert passed is returned
+        assert all(st.state_gathers == 0 for st in stats.values())
+        assert all(st.frames == 6 for st in stats.values())
+        _assert_equal_solo(stats, streams)
+
+    @pytest.mark.parametrize("case", ["join", "retire", "fps", "straggler"])
+    def test_membership_change_regathers(self, case):
+        """Whenever a group's members differ from the batch that holds
+        their states, the rows are regathered: on exactly those steps,
+        with outputs still equal to each stream served alone and every
+        frame accounted for."""
+        kw, fps = {}, {}
+        if case == "join":
+            # sid 0 leaves after two frames; sid 2 waits in the queue,
+            # joins alone (cold), then shares a group with sid 1
+            streams = {0: _moving(500, 2), 1: _moving(510, 5),
+                       2: _moving(520, 3)}
+            kw = dict(max_streams=2)
+            expected = [set(), set(), set(), {1, 2}, set(), {1}]
+        elif case == "retire":
+            streams = {0: _moving(530, 3), 1: _moving(540, 5),
+                       2: _moving(550, 5)}
+            expected = [set(), set(), set(), {1, 2}, set()]
+        elif case == "fps":
+            # sid 1 at 15 fps is due every other step of its 30 fps
+            # group mates: every warm step serves another subset
+            streams = {0: _moving(560, 6), 1: _moving(570, 3),
+                       2: _moving(580, 6)}
+            fps = {1: 15.0}
+            expected = [set(), {0, 2}, {0, 1, 2}, {0, 2}, {0, 1, 2},
+                        {0, 2}]
+        else:
+            # sid 1 lags 100 ms a frame until it is struck out into a
+            # solo group; shedding is held off to keep every frame
+            streams = {sid: _moving(590 + 20 * sid, 12) for sid in range(3)}
+            kw = dict(chaos=FaultPlan.parse("slow@s1:100"),
+                      guard=GuardPolicy(shed_after=100))
+            expected = None
+        eng = StreamEngine(BATCH_CFG, collect=True, **kw)
+        for sid, fs in streams.items():
+            eng.submit(StreamRequest(sid=sid, frames=_list_source(fs),
+                                     fps=fps.get(sid, 30.0)))
+        groups, gathers = _run_counting_gathers(eng)
+        if expected is None:
+            solo = [j for j, gs in enumerate(groups) if (1,) in gs]
+            assert solo, "the straggler was never excluded"
+            k = solo[0]
+            assert groups[k] == [(0, 2), (1,)]
+            expected = [set()] * len(groups)
+            expected[k] = {0, 1, 2}
+        assert gathers == expected, groups
+        stats = {st.sid: st for st in eng.finished}
+        for sid, st in stats.items():
+            assert st.state_gathers == sum(sid in g for g in expected)
+        c = eng.health.counts
+        assert (c["served"] + c["retried"] + c["degraded"] + c["shed"]
+                + c["quarantined"]) == eng.health.submitted
+        assert eng.health.submitted == sum(map(len, streams.values()))
+        _assert_equal_solo(stats, streams, fps)
+
+
 # ----------------------------------------------------------------- spans --
 
 def _program_spans(log_dir):
@@ -545,3 +692,13 @@ class TestStreamSpans:
         attempts = [(s, e) for n, s, e, _ in spans
                     if n == "repro.guard.attempt" and a <= s and e <= b]
         assert len(attempts) == 2
+
+    def test_concat_marks_whole_state(self, traced_engine):
+        """The cold first serve builds a zero state; every later serve of
+        the same two streams passes the batch whole."""
+        eng, spans = traced_engine
+        whole = [m["whole"] for _, _, _, m in sorted(
+            (sp for sp in spans if sp[0] == "repro.stream.concat"),
+            key=lambda sp: sp[1])]
+        assert whole == [0, 1, 1, 1]
+        assert all(st.state_gathers == 0 for st in eng.finished)
