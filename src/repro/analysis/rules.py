@@ -100,9 +100,11 @@ RULES: Dict[str, Rule] = {
             "DTYPE001",
             "dtype-ladder",
             "u8 input × integer taps accumulates exactly in f32 (≤ 2^24), "
-            "and the traced kernel's actual integer accumulation dtype "
-            "(recovered from its u8→int entry cast) equals the narrowest "
-            "dtype the ladder proof licenses (core.ladder.accum_dtype)",
+            "u16 input keeps the integer lane exactly where its bound at "
+            "65535 does, and the traced kernel's actual integer "
+            "accumulation dtype (recovered from its u8/u16→int entry cast) "
+            "equals the narrowest dtype the ladder proof licenses for that "
+            "frame dtype (core.ladder.accum_dtype)",
             "PR 8 (spec proof) / PR 9 (kernel check)",
         ),
         Rule(
@@ -327,94 +329,148 @@ def check_contraction_fences(jaxpr, *, location: str) -> List[Violation]:
 # must all cite the *same* proof.
 
 
-def check_dtype_ladder(spec, *, location: str) -> List[Violation]:
-    """DTYPE001 (spec half): integer-tap operators must accumulate u8
-    input exactly in f32 (all intermediates ≤ 2^24) — the contract both
-    arithmetic lanes rely on: it is what makes the i16/i32 integer lane
-    bit-identical to the f32 lane by construction."""
-    b = tap_accumulation_bounds(spec)
+def check_dtype_ladder(spec, *, location: str, input_dtype="uint8"
+                       ) -> List[Violation]:
+    """DTYPE001 (spec half), for frames of ``input_dtype``.
+
+    u8: integer-tap operators must accumulate u8 input exactly in f32 (all
+    intermediates ≤ 2^24) — the contract both arithmetic lanes rely on: it
+    is what makes the i16/i32 integer lane bit-identical to the f32 lane by
+    construction. Deeper frames (u16, bound 65535): an operator whose bound
+    leaves f32's exact range (sobel7) keeps no integer lane there, so the
+    lane's gate (``core.ladder.int_lane_eligible``) must admit an operator
+    exactly when its bound at the dtype's largest value is f32-exact and
+    fits i32."""
+    from repro.core import ladder
+
+    m = ladder.frame_max(input_dtype)
+    b = tap_accumulation_bounds(spec, input_max=m)
     if not b["integer_taps"]:
         return []  # fractional taps opt out of the integer ladder
-    if b["f32_exact"]:
+    detail = (
+        ("input_max", str(m)),
+        ("worst", f"{b['worst']:.0f}"),
+        ("fits_i16", str(b["fits_i16"])),
+        ("fits_i32", str(b["fits_i32"])),
+    )
+    if jnp.dtype(input_dtype) == jnp.uint8:
+        if b["f32_exact"]:
+            return []
+        return [
+            Violation(
+                "DTYPE001",
+                location,
+                f"integer-tap accumulation bound {b['worst']:.0f} exceeds the "
+                f"f32-exact integer range (2^24); i16={b['fits_i16']}, "
+                f"i32={b['fits_i32']}",
+                detail=detail[1:],
+            )
+        ]
+    exact = b["f32_exact"] and b["fits_i32"]
+    admitted, _reason = ladder.int_lane_eligible(
+        spec, rgb=False, input_dtype=input_dtype
+    )
+    if admitted == exact:
         return []
     return [
         Violation(
             "DTYPE001",
             location,
-            f"integer-tap accumulation bound {b['worst']:.0f} exceeds the "
-            f"f32-exact integer range (2^24); i16={b['fits_i16']}, "
-            f"i32={b['fits_i32']}",
-            detail=(
-                ("worst", f"{b['worst']:.0f}"),
-                ("fits_i16", str(b["fits_i16"])),
-                ("fits_i32", str(b["fits_i32"])),
-            ),
+            f"the integer lane {'admits' if admitted else 'refuses'} "
+            f"{jnp.dtype(input_dtype).name} frames of operator {spec.name!r}, "
+            f"whose bound {b['worst']:.0f} at {m} "
+            f"{'fits' if exact else 'leaves'} the f32-exact range and i32",
+            detail=detail,
         )
     ]
 
 
 def check_kernel_accum_dtype(jaxpr, *, location: str, spec) -> List[Violation]:
     """DTYPE001 (kernel half): the integer lane's *actual* accumulation
-    dtype must equal the narrowest dtype the ladder proof licenses.
+    dtype must equal the narrowest dtype the ladder proof licenses for the
+    frame dtype it starts from.
 
-    The lane entry is the only place a traced program converts a u8
-    array (rank ≥ 2 — scalar index math never starts from u8) to a
-    signed integer: ``x.astype(accum_dtype)`` in the kernels, or the
-    XLA-path equivalent in ``sobel_components``/``thin_map``. The walk
-    descends into kernel bodies. No such cast ⇒ the trace is on the f32
-    lane and the check passes vacuously. A cast *narrower* than
-    :func:`repro.core.ladder.accum_dtype` — i16 where the bound needs
-    i32 — is the silent-wraparound bug this rule exists to catch; wider
-    (i16-licensed math run in i32, as the TPU lane does around Mosaic's
-    16-bit gaps) stays exact and passes, while anything beyond i32 has
-    no proof at all and fails.
+    The lane entry is the only place a traced program converts a u8 or u16
+    array (rank ≥ 2 — scalar index math never starts from them) to a
+    signed integer that it computes in: ``x.astype(accum_dtype)`` in the
+    kernels, or the XLA-path equivalent in ``sobel_components``/
+    ``thin_map``. A cast whose every use converts on to a float is the f32
+    lane widening through i32 (``tiling.as_f32``, as Mosaic requires), not
+    an entry. The walk descends into kernel bodies. No entry ⇒ the trace
+    is on the f32 lane and the check passes vacuously. An entry *narrower*
+    than :func:`repro.core.ladder.accum_dtype` of its frame dtype — i16
+    where the bound needs i32, as every u16 frame of sobel5 does — is the
+    silent-wraparound bug this rule exists to catch; wider (i16-licensed
+    math run in i32, as the TPU lane does around Mosaic's 16-bit gaps)
+    stays exact and passes, while anything beyond i32 has no proof at all
+    and fails.
     """
     from repro.core import ladder
 
     _WIDTH = {"int16": 16, "int32": 32}
-    seen: List[str] = []
-    for eqn in iter_jaxpr_eqns(jaxpr, opaque=()):
+    _SHORT = {"uint8": "u8", "uint16": "u16"}
+    eqns = list(iter_jaxpr_eqns(jaxpr, opaque=()))
+    uses: Dict[object, list] = {}
+    for eqn in eqns:
+        for v in eqn.invars:
+            if isinstance(v, jax_core.Var):
+                uses.setdefault(v, []).append(eqn)
+
+    def widens_to_float(var) -> bool:
+        return bool(uses.get(var)) and all(
+            u.primitive.name == "convert_element_type"
+            and jnp.issubdtype(u.outvars[0].aval.dtype, jnp.floating)
+            for u in uses[var]
+        )
+
+    seen: Dict[str, List[str]] = {}   # frame dtype -> accumulation dtypes
+    for eqn in eqns:
         if eqn.primitive.name != "convert_element_type":
             continue
         src = getattr(eqn.invars[0], "aval", None)
         dst = eqn.outvars[0].aval
         if src is None or len(getattr(dst, "shape", ())) < 2:
             continue
-        if src.dtype != jnp.uint8:
+        if src.dtype.name not in _SHORT:
             continue
         if not jnp.issubdtype(dst.dtype, jnp.signedinteger):
             continue
-        if str(dst.dtype) not in seen:
-            seen.append(str(dst.dtype))
-    if not seen:
-        return []
-    expected = ladder.accum_dtype(spec)
-    if expected is None:
-        return [
+        if widens_to_float(eqn.outvars[0]):
+            continue
+        found = seen.setdefault(src.dtype.name, [])
+        if str(dst.dtype) not in found:
+            found.append(str(dst.dtype))
+    out: List[Violation] = []
+    for frame, found in seen.items():
+        expected = ladder.accum_dtype(spec, input_dtype=frame)
+        if expected is None:
+            out.append(
+                Violation(
+                    "DTYPE001",
+                    location,
+                    f"integer accumulation ({', '.join(found)}) of "
+                    f"{_SHORT[frame]} frames in a trace of operator "
+                    f"{spec.name!r}, which has no proven integer budget "
+                    "for them (fractional taps or bound beyond 2^24)",
+                    detail=(("frame", frame), ("found", ",".join(found)),
+                            ("expected", "none")),
+                )
+            )
+            continue
+        out += [
             Violation(
                 "DTYPE001",
                 location,
-                f"integer accumulation ({', '.join(seen)}) in a trace of "
-                f"operator {spec.name!r}, which has no proven integer "
-                "budget (fractional taps or bound beyond 2^24)",
-                detail=(("found", ",".join(seen)), ("expected", "none")),
+                f"kernel accumulates {_SHORT[frame]} taps in {d}, but the "
+                f"ladder proof licenses {expected} for operator {spec.name!r}"
+                + ("" if d in _WIDTH else " (no proof covers this dtype)"),
+                detail=(("frame", frame), ("found", d),
+                        ("expected", expected)),
             )
+            for d in found
+            if d not in _WIDTH or _WIDTH[d] < _WIDTH[expected]
         ]
-    bad = [
-        d for d in seen
-        if d not in _WIDTH or _WIDTH[d] < _WIDTH[expected]
-    ]
-    return [
-        Violation(
-            "DTYPE001",
-            location,
-            f"kernel accumulates u8 taps in {d}, but the ladder proof "
-            f"licenses {expected} for operator {spec.name!r}"
-            + ("" if d in _WIDTH else " (no proof covers this dtype)"),
-            detail=(("found", d), ("expected", expected)),
-        )
-        for d in bad
-    ]
+    return out
 
 
 def _dma_op_counts(kernel_jaxpr) -> Dict[str, int]:

@@ -64,6 +64,7 @@ class Mode:
     export: bool = False  # part of the Mosaic export battery
     pipelined: bool = False  # manual DMA ring requested: PIPE001 applies
     gray_only: bool = False  # integer lane: RGB is ineligible by design
+    frame_dtype: str = "uint8"  # dtype of the traced frames
 
     def kw(self) -> Dict[str, object]:
         return dict(self.config_kw)
@@ -87,6 +88,10 @@ MODES: Dict[str, Mode] = {
         Mode("lowprec-pipelined",
              (("precision", "int"), ("pipeline_depth", 3), ("nms", True)),
              pipelined=True, gray_only=True, export=True),
+        # 12/16-bit sensor frames read raw: the integer lane at the u16
+        # bound (i32 for sobel5), traced only where the ladder admits it.
+        Mode("lowprec-u16", (("precision", "int"),), gray_only=True,
+             export=True, frame_dtype="uint16"),
     ]
 }
 
@@ -146,7 +151,7 @@ def _trace_combo(op: str, backend: str, padding: str, layout: str, mode: Mode):
     rgb = layout == "rgb"
     n, h, w = TRACE_SHAPE
     shape = (n, h, w, 3) if rgb else (n, h, w)
-    x = jnp.zeros(shape, jnp.uint8)
+    x = jnp.zeros(shape, mode.frame_dtype)
     if mode.stream:
         state = api.StreamState.init(n, h, w, cfg, rgb=rgb)
         jaxpr = jax.make_jaxpr(lambda f, s: api.edge_detect_stream(f, cfg, s))(
@@ -277,7 +282,7 @@ def _export_violations(op: str, layout: str, mode: Mode, report: Report) -> List
         block_w=EXPORT_BLOCK[1],
         **mode.kw(),
     )
-    x = jnp.zeros(shape, jnp.uint8)
+    x = jnp.zeros(shape, mode.frame_dtype)
     try:
         exported = jax_export.export(
             jax.jit(lambda a: api.edge_detect(a, cfg).magnitude), platforms=["tpu"]
@@ -300,14 +305,17 @@ def _export_violations(op: str, layout: str, mode: Mode, report: Report) -> List
 
 
 def _spec_violations(op: str, report: Report) -> List[Violation]:
+    from repro.core import ladder
     from repro.core.filters import get_operator
     from repro.kernels.edge import default_block_shape
 
     spec = get_operator(op)
     out: List[Violation] = []
     location = f"spec:{op}"
-    out += rules.check_dtype_ladder(spec, location=location)
-    report.checks += 1
+    for frame in ladder.INT_LANE_INPUTS:
+        out += rules.check_dtype_ladder(spec, location=location,
+                                        input_dtype=frame)
+        report.checks += 1
     # The fallback block chooser must respect the budget it was derived
     # from, at every service resolution, worst-case halo (NMS) included.
     for h, w in SERVICE_SHAPES:
@@ -360,6 +368,20 @@ def _source_violations(report: Report) -> List[Violation]:
     return out
 
 
+def _lane_admits(op: str, mode: Mode) -> bool:
+    """False where ``mode`` asks for the integer lane on frames whose bound
+    the ladder refuses for ``op`` (sobel7 at u16)."""
+    from repro.core import ladder
+    from repro.core.filters import get_operator
+
+    if dict(mode.config_kw).get("precision") != "int":
+        return True
+    ok, _reason = ladder.int_lane_eligible(
+        get_operator(op), rgb=False, input_dtype=mode.frame_dtype
+    )
+    return ok
+
+
 def analyze(
     *,
     operators: Optional[Sequence[str]] = None,
@@ -401,6 +423,8 @@ def analyze(
                         continue  # the DMA ring only exists on fused paths
                     if mode.gray_only and layout == "rgb":
                         continue  # explicit int on RGB raises by contract
+                    if not _lane_admits(op, mode):
+                        continue  # explicit int past the bound raises too
                     pads = paddings if (mode.all_paddings or not full) else ("reflect",)
                     if not mode.all_paddings:
                         pads = pads[:1]
@@ -418,7 +442,7 @@ def analyze(
         for op in operators if full else operators[:1]:
             for mode_name in mode_names:
                 mode = MODES[mode_name]
-                if not mode.export:
+                if not mode.export or not _lane_admits(op, mode):
                     continue
                 report.add(_export_violations(op, "gray", mode, report))
         for mode_name in mode_names:

@@ -107,12 +107,13 @@ class EdgeConfig:
                   None = auto. Outputs are bit-exact across backends.
       block_h/block_w: Pallas tile override; None = tuning cache / default.
       precision:  arithmetic lane: ``auto`` | ``f32`` | ``int``. ``int`` is
-                  the exact low-precision lane — u8 gray frames x integer
-                  taps accumulated in the i16/i32 budget
-                  ``repro.core.ladder`` proves, f32 only from the
-                  magnitude/NMS stage on — *bit-identical* to the f32 lane
-                  (it raises when the proof does not cover the workload:
-                  RGB, non-u8 input, fractional taps, oversized bound).
+                  the exact low-precision lane — u8 or u16 gray frames x
+                  integer taps accumulated in the i16/i32 budget
+                  ``repro.core.ladder`` proves for the frame's dtype, f32
+                  only from the magnitude/NMS stage on — *bit-identical*
+                  to the f32 lane (it raises when the proof does not cover
+                  the workload: RGB, other input dtypes, fractional taps,
+                  a bound past 2^24 such as sobel7's on u16 frames).
                   ``auto`` opts eligible workloads in on the Pallas
                   backends and stays f32 on XLA
                   (``repro.kernels.dispatch.resolve_precision``).

@@ -84,6 +84,17 @@ class ModelConfig:
     sobel_block_h: int = 0           # Pallas tile rows; 0 = tuning cache / default
     sobel_block_w: int = 0           # Pallas tile cols; 0 = tuning cache / default
     sobel_shard: str = ""            # image-mesh shard spec "DxRxC" | "auto"; "" = single device
+    frame_bits: int = 0              # bits a pixel the sensor delivers (8 -> u8, 9..16 -> u16 frames); 0 = f32 frames
+
+    @property
+    def frame_dtype(self) -> str:
+        """The dtype of the frames this deployment's source delivers: the
+        narrowest unsigned container of ``frame_bits``, else float32."""
+        if not self.frame_bits:
+            return "float32"
+        if not 1 <= self.frame_bits <= 16:
+            raise ValueError(f"frame_bits {self.frame_bits} is not 1..16")
+        return "uint8" if self.frame_bits <= 8 else "uint16"
 
     def edge_config(self, **overrides):
         """This config's image pipeline as a ``repro.api.EdgeConfig``."""
@@ -176,6 +187,7 @@ _MODULES = {
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b_a6_6b",
     "zamba2-2.7b": "zamba2_2_7b",
     "sobel-hd": "sobel_hd",
+    "inspect-12mp": "inspect_12mp",  # image deployment, not in ARCH_IDS
 }
 
 

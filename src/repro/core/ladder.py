@@ -1,10 +1,11 @@
 """Exact integer-accumulation budgets for the low-precision tap lane.
 
 The paper's "operator transformation" trick restructures the taps to cut
-arithmetic; the orthogonal precision trick is that a u8 frame correlated
-with *integer* taps never needs floating point at all: every intermediate
-the variant ladder materializes is an exact integer bounded by
-``input_max * sum(|taps|)``, so the whole gradient stage can run in
+arithmetic; the orthogonal precision trick is that a u8 or u16 frame
+correlated with *integer* taps never needs floating point at all: every
+intermediate the variant ladder materializes is an exact integer bounded by
+``input_max * sum(|taps|)`` (``input_max`` the frame dtype's largest value,
+:func:`frame_max`), so the whole gradient stage can run in
 i16/i32 and convert to f32 only at the magnitude/NMS boundary — and the
 result is *bit-identical* to the f32 lane, because both lanes compute the
 same exact integers (f32 holds every integer up to 2^24 exactly).
@@ -39,6 +40,8 @@ import numpy as np
 
 __all__ = [
     "F32_EXACT_INT",
+    "INT_LANE_INPUTS",
+    "frame_max",
     "tap_accumulation_bounds",
     "accum_dtype",
     "int_lane_eligible",
@@ -51,6 +54,29 @@ __all__ = [
 F32_EXACT_INT = 2**24
 _I16_MAX = 2**15 - 1
 _I32_MAX = 2**31 - 1
+
+# The frame dtypes the integer lane takes, each bounded by its largest
+# value: 8-bit video, and 12/16-bit sensor frames in 16-bit containers.
+INT_LANE_INPUTS = (np.dtype(np.uint8), np.dtype(np.uint16))
+
+
+def frame_max(input_dtype) -> Optional[int]:
+    """The largest value of an ``input_dtype`` frame, the bound every proof
+    here starts from; None for a dtype the integer lane does not take."""
+    dt = np.dtype(input_dtype)
+    return int(np.iinfo(dt).max) if dt in INT_LANE_INPUTS else None
+
+
+def _input_gate(input_dtype) -> Tuple[Optional[int], str]:
+    """(input bound, reason): the first gate of both eligibility checks."""
+    m = frame_max(input_dtype)
+    if m is None:
+        names = "/".join(d.name for d in INT_LANE_INPUTS)
+        return None, (
+            f"input dtype {np.dtype(input_dtype).name} is not {names} — the "
+            "integer bound only covers unsigned 8/16-bit frames"
+        )
+    return m, ""
 
 
 def tap_accumulation_bounds(spec, *, input_max: int = 255) -> Dict[str, object]:
@@ -80,12 +106,13 @@ def tap_accumulation_bounds(spec, *, input_max: int = 255) -> Dict[str, object]:
     }
 
 
-def accum_dtype(spec, *, input_max: int = 255) -> Optional[str]:
+def accum_dtype(spec, *, input_dtype="uint8") -> Optional[str]:
     """Narrowest exact integer accumulation dtype for the spec, or None.
 
     Returns ``"int16"``/``"int32"`` when the integer lane is provably
-    bit-exact against the f32 lane for ``input_max``-bounded (u8) input,
-    else ``None``. Three conditions, all from the same
+    bit-exact against the f32 lane for ``input_dtype`` frames (bounded by
+    :func:`frame_max`: 255 for u8, 65535 for u16), else ``None``. Three
+    conditions, all from the same
     :func:`tap_accumulation_bounds` computation DTYPE001 checks:
 
       * integer taps — fractional taps have no exact integer form;
@@ -94,7 +121,13 @@ def accum_dtype(spec, *, input_max: int = 255) -> Optional[str]:
         the *f32* lane itself rounds, so "bit-identical by construction"
         would have nothing exact to be identical to.
     """
-    b = tap_accumulation_bounds(spec, input_max=input_max)
+    m = frame_max(input_dtype)
+    if m is None:
+        return None
+    return _narrowest(tap_accumulation_bounds(spec, input_max=m))
+
+
+def _narrowest(b) -> Optional[str]:
     if not b["integer_taps"] or not b["f32_exact"]:
         return None
     if b["fits_i16"]:
@@ -104,9 +137,7 @@ def accum_dtype(spec, *, input_max: int = 255) -> Optional[str]:
     return None
 
 
-def int_lane_eligible(
-    spec, *, rgb: bool, input_dtype=None, input_max: int = 255
-) -> Tuple[bool, str]:
+def int_lane_eligible(spec, *, rgb: bool, input_dtype="uint8") -> Tuple[bool, str]:
     """(eligible, reason) for running the exact integer lane.
 
     ``reason`` explains the *first* failing gate when ineligible (used
@@ -122,12 +153,10 @@ def int_lane_eligible(
             "RGB input needs the fractional BT.601 luma, whose fenced f32 "
             "rounding has no bit-exact fixed-point equivalent"
         )
-    if input_dtype is not None and np.dtype(input_dtype) != np.dtype(np.uint8):
-        return False, (
-            f"input dtype {np.dtype(input_dtype).name} is not uint8 — the "
-            "integer bound only covers [0, 255] integer frames"
-        )
-    b = tap_accumulation_bounds(spec, input_max=input_max)
+    m, reason = _input_gate(input_dtype)
+    if m is None:
+        return False, reason
+    b = tap_accumulation_bounds(spec, input_max=m)
     if not b["integer_taps"]:
         return False, f"operator {spec.name!r} has fractional taps"
     if not b["f32_exact"]:
@@ -191,9 +220,7 @@ def plan_input_bound(plan, *, input_max: int = 255):
     return m, ""
 
 
-def plan_int_eligible(
-    plan, *, rgb: bool, input_dtype=None, input_max: int = 255
-) -> Tuple[bool, str]:
+def plan_int_eligible(plan, *, rgb: bool, input_dtype="uint8") -> Tuple[bool, str]:
     """Plan-level (eligible, reason) for the exact integer lane."""
     spec = plan.gradient
     if spec is None:
@@ -202,20 +229,16 @@ def plan_int_eligible(
             "covers gradient plans only"
         )
     if not plan.pre_stages:
-        return int_lane_eligible(
-            spec, rgb=rgb, input_dtype=input_dtype, input_max=input_max
-        )
+        return int_lane_eligible(spec, rgb=rgb, input_dtype=input_dtype)
     if rgb:
         return False, (
             "RGB input needs the fractional BT.601 luma, whose fenced f32 "
             "rounding has no bit-exact fixed-point equivalent"
         )
-    if input_dtype is not None and np.dtype(input_dtype) != np.dtype(np.uint8):
-        return False, (
-            f"input dtype {np.dtype(input_dtype).name} is not uint8 — the "
-            "integer bound only covers [0, 255] integer frames"
-        )
-    m, reason = plan_input_bound(plan, input_max=input_max)
+    m, reason = _input_gate(input_dtype)
+    if m is None:
+        return False, reason
+    m, reason = plan_input_bound(plan, input_max=m)
     if m is None:
         return False, reason
     b = tap_accumulation_bounds(spec, input_max=m)
@@ -231,21 +254,17 @@ def plan_int_eligible(
     return True, ""
 
 
-def plan_accum_dtype(plan, *, input_max: int = 255) -> Optional[str]:
+def plan_accum_dtype(plan, *, input_dtype="uint8") -> Optional[str]:
     """Narrowest exact integer accumulation dtype for the whole plan."""
     spec = plan.gradient
     if spec is None:
         return None
     if not plan.pre_stages:
-        return accum_dtype(spec, input_max=input_max)
-    m, _reason = plan_input_bound(plan, input_max=input_max)
+        return accum_dtype(spec, input_dtype=input_dtype)
+    m = frame_max(input_dtype)
     if m is None:
         return None
-    b = tap_accumulation_bounds(spec, input_max=m)
-    if not b["integer_taps"] or not b["f32_exact"]:
+    m, _reason = plan_input_bound(plan, input_max=m)
+    if m is None:
         return None
-    if b["fits_i16"]:
-        return "int16"
-    if b["fits_i32"]:
-        return "int32"
-    return None
+    return _narrowest(tap_accumulation_bounds(spec, input_max=m))

@@ -173,7 +173,7 @@ def thin_map(
 
     ``precision="int"`` runs the gradient ladder in the exact integer
     accumulation dtype ``repro.core.ladder`` proves (the caller must have
-    gated eligibility: u8-valued gray, integer taps, budget fits); the
+    gated eligibility: u8/u16 gray, integer taps, budget fits); the
     components are cast to f32 before the magnitude/NMS stage, which stays
     f32 by contract — bit-identical to the default lane.
     """
@@ -182,8 +182,9 @@ def thin_map(
     if precision == "int":
         from repro.core import ladder
 
-        acc = (ladder.plan_accum_dtype(plan) if plan is not None
-               else ladder.accum_dtype(spec))
+        acc = (ladder.plan_accum_dtype(plan, input_dtype=gray.dtype)
+               if plan is not None
+               else ladder.accum_dtype(spec, input_dtype=gray.dtype))
         if acc is None:
             raise ValueError(
                 f"precision='int' unavailable for operator {spec.name!r}"

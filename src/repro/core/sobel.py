@@ -364,8 +364,8 @@ def sobel_components(
     ``operator``/``size``; the plan must carry a gradient stage (this
     function returns direction components).
 
-    ``precision="int"`` runs the exact low-precision lane: uint8 input cast
-    to the i16/i32 budget proved by ``repro.core.ladder``, gradients
+    ``precision="int"`` runs the exact low-precision lane: uint8/uint16 input
+    cast to the i16/i32 budget proved by ``repro.core.ladder``, gradients
     accumulated in integers, components cast to f32 on return —
     bit-identical to the default f32 lane (both compute the same exact
     integers). Raises for inputs/operators the budget does not cover.
@@ -395,12 +395,12 @@ def sobel_components(
             ok, reason = ladder.plan_int_eligible(
                 plan, rgb=False, input_dtype=image.dtype
             )
-            acc = ladder.plan_accum_dtype(plan)
+            acc = ladder.plan_accum_dtype(plan, input_dtype=image.dtype)
         else:
             ok, reason = ladder.int_lane_eligible(
                 spec, rgb=False, input_dtype=image.dtype
             )
-            acc = ladder.accum_dtype(spec)
+            acc = ladder.accum_dtype(spec, input_dtype=image.dtype)
         if not ok:
             raise ValueError(f"precision='int' unavailable: {reason}")
         x = image.astype(jnp.dtype(acc))

@@ -73,7 +73,14 @@ def lm_batch(
 def image_batch(
     cfg: ModelConfig, batch: int, *, seed: int = 0, step: int = 0
 ) -> Dict[str, np.ndarray]:
-    """Batch of synthetic images (blocks + gradients => real edges)."""
+    """Batch of synthetic images (blocks + gradients => real edges) in the
+    dtype the config's sensor delivers (``cfg.frame_dtype``).
+
+    Float frames hold the scene in [0, 255]. Integer frames of
+    ``cfg.frame_bits`` bits (12-bit Mono12 in u16 containers, say) span
+    their sensor's whole range: the scene scaled to full scale, with a
+    saturated highlight at the largest value and a shadow at 0 in opposite
+    corners."""
     rng = np.random.default_rng((seed * 1_000_003 + step) & 0x7FFFFFFF)
     h, w = cfg.image_h, cfg.image_w
     yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
@@ -83,7 +90,14 @@ def image_batch(
         cx, cy, r = rng.uniform(0, w), rng.uniform(0, h), rng.uniform(min(h, w) / 8, min(h, w) / 3)
         disk = ((xx - cx) ** 2 + (yy - cy) ** 2) < r * r
         imgs[i] = np.clip(base + 120.0 * disk + rng.normal(0, 2, (h, w)), 0, 255)
-    return {"images": imgs}
+    if not cfg.frame_bits:
+        return {"images": imgs}
+    full = float((1 << cfg.frame_bits) - 1)
+    frames = np.rint(imgs * np.float32(full / 255.0))
+    ch, cw = max(1, h // 8), max(1, w // 8)
+    frames[:, :ch, :cw] = full
+    frames[:, h - ch:, w - cw:] = 0.0
+    return {"images": frames.astype(cfg.frame_dtype)}
 
 
 def video_frame(

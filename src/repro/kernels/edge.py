@@ -27,7 +27,7 @@ size-specialized kernels this module replaced:
 
 Two orthogonal lanes thread through both pipelines:
 
-  * ``precision="int"`` — the exact low-precision lane: u8 frames x
+  * ``precision="int"`` — the exact low-precision lane: u8/u16 frames x
     integer taps accumulated in the i16/i32 dtype ``repro.core.ladder``
     proves, cast to f32 only at the magnitude/NMS boundary. Bit-identical
     to the f32 lane by construction (both compute the same exact
@@ -93,6 +93,7 @@ __all__ = [
     "edge_stream_pallas",
     "default_block_shape",
     "kernel_dtype",
+    "kernel_name",
 ]
 
 # Per-block maxima leave the kernel as one 128-lane row per grid step (the
@@ -180,16 +181,30 @@ def default_block_shape(
     return bh, bw
 
 
+# Integer frame dtypes the kernels read raw from HBM and widen per block in
+# VMEM, the integer lane's inputs: 8-bit video (4x less input traffic than
+# f32) and 12/16-bit sensor frames in 16-bit containers (2x less).
+RAW_DTYPES = ladder.INT_LANE_INPUTS
+
+
 def kernel_dtype(x: jnp.ndarray) -> jnp.ndarray:
     """The repo-wide kernel dtype policy.
 
-    ``uint8`` is kept as-is (4x less HBM input traffic; the kernel casts
-    per-block in VMEM); every other integer/bool/float dtype is cast to
-    float32 here (the kernels compute in f32 everywhere).
+    ``uint8`` and ``uint16`` are kept as-is (the kernel casts per block in
+    VMEM); every other integer/bool/float dtype is cast to float32 here
+    (the kernels compute in f32 everywhere).
     """
-    if x.dtype == jnp.uint8:
+    if x.dtype in RAW_DTYPES:
         return x
     return x.astype(jnp.float32)
+
+
+def kernel_name(dtype, lane: str, *, stream: bool = False) -> str:
+    """The ``pallas_call``'s name, as the device trace shows it: the input
+    dtype the kernel reads and the lane it computes in (the integer lane's
+    accumulation dtype, else f32), e.g. ``edge_u16_int32``."""
+    dt = jnp.dtype(dtype)
+    return f"edge_{'stream_' if stream else ''}{dt.kind}{dt.itemsize * 8}_{lane}"
 
 
 # ---------------------------------------------------------------------------
@@ -504,7 +519,7 @@ def edge_pallas(
 ):
     """Fused megakernel on the raw batch — any registered operator, any (H, W).
 
-    ``x``: ``(N, H, W)`` grayscale (u8 or f32), or ``(N, H, W, 3)`` RGB when
+    ``x``: ``(N, H, W)`` grayscale (u8, u16 or f32), or ``(N, H, W, 3)`` RGB when
     ``rgb`` (BT.601 luma applied per-tile in VMEM).
 
     Outputs, in order (a bare array when only one):
@@ -524,7 +539,7 @@ def edge_pallas(
     ``variant``/``directions`` must be valid for the operator (resolve via
     the spec first; see ``repro.api`` / ``repro.kernels.dispatch``).
 
-    ``precision="int"`` runs the exact integer lane (u8 gray input only;
+    ``precision="int"`` runs the exact integer lane (u8/u16 gray input only;
     raises with the first failing eligibility gate otherwise — see
     ``repro.core.ladder``); outputs stay f32 and bit-identical to the
     default lane. ``pipeline_depth=0`` (default) uses Pallas's automatic
@@ -586,8 +601,11 @@ def edge_pallas(
             )
         if not ok:
             raise ValueError(f"precision='int' unavailable: {reason}")
-        acc_dtype = (ladder.plan_accum_dtype(plan) if plan is not None
-                     else ladder.accum_dtype(spec))
+        acc_dtype = (
+            ladder.plan_accum_dtype(plan, input_dtype=x.dtype)
+            if plan is not None
+            else ladder.accum_dtype(spec, input_dtype=x.dtype)
+        )
         if not interpret and acc_dtype == "int16":
             # Mosaic's 16-bit vector coverage is incomplete (e.g. no i16
             # neg); i32 holds every i16-bounded intermediate exactly, so
@@ -608,6 +626,7 @@ def edge_pallas(
     reach = plan.linear_reach if plan is not None else spec.radius
     r_in = window_radius(reach, out_nms)
     in_spec = window_spec(h, w, bh, bw, r_in, channels=3 if rgb else None)
+    name = kernel_name(x.dtype, acc_dtype or "f32")
     x = _planar(x, rgb)
 
     plane = pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j))
@@ -701,6 +720,7 @@ def edge_pallas(
                 dimension_semantics=("arbitrary",) * 3
             ),
             interpret=interpret,
+            name=name,
         )(pad_to_windows(x, bh, bw, r_in))
     else:
         kernel = functools.partial(_kernel, **common)
@@ -711,6 +731,7 @@ def edge_pallas(
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name=name,
         )(x)
     out = list(out)
     if with_max:
@@ -815,6 +836,7 @@ def edge_stream_pallas(
             _bmax_shape(n, gh, gw),
         ],
         interpret=interpret,
+        name=kernel_name(x.dtype, "f32", stream=True),
     )(
         mask.astype(jnp.int32),
         _planar(x, rgb),

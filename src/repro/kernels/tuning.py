@@ -501,8 +501,8 @@ def sweep(
     :class:`~repro.core.filters.StencilPlan` or registered plan name)
     overrides both and times the fused multi-stage kernel with its
     composed halo. ``precision="int"`` times the
-    exact integer lane — pass ``dtype="uint8"`` with it (the lane rejects
-    anything else).
+    exact integer lane — pass ``dtype="uint8"`` or ``"uint16"`` with it
+    (the lane rejects anything else).
     """
     import jax.numpy as jnp
 

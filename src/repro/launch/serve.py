@@ -3,8 +3,10 @@
 LM architectures: continuous-batching engine over randomly generated prompt
 traffic; reports token throughput and per-request latency percentiles.
 
-Image architectures (``sobel-hd``): frame-serving loop over synthetic camera
-traffic through the ``repro.api`` facade — the arch's ``EdgeConfig``
+Image architectures (``sobel-hd``, ``inspect-12mp``): frame-serving loop
+over synthetic camera traffic, in the dtype the arch's sensor delivers
+(``ModelConfig.frame_dtype``: f32, or 12-bit u16 frames for
+``inspect-12mp``), through the ``repro.api`` facade — the arch's ``EdgeConfig``
 (operator / directions / variant / backend / block overrides) is threaded
 verbatim into :func:`repro.api.edge_detect`; reports megapixels/second and
 per-batch latency percentiles (the paper's Table 2 metric). ``--edges``
@@ -129,6 +131,7 @@ def serve_image(cfg, args, on_result=None) -> dict:
         f"serving {cfg.name}: operator={edge_cfg.operator} "
         f"variant={edge_cfg.variant} directions={edge_cfg.directions} "
         f"backend={edge_cfg.backend} {cfg.image_h}x{cfg.image_w} "
+        f"{cfg.frame_dtype} "
         f"devices={len(pop)} shard={shard_spec or 'none'}"
         f"{' mode=edges (NMS+hysteresis)' if args.edges else ''}"
         f"{f' chaos={args.chaos!r}' if args.chaos else ''}"

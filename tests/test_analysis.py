@@ -376,6 +376,48 @@ def test_bad_narrow_accumulation_trips_dtype001_only():
     ) == []
 
 
+def test_dtype001_covers_u16_frames(monkeypatch):
+    """Both halves at the u16 bound (65535). The spec half: the integer
+    lane admits an operator's u16 frames exactly where its bound there is
+    f32-exact and fits i32 (sobel5 yes, sobel7 no). The kernel half: the
+    traced u16 integer lane of sobel5 enters i32; an i16 entry wraps; the
+    f32 lane's widening through i32 is no entry."""
+    from repro.core import ladder
+
+    for name in ("sobel3", "sobel5", "scharr3", "prewitt3", "sobel7"):
+        spec = get_operator(name)
+        assert analysis.check_dtype_ladder(
+            spec, location="t", input_dtype="uint16") == [], name
+    assert not analysis.tap_accumulation_bounds(
+        get_operator("sobel7"), input_max=65535)["f32_exact"]
+    # a gate that admitted every operator would let sobel7's u16 frames in
+    monkeypatch.setattr(ladder, "int_lane_eligible", lambda *a, **k: (True, ""))
+    vios = analysis.check_dtype_ladder(get_operator("sobel7"), location="t",
+                                       input_dtype="uint16")
+    assert _rule_ids(vios) == {"DTYPE001"} and "admits uint16" in vios[0].message
+    monkeypatch.undo()
+
+    spec5, spec7 = get_operator("sobel5"), get_operator("sobel7")
+    x16 = jnp.zeros((1, 64, 96), jnp.uint16)
+    lane = jax.make_jaxpr(lambda a: ekern.edge_pallas(
+        a, block_h=16, block_w=32, precision="int", interpret=True))(x16)
+    assert analysis.check_kernel_accum_dtype(lane, location="t", spec=spec5) == []
+    f32_lane = jax.make_jaxpr(lambda a: ekern.edge_pallas(
+        a, operator="sobel7", block_h=16, block_w=32, interpret=True))(x16)
+    assert analysis.check_kernel_accum_dtype(
+        f32_lane, location="t", spec=spec7) == []
+
+    narrow = jax.make_jaxpr(
+        lambda a: (a.astype(jnp.int16) * 2).astype(jnp.float32))(x16)
+    vios = analysis.check_kernel_accum_dtype(narrow, location="t", spec=spec5)
+    assert _rule_ids(vios) == {"DTYPE001"}
+    assert "accumulates u16 taps in int16" in vios[0].message
+    wide = jax.make_jaxpr(
+        lambda a: (a.astype(jnp.int32) * 2).astype(jnp.float32))(x16)
+    vios = analysis.check_kernel_accum_dtype(wide, location="t", spec=spec7)
+    assert _rule_ids(vios) == {"DTYPE001"} and "no proven" in vios[0].message
+
+
 def test_bad_wrong_radius_ring_trips_halo001():
     """HALO001's ring branch: a manual-DMA kernel whose ring slots are
     sized for r=1 cannot be feeding an r=2 stencil — probed against the

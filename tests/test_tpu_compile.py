@@ -116,6 +116,29 @@ def test_serving_step_compiles_for_v5e(topo, edges):
     _compile(topo, lambda f: edge_detect(f, ecfg), (HD, jnp.float32))
 
 
+def test_u16_inspection_step_compiles_for_v5e(topo):
+    """The ``inspect-12mp`` request (one 3000x4096 12-bit frame in a u16
+    container), the fused ``with_max`` kernel in the lane ``auto`` picks:
+    the frame reaches the kernel's custom call as ``u16``, with no f32
+    copy of it made before the kernel."""
+    import re
+
+    from repro.api import edge_detect
+    from repro.configs import get_config
+    from repro.launch.serve import image_edge_config
+
+    cfg = get_config("inspect-12mp").replace(sobel_backend="pallas-tpu")
+    ecfg = image_edge_config(cfg)
+    shape = (1, cfg.image_h, cfg.image_w)
+    text = _compile(topo, lambda f: edge_detect(f, ecfg), (shape, jnp.uint16))
+    plane = "[{},{},{}]".format(*shape)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and re.search(r"%edge_u16_(int32|f32)", calls[0])
+    assert f"u16{plane}" in calls[0]
+    assert not [ln for ln in text.splitlines()
+                if " convert(" in ln and f"f32{plane}" in ln]
+
+
 @pytest.mark.parametrize("backend", ["pallas-tpu", "xla"])
 def test_stream_engine_step_compiles_for_v5e(topo, backend):
     """The stream engine's jitted programs (``serve/streams.py``) on a warm
