@@ -468,7 +468,9 @@ def edge_detect_stream(
       * **Delta-skip tiles** — a per-tile exact change test against
         ``state.frame``; unchanged tiles splice the cached thin map and
         per-block maxima instead of recomputing (``result.skipped`` counts
-        them per stream). Output is bit-identical to full recompute.
+        them per stream). When no tile of the batch changed, a branch on
+        the device skips the frame compute outright. Output is
+        bit-identical to full recompute.
       * **Temporal hysteresis** — with ``config.temporal``, recent frames'
         edges seed this frame's linking (decayed by ``config.decay``), so
         detections persist instead of flickering. ``decay=0`` is

@@ -612,8 +612,8 @@ def stream_delta(
     raw per-block diff OR-dilated by the window reach so halo reads are
     honored — and the ``(B,)`` int32 count of skippable tiles. An
     uninitialized state marks every tile changed (the caches are zeros,
-    not frame -1). Fully traceable; the serve engine also calls it alone
-    to host-check for the all-static fast path.
+    not frame -1). Fully traceable; :func:`edge_stream` runs it inside
+    its call and branches on it on the device.
     """
     bh, bw = state.block
     h, w = (x.shape[-3], x.shape[-2]) if rgb else (x.shape[-2], x.shape[-1])
@@ -733,7 +733,6 @@ def edge_stream(
     state: Optional["StreamState"] = None,
     *,
     layout: Optional[str] = None,
-    changed: Optional[jnp.ndarray] = None,
     tuning_cache: Optional[tuning.TuningCache] = None,
 ) -> tuple:
     """One streaming frame step: delta-skip compute + temporal epilogue.
@@ -742,19 +741,21 @@ def edge_stream(
     batch ``NHW``/``NHWC`` (time is the successive calls, so video-stack
     layouts are rejected). ``state`` is the previous step's
     :class:`~repro.api.StreamState` (``None`` = cold start: every tile
-    recomputes and the caches fill). ``changed`` lets a caller that
-    already ran :func:`stream_delta` (the serve engine's all-static host
-    check) pass the mask in instead of recomputing it.
+    recomputes and the caches fill). The per-tile change test
+    (:func:`stream_delta`) runs inside this call.
 
-    Backend split:
+    On an initialized state the frame compute sits behind a ``lax.cond``
+    on the device: when no tile of the whole batch changed, the cached
+    primary map and block maxima are this frame's, with no kernel launch
+    and no host round trip — the outputs are those of
+    :func:`edge_stream_cached`. Otherwise the backend's compute runs:
 
       * Pallas backends run the masked-grid megakernel
         (``kernels.edge.edge_stream_pallas``): flagged tiles recompute,
         the rest branch to a cached-tile splice.
       * XLA recomputes the frame and splices per-tile with a select — the
         mask is accounting there (XLA fuses the whole frame; its real
-        delta win is the engine's whole-frame short-circuit onto
-        :func:`edge_stream_cached`).
+        delta win is the whole-batch short-circuit above).
 
     Either way the output is bit-identical to stateless full recompute
     (unchanged input windows reproduce identical arithmetic), which the
@@ -798,35 +799,45 @@ def edge_stream(
             f"{x.shape}; streams of different shape need their own state"
         )
 
-    if changed is None:
-        changed, skipped = stream_delta(x, state, config, rgb=rgb)
-    else:
-        gh, gw = state.grid
-        skipped = jnp.int32(gh * gw) - jnp.sum(
-            changed.astype(jnp.int32), axis=(-2, -1)
-        )
+    changed, skipped = stream_delta(x, state, config, rgb=rgb)
 
-    if backend == "xla":
-        run = _backend_compute(
-            config, backend, rgb=rgb, need_comps=False,
-            need_raw=config.nms, block_h=None, block_w=None,
-        )
-        fresh, _comps, raw = run(x)
-        fresh_bmax = _block_reduce_max(raw if raw is not None else fresh,
-                                       bh, bw)
-        pixel_mask = jnp.repeat(
-            jnp.repeat(changed, bh, axis=-2), bw, axis=-1
-        )[:, :h, :w]
-        primary = jnp.where(pixel_mask, fresh, state.primary)
-        bmax = jnp.where(changed, fresh_bmax, state.bmax)
-    else:
-        primary, bmax = ekern.edge_stream_pallas(
+    def fresh():
+        if backend == "xla":
+            run = _backend_compute(
+                config, backend, rgb=rgb, need_comps=False,
+                need_raw=config.nms, block_h=None, block_w=None,
+            )
+            full, _comps, raw = run(x)
+            full_bmax = _block_reduce_max(raw if raw is not None else full,
+                                          bh, bw)
+            pixel_mask = jnp.repeat(
+                jnp.repeat(changed, bh, axis=-2), bw, axis=-1
+            )[:, :h, :w]
+            return (jnp.where(pixel_mask, full, state.primary),
+                    jnp.where(changed, full_bmax, state.bmax))
+        return ekern.edge_stream_pallas(
             x, state.primary, state.bmax, changed.astype(jnp.int32),
             operator=config.operator, variant=config.variant,
             params=config.params, directions=config.directions,
             padding=config.padding, block_h=bh, block_w=bw, rgb=rgb,
             out_nms=config.nms, interpret=(backend == "pallas-interpret"),
         )
+
+    def cached():
+        # Each array passes through an exact identity that XLA keeps as an
+        # op, so this branch writes buffers of its own: a branch returning
+        # the state's arrays as they are makes XLA copy them ahead of the
+        # cond, on every step that computes.
+        return jax.tree.map(
+            lambda a: jax.lax.reduce_precision(a, exponent_bits=8,
+                                               mantissa_bits=23),
+            (state.primary, state.bmax),
+        )
+
+    if state.initialized:
+        primary, bmax = jax.lax.cond(jnp.any(changed), fresh, cached)
+    else:
+        primary, bmax = fresh()
 
     return _stream_epilogue(
         x, config, state, primary, bmax, skipped,
@@ -840,15 +851,15 @@ def edge_stream_cached(
     *,
     layout: str = "NHW",
 ) -> tuple:
-    """The all-static fast path: a frame step with no frame compute.
+    """The all-static step: a frame step with no frame compute.
 
-    When the serve engine's host-side check of :func:`stream_delta` shows
-    zero changed tiles across the whole group, the kernel launch (and even
-    the frame's HBM read) is skipped outright — the cached primary map and
-    block maxima ARE this frame's outputs. Only the epilogue runs, because
-    it still must: the temporal seed strength decays every frame (edges
-    can disappear on a static scene as their seeds expire) and
-    normalization/linking read the cached values. Bit-identical to
+    When :func:`stream_delta` finds no changed tile across the whole
+    batch, the cached primary map and block maxima ARE this frame's
+    outputs. Only the epilogue runs, because it still must: the temporal
+    seed strength decays every frame (edges can disappear on a static
+    scene as their seeds expire) and normalization/linking read the
+    cached values. :func:`edge_stream` takes this branch on the device
+    by itself; this function is its reference, bit-identical to
     :func:`edge_stream` on the same static frame.
     """
     config = config.resolved()

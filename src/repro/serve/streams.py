@@ -26,22 +26,24 @@ state is temporal edge state instead of a KV cache:
     stream's row stays in its batch until the group's next regather, so a
     batch holds at most one frame's state per stream that has left it.
     Batching is an execution detail, never a semantic one.
-  * **Delta-skip dispatch.** Before computing, the engine runs the per-tile
-    change test (``dispatch.stream_delta``) and host-checks it: a fully
-    static group takes ``dispatch.edge_stream_cached`` — no kernel launch
-    at all, just the cheap epilogue — while a partially changed group runs
-    the masked-grid kernel that recomputes only flagged tiles.
+  * **Delta-skip dispatch.** A group serve is one jitted
+    ``dispatch.edge_stream`` call. Inside it the per-tile change test
+    (``dispatch.stream_delta``) picks, on the device, between the cached
+    maps of a fully static group — no kernel launch at all, just the
+    cheap epilogue — and the masked-grid kernel that recomputes only
+    flagged tiles. The host learns which it was from the per-stream
+    skipped-tile counts it reads back anyway (``StreamStats.cached_steps``).
   * **Split timing.** Each group serve records two host-clock times on its
     members' :class:`StreamStats`: ``transfer_ms`` covers the host
     ``np.stack`` of the members' frames and their transfer to the device
     (``block_until_ready`` on the device-put); ``compute_ms`` covers the
-    choice of the group's state (passed whole, regathered or zero-filled),
-    the delta test and the compute, but no queue wait. The step's phases
+    choice of the group's state (passed whole, regathered or zero-filled)
+    and the step call, but no queue wait. The step's phases
     are also profiler spans named ``repro.stream.*`` (``repro.guard.*``
     for the retry ladder), on the device trace's clock: ``step`` (metadata
     ``step``, ``frames``, ``groups``), ``intake``, ``stack``, ``h2d``,
     ``concat`` (the choice of state; metadata ``whole``: 1 when the batch
-    was passed whole, else 0), ``delta``, ``compute``, ``split`` (pointing
+    was passed whole, else 0), ``compute``, ``split`` (pointing
     the members at the new batch), ``account`` and ``police``.
 
 Batched streams share their group's step latency — a reported per-stream
@@ -269,15 +271,9 @@ class StreamEngine:
         )
 
     def _make_jits(self) -> None:
-        """(Re)build the jitted step functions — fresh after device loss."""
-        self._jit_delta = jax.jit(
-            dispatch.stream_delta, static_argnames=("rgb",)
-        )
+        """(Re)build the jitted step function — fresh after device loss."""
         self._jit_step = jax.jit(
             dispatch.edge_stream, static_argnames=("layout",)
-        )
-        self._jit_cached = jax.jit(
-            dispatch.edge_stream_cached, static_argnames=("layout",)
         )
 
     # -- public API ----------------------------------------------------------
@@ -443,35 +439,19 @@ class StreamEngine:
                     s.solo = True
 
     def _exec_group(self, cfg, frames, state, layout):
-        """One guarded group serve: delta host-check, cached or masked step.
+        """One guarded group serve: one jitted step call, no host read.
 
+        The step runs the delta test and chooses between the cached maps
+        and the masked compute on the device (``dispatch.edge_stream``).
         Runs under :class:`~repro.serve.guard.StepGuard` — ``cfg`` is the
         primary or fallback config depending on the rung. Blocks on the
         result so failures surface here, inside the retry ladder.
         """
-        rgb = layout.endswith("C")
-        if state.initialized:
-            with jax.profiler.TraceAnnotation("repro.stream.delta"):
-                changed, _skipped = self._jit_delta(frames, state, cfg,
-                                                    rgb=rgb)
-                static = not bool(jax.device_get(jnp.any(changed)))
-        else:
-            changed, static = None, False
         with jax.profiler.TraceAnnotation("repro.stream.compute"):
-            if static:
-                # Whole group unchanged: skip the kernel launch outright —
-                # the cached maps ARE this frame's outputs; only the
-                # (temporal) epilogue runs. Bit-identical to the masked
-                # kernel on the same frames, and the XLA backend's real
-                # delta win.
-                result, new_state = self._jit_cached(cfg, state,
-                                                     layout=layout)
-            else:
-                result, new_state = self._jit_step(
-                    frames, cfg, state, layout=layout, changed=changed
-                )
+            result, new_state = self._jit_step(frames, cfg, state,
+                                               layout=layout)
             jax.block_until_ready(result)
-        return result, new_state, static
+        return result, new_state
 
     def _serve_group(self, members: List[int]) -> None:
         slots = [self.slots[i] for i in members]
@@ -489,7 +469,7 @@ class StreamEngine:
         with jax.profiler.TraceAnnotation("repro.stream.concat") as span:
             state, how = self._group_state(slots, frames)
             span.set_metadata(whole=int(how == "whole"))
-        (result, new_state, cached), kind, attempts = self._guard(
+        (result, new_state), kind, attempts = self._guard(
             frames, state, layout
         )
         compute_ms = (time.perf_counter() - t1) * 1e3
@@ -518,6 +498,10 @@ class StreamEngine:
                 s.batch, s.row = new_state, b
         with jax.profiler.TraceAnnotation("repro.stream.account"):
             skipped = np.asarray(result.skipped)
+            # A warm group with every tile of every member skipped was
+            # served from its cached maps, with no kernel launch.
+            cached = state.initialized and bool(
+                np.all(skipped == new_state.tiles))
             for b, s in enumerate(slots):
                 st = s.stats
                 st.frames += 1

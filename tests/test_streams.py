@@ -524,35 +524,39 @@ def _moving(seed, n, h=40, w=48):
     return [_frame(h=h, w=w, seed=seed + t) for t in range(n)]
 
 
+def _recording_step(eng):
+    """Wrap ``eng._jit_step``; returns the list it appends ``(state
+    passed, result, state returned)`` to at each call."""
+    calls = []
+    step = eng._jit_step
+
+    def call(frames, cfg, state, **kw):
+        result, new_state = step(frames, cfg, state, **kw)
+        calls.append((state, result, new_state))
+        return result, new_state
+
+    eng._jit_step = call
+    return calls
+
+
 class TestBatchedState:
     def test_genlocked_group_passes_state_whole(self):
         """Four genlocked streams: from the second step on, the step call
         gets the very state object the previous call returned (no slice,
-        no concat), across the masked and the cached step alike."""
-        # frames 0-2 move, 3-5 repeat frame 2: the last steps take the
-        # cached (no-kernel) call
+        no concat), across masked and cached serves alike."""
+        # frames 0-2 move, 3-5 repeat frame 2: the last serves keep the
+        # cached maps (no kernel launch)
         streams = {sid: [_frame(seed=400 + 10 * sid + min(t, 2))
                          for t in range(6)] for sid in range(4)}
         eng = StreamEngine(BATCH_CFG, collect=True)
-        calls = []
-
-        def recording(fn, state_pos):
-            def call(*args, **kw):
-                result, new_state = fn(*args, **kw)
-                calls.append((fn, args[state_pos], new_state))
-                return result, new_state
-            return call
-
-        step, cached = eng._jit_step, eng._jit_cached
-        eng._jit_step = recording(step, 2)
-        eng._jit_cached = recording(cached, 1)
+        calls = _recording_step(eng)
         for sid, fs in streams.items():
             eng.submit(StreamRequest(sid=sid, frames=_list_source(fs)))
         stats = eng.run()
         assert len(calls) == 6
-        assert [fn for fn, _, _ in calls] == [step] * 3 + [cached] * 3
-        for (_, _, returned), (_, passed, _) in zip(calls, calls[1:]):
+        for (_, _, returned), (passed, _, _) in zip(calls, calls[1:]):
             assert passed is returned
+        assert all(st.cached_steps == 3 for st in stats.values())
         assert all(st.state_gathers == 0 for st in stats.values())
         assert all(st.frames == 6 for st in stats.values())
         _assert_equal_solo(stats, streams)
@@ -613,6 +617,134 @@ class TestBatchedState:
         _assert_equal_solo(stats, streams, fps)
 
 
+# ------------------------------------------------ on-device delta choice --
+
+class TestDeviceDeltaChoice:
+    """A group serve is one jitted call that chooses, on the device,
+    between the cached maps and the masked compute."""
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+    def test_static_group_equals_cached_step(self, backend):
+        """Two streams whose frames repeat: each warm serve gives the
+        outputs and next state of ``edge_stream_cached`` on the state it
+        was given, bit for bit, and counts as a cached step."""
+        cfg = EdgeConfig(nms=True, hysteresis=True, temporal=True,
+                         decay=0.9, backend=backend, block_h=16, block_w=16)
+        eng = StreamEngine(cfg)
+        calls = _recording_step(eng)
+        for sid in range(2):
+            eng.submit(StreamRequest(sid=sid, frames=_list_source(
+                [_frame(seed=600 + sid)] * 3)))
+        stats = eng.run()
+        assert len(calls) == 3
+        for passed, result, returned in calls[1:]:
+            want, want_state = dispatch.edge_stream_cached(
+                eng.config, passed, layout="NHW")
+            for field in ("magnitude", "edges", "skipped"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(result, field)),
+                    np.asarray(getattr(want, field)))
+            got_leaves, got_aux = returned.tree_flatten()
+            want_leaves, want_aux = want_state.tree_flatten()
+            assert got_aux == want_aux
+            for g, w in zip(got_leaves, want_leaves):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert all(st.cached_steps == 2 for st in stats.values())
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+    def test_mixed_group_matches_recompute(self, backend):
+        """One static and one moving stream in one group: the group runs
+        the masked compute (not a cached step), the static member still
+        skips all its tiles, and every frame equals stateless recompute."""
+        cfg = EdgeConfig(nms=True, hysteresis=True, backend=backend,
+                         block_h=16, block_w=16)
+        streams = {0: [_frame(seed=610)] * 4, 1: _moving(620, 4)}
+        eng = StreamEngine(cfg, collect=True)
+        for sid, fs in streams.items():
+            eng.submit(StreamRequest(sid=sid, frames=_list_source(fs)))
+        stats = eng.run()
+        for sid, fs in streams.items():
+            st = stats[sid]
+            assert st.frames == 4 and st.cached_steps == 0
+            for t, (f, out) in enumerate(zip(fs, st.outputs)):
+                ref = edge_detect(f, cfg)
+                np.testing.assert_array_equal(out["magnitude"],
+                                              np.asarray(ref.magnitude))
+                np.testing.assert_array_equal(out["edges"],
+                                              np.asarray(ref.edges))
+                if t:
+                    all_skipped = out["skipped"] == st.tiles_per_frame
+                    assert all_skipped == (sid == 0)
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+    def test_static_serve_launches_no_kernel(self, monkeypatch, backend):
+        """The frame compute runs on the cold serve and on a serve whose
+        frames changed, and not at all on a static serve: a callback traced
+        into the compute counts its runs on the device."""
+        runs, traced = [], []
+
+        def counted(compute):
+            def call(*args, **kw):
+                traced.append(1)
+                jax.debug.callback(lambda: runs.append(1))
+                return compute(*args, **kw)
+            return call
+
+        if backend == "xla":
+            make = dispatch._backend_compute
+            monkeypatch.setattr(dispatch, "_backend_compute",
+                                lambda *a, **kw: counted(make(*a, **kw)))
+        else:
+            monkeypatch.setattr(dispatch.ekern, "edge_stream_pallas",
+                                counted(dispatch.ekern.edge_stream_pallas))
+        cfg = EdgeConfig(nms=True, hysteresis=True, backend=backend,
+                         block_h=16, block_w=16)
+        # a frame size no other test traces, so the step is traced afresh
+        # with the counter in it
+        f0, f1 = (_frame(h=72, w=88, seed=750 + t) for t in range(2))
+        eng = StreamEngine(cfg)
+        eng.submit(StreamRequest(sid=0, frames=_list_source([f0, f0, f1])))
+        seen = []
+        for _ in range(3):
+            assert eng.step()
+            jax.effects_barrier()
+            seen.append(len(runs))
+        assert traced
+        assert seen == [1, 1, 2]
+        assert eng.run()[0].cached_steps == 1
+
+    @pytest.mark.parametrize("moving", [False, True])
+    def test_one_call_and_no_host_read_per_serve(self, monkeypatch,
+                                                 moving):
+        """A warm group serve makes exactly one jitted call and reads
+        nothing back from the device before that call returns."""
+        eng = StreamEngine(BATCH_CFG)
+        for sid in range(2):
+            fs = (_moving(700 + 10 * sid, 3) if moving
+                  else [_frame(seed=700 + sid)] * 3)
+            eng.submit(StreamRequest(sid=sid, frames=_list_source(fs)))
+        assert eng.step()                       # cold serve: compiles
+        events = []
+        step, get = eng._jit_step, jax.device_get
+
+        def call(*args, **kw):
+            events.append("call")
+            out = step(*args, **kw)
+            events.append("returned")
+            return out
+
+        def device_get(x):
+            events.append("device_get")
+            return get(x)
+
+        eng._jit_step = call
+        monkeypatch.setattr(jax, "device_get", device_get)
+        assert eng.step()
+        assert events == ["call", "returned"]
+        cached = [eng.slots[i].stats.cached_steps for i in range(2)]
+        assert cached == ([0, 0] if moving else [1, 1])
+
+
 # ----------------------------------------------------------------- spans --
 
 def _program_spans(log_dir):
@@ -654,8 +786,8 @@ def traced_engine(tmp_path_factory):
 
 
 class TestStreamSpans:
-    PHASES = ("intake", "stack", "h2d", "concat", "delta", "compute",
-              "split", "account", "police")
+    PHASES = ("intake", "stack", "h2d", "concat", "compute", "split",
+              "account", "police")
 
     def test_phase_spans_fall_inside_a_step(self, traced_engine):
         _, spans = traced_engine

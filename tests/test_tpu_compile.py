@@ -14,6 +14,7 @@ library. The persistent compilation cache is off around the compiles, since
 an entry written for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,7 +122,6 @@ def test_u16_inspection_step_compiles_for_v5e(topo):
     container), the fused ``with_max`` kernel in the lane ``auto`` picks:
     the frame reaches the kernel's custom call as ``u16``, with no f32
     copy of it made before the kernel."""
-    import re
 
     from repro.api import edge_detect
     from repro.configs import get_config
@@ -141,11 +141,20 @@ def test_u16_inspection_step_compiles_for_v5e(topo):
 
 @pytest.mark.parametrize("backend", ["pallas-tpu", "xla"])
 def test_stream_engine_step_compiles_for_v5e(topo, backend):
-    """The stream engine's jitted programs (``serve/streams.py``) on a warm
-    state of 2 u8 streams at 2048^2: the per-tile delta test and the masked
-    step, on the kernel backend and on its XLA fallback. These XLA stages
-    are where the chip's compiler, not Mosaic, can refuse (e.g. a
-    ``reduce_window`` that overflows scoped VMEM)."""
+    """The stream engine's jitted program (``serve/streams.py``) on a warm
+    state of 2 u8 streams at 2048^2: the per-tile delta test and the
+    device-side choice between the cached maps and the masked step, on the
+    kernel backend and on its XLA fallback. These XLA stages are where the
+    chip's compiler, not Mosaic, can refuse (e.g. a ``reduce_window`` that
+    overflows scoped VMEM).
+
+    The choice is one ``conditional``: the kernel sits inside its compute
+    branch, and the cached branch passes the state's maps through an
+    identity ``reduce-precision``. Without that identity XLA copies the
+    32 MiB primary map ahead of the conditional on every step, so the
+    entry computation must hold no ``copy`` of the primary parameter (a
+    ``copy-start`` prefetch into on-chip memory for the kernel is not
+    that copy)."""
     from repro.api import EdgeConfig, StreamState
     from repro.kernels import dispatch
 
@@ -161,10 +170,65 @@ def test_stream_engine_step_compiles_for_v5e(topo, backend):
         for a in leaves
     ), block, True)
     frames = jax.ShapeDtypeStruct((n, h, w), jnp.uint8, sharding=one)
-    mask = jax.ShapeDtypeStruct((n, h // BH, w // BW), jnp.bool_,
-                                sharding=one)
-    jax.jit(dispatch.stream_delta).lower(frames, warm, cfg).compile()
     text = jax.jit(dispatch.edge_stream, static_argnames=("layout",)).lower(
-        frames, cfg, warm, layout="NHW", changed=mask
+        frames, cfg, warm, layout="NHW"
     ).compile().as_text()
     assert ("tpu_custom_call" in text) == (backend == "pallas-tpu")
+
+    comps = _computations(text)
+    entry = comps["ENTRY"][0]
+    (cond,) = [ln for ln in entry if " conditional(" in ln]
+    branches = re.search(r"branch_computations=\{([^}]*)\}", cond).group(1)
+    branches = [b.strip().lstrip("%") for b in branches.split(",")]
+    reach = {b: _reachable(comps, b) for b in branches}
+
+    def holds(names, needle):
+        return any(needle in ln for c in names for ln in comps[c][0])
+
+    (cached,) = [b for b in branches if holds(reach[b], " reduce-precision(")]
+    (compute,) = [b for b in branches if b != cached]
+    assert not holds(reach[compute], " reduce-precision(")
+    if backend == "pallas-tpu":
+        assert holds(reach[compute], "tpu_custom_call")
+        outside = _reachable(comps, "ENTRY") - reach[compute]
+        assert not holds(outside, "tpu_custom_call")
+    (primary,) = [
+        re.match(r"\s*%(\S+) = ", ln).group(1) for ln in entry
+        if " parameter(" in ln and ln.split(" = ", 1)[1].startswith(
+            f"f32[{n},{h},{w}]")
+    ]
+    assert not [ln for ln in entry
+                if re.search(rf" copy\(%{re.escape(primary)}\)", ln)]
+
+
+def _computations(text):
+    """HLO text -> {computation: (its instruction lines, the computations
+    they call)}; the entry computation is also under ``"ENTRY"``."""
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{$", ln)
+        if name is None and head:
+            name, is_entry, lines, calls = head.group(2), head.group(1), [], []
+        elif name is not None and ln == "}":
+            comps[name] = (lines, calls)
+            if is_entry:
+                comps["ENTRY"] = comps[name]
+            name = None
+        elif name is not None:
+            lines.append(ln)
+            calls += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", ln)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", ln):
+                calls += [b.strip().lstrip("%") for b in group.split(",")]
+    return comps
+
+
+def _reachable(comps, root):
+    """``root`` and every computation it calls, directly or not."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += comps[c][1]
+    return seen
