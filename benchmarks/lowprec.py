@@ -1,16 +1,13 @@
-"""Low-precision + DMA-pipelined lanes of the fused megakernel (PR 9).
+"""Low-precision lane of the fused megakernel.
 
 Measures the exact integer lane (u8 taps accumulated in i16/i32, f32 only
-at normalize) and the double-buffered DMA pipeline against the f32 lane,
-on gray u8 frames. Series per case:
+at normalize) against the f32 lane, on gray u8 frames. Series per case:
 
   * ``xla-f32``      — legacy XLA path, f32 lane (the ``--compare`` norm
     reference row; CI's geomean gate runs over xla-backend rows);
   * ``xla-int``      — legacy XLA path, explicit integer lane;
-  * ``fused-f32``    — megakernel, f32 lane, auto (unpipelined) schedule;
-  * ``fused-int``    — megakernel, integer lane, auto schedule;
-  * ``fused-int-d2`` / ``fused-int-d3`` — integer lane through the manual
-    double/triple-buffered HBM->VMEM DMA ring.
+  * ``fused-f32``    — megakernel, f32 lane;
+  * ``fused-int``    — megakernel, integer lane.
 
 Both lanes read the same u8 frame and write the same f32 magnitude, so
 HBM bytes/px barely move; the honest integer-lane saving is accumulator
@@ -52,18 +49,14 @@ def run(smoke: bool = False) -> List[Dict]:
         base = EdgeConfig(operator=operator).resolved()
         accum = ladder.accum_dtype(get_operator(operator)) or "f32"
         series = [
-            ("xla-f32", "xla", "f32", None),
-            ("xla-int", "xla", "int", None),
-            ("fused-f32", fused_backend, "f32", None),
-            ("fused-int", fused_backend, "int", None),
-            ("fused-int-d2", fused_backend, "int", 2),
-            ("fused-int-d3", fused_backend, "int", 3),
+            ("xla-f32", "xla", "f32"),
+            ("xla-int", "xla", "int"),
+            ("fused-f32", fused_backend, "f32"),
+            ("fused-int", fused_backend, "int"),
         ]
         ref_us = None
-        for lane, backend, precision, depth in series:
-            cfg = base.replace(
-                backend=backend, precision=precision, pipeline_depth=depth
-            )
+        for lane, backend, precision in series:
+            cfg = base.replace(backend=backend, precision=precision)
             fn = jax.jit(lambda x, c=cfg: edge_detect(x, c).magnitude)
             us = measure_us(fn, img, iters=3)
             if ref_us is None:
@@ -88,7 +81,6 @@ def run(smoke: bool = False) -> List[Dict]:
                         "operator": operator,
                         "n": n,
                         "precision": precision,
-                        "pipeline_depth": depth or 0,
                         "input": "gray-u8",
                     },
                 }

@@ -113,7 +113,7 @@ def _config(backend):
 def _block_source(edge_cfg, cfg):
     from repro.kernels import dispatch
 
-    bh, bw, _depth, source = dispatch.choose_block_shape(
+    bh, bw, source = dispatch.choose_block_shape(
         cfg.image_h, cfg.image_w, block_h=edge_cfg.block_h,
         block_w=edge_cfg.block_w, backend=KERNEL_BACKEND,
     )

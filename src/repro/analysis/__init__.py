@@ -12,7 +12,6 @@ from repro.analysis.rules import (
     RULES,
     AnalysisError,
     check_contraction_fences,
-    check_dma_pipeline,
     check_dtype_ladder,
     check_fusion_purity,
     check_halo_window,
@@ -41,7 +40,6 @@ __all__ = [
     "scan_file",
     "scan_source",
     "check_contraction_fences",
-    "check_dma_pipeline",
     "check_dtype_ladder",
     "check_fusion_purity",
     "check_kernel_accum_dtype",

@@ -41,7 +41,6 @@ __all__ = [
     "check_contraction_fences",
     "check_dtype_ladder",
     "check_kernel_accum_dtype",
-    "check_dma_pipeline",
     "check_vmem_budget",
     "check_halo_window",
     "check_static_registration",
@@ -106,15 +105,6 @@ RULES: Dict[str, Rule] = {
             "equals the narrowest dtype the ladder proof licenses for that "
             "frame dtype (core.ladder.accum_dtype)",
             "PR 8 (spec proof) / PR 9 (kernel check)",
-        ),
-        Rule(
-            "PIPE001",
-            "dma-pipeline",
-            "a fused launch that requests a manual pipeline_depth compiles "
-            "a well-formed double-buffered DMA ring: dma_start AND "
-            "dma_wait in the kernel body, ring depth ≥ 2, and one DMA "
-            "semaphore per ring slot so starts and waits pair one-to-one",
-            "PR 9",
         ),
         Rule(
             "VMEM001",
@@ -473,114 +463,6 @@ def check_kernel_accum_dtype(jaxpr, *, location: str, spec) -> List[Violation]:
     return out
 
 
-def _dma_op_counts(kernel_jaxpr) -> Dict[str, int]:
-    """dma_start/dma_wait sites in a kernel body, descending into the
-    ``cond`` branches that ``pl.when`` wraps them in."""
-    counts = {"dma_start": 0, "dma_wait": 0}
-    for eqn in iter_jaxpr_eqns(kernel_jaxpr, opaque=()):
-        if eqn.primitive.name in counts:
-            counts[eqn.primitive.name] += 1
-    return counts
-
-
-def _pipeline_scratch(pc) -> Tuple[Optional[object], Optional[object]]:
-    """(ring_aval, sem_aval) of a manual-DMA pallas_call, else (None, None).
-
-    Scratch operands are the trailing kernel-jaxpr invars
-    (``grid_mapping.num_scratch_operands`` of them). The DMA semaphore
-    array identifies itself by memory space; among the remaining VMEM
-    scratch buffers the copy ring is the one with the widest row tile —
-    the v2 sink rows are halo-cropped (ew < tw) by construction.
-    """
-    gm = pc.params["grid_mapping"]
-    n = getattr(gm, "num_scratch_operands", 0) or 0
-    if not n:
-        return None, None
-    avals = [v.aval for v in pc.params["jaxpr"].invars[-n:]]
-    sems = [a for a in avals if "semaphore" in str(a).lower()]
-    rings = [
-        a for a in avals
-        if "semaphore" not in str(a).lower() and len(a.shape) >= 3
-    ]
-    if not sems or not rings:
-        return None, None
-    ring = max(rings, key=lambda a: a.shape[-1])
-    return ring, sems[0]
-
-
-def check_dma_pipeline(jaxpr, *, location: str, min_depth: int = 2) -> List[Violation]:
-    """PIPE001: every fused launch on this path compiled a well-formed
-    manual DMA ring — dma_start AND dma_wait present in the kernel body,
-    ring depth ≥ ``min_depth`` (double buffering needs two slots), and
-    exactly one DMA semaphore per ring slot so each started copy has a
-    slot-matched wait. Only meaningful on traces that *requested* a
-    manual ``pipeline_depth``; the automatic-pipelining path compiles no
-    DMA ops by design and must not be passed here.
-    """
-    out: List[Violation] = []
-    for pc in find_pallas_eqns(jaxpr):
-        counts = _dma_op_counts(pc.params["jaxpr"])
-        if not counts["dma_start"]:
-            out.append(
-                Violation(
-                    "PIPE001",
-                    location,
-                    "no dma_start in the fused kernel body — a manual "
-                    "pipeline_depth was requested but the kernel compiled "
-                    "without a DMA ring",
-                    detail=(("dma_start", "0"),),
-                )
-            )
-            continue
-        if not counts["dma_wait"]:
-            out.append(
-                Violation(
-                    "PIPE001",
-                    location,
-                    f"{counts['dma_start']} dma_start site(s) but no "
-                    "dma_wait — started copies are never consumed",
-                    detail=(("dma_start", str(counts["dma_start"])),
-                            ("dma_wait", "0")),
-                )
-            )
-            continue
-        ring, sem = _pipeline_scratch(pc)
-        if ring is None:
-            out.append(
-                Violation(
-                    "PIPE001",
-                    location,
-                    "DMA ops present but no (ring buffer, DMA semaphore) "
-                    "scratch pair on the pallas_call",
-                    detail=(("scratch", "missing"),),
-                )
-            )
-            continue
-        depth = int(ring.shape[0])
-        if depth < min_depth:
-            out.append(
-                Violation(
-                    "PIPE001",
-                    location,
-                    f"DMA ring depth {depth} < {min_depth} — double "
-                    "buffering requires at least two slots",
-                    detail=(("depth", str(depth)),),
-                )
-            )
-        nsem = int(sem.shape[0]) if sem.shape else 0
-        if nsem != depth:
-            out.append(
-                Violation(
-                    "PIPE001",
-                    location,
-                    f"{nsem} DMA semaphore(s) for a depth-{depth} ring — "
-                    "starts and waits cannot pair one-to-one per slot",
-                    detail=(("semaphores", str(nsem)), ("depth", str(depth))),
-                )
-            )
-    return out
-
-
 def check_vmem_budget(
     *,
     location: str,
@@ -731,24 +613,9 @@ def check_halo_window(
                         f"for r={expected}",
                         ("tile", str(tile)), ("expected", str(want)))
         if not windows:
-            # Manual-DMA kernels take their input as an opaque ANY-space
-            # ref (no Element window to probe); the halo geometry is baked
-            # into the copy ring instead: each slot holds exactly one
-            # window_shape(...) tile, so the ring's trailing dims carry the
-            # compiled reach.
-            ring, _sem = _pipeline_scratch(pc)
-            if ring is None:
-                vio("no halo'd Element input window (and no DMA ring) on the "
-                    "pallas_call — the stencil cannot be reading its halo",
-                    ("windows", "0"))
-            elif image_hw is not None:
-                want = window_shape(image_hw[0], image_hw[1], block_h,
-                                    block_w, expected)
-                got = tuple(ring.shape[-2:])
-                if got != want:
-                    vio(f"DMA ring slot tile {got} != window_shape(...) "
-                        f"= {want} for r={expected}",
-                        ("tile", str(got)), ("expected", str(want)))
+            vio("no halo'd Element input window on the pallas_call — the "
+                "stencil cannot be reading its halo",
+                ("windows", "0"))
         exch = halo_mod.exchange_radius(spec, nms, plan=plan)
         if exch != expected:
             vio(f"sharded exchange width {exch} != kernel window radius "

@@ -62,7 +62,6 @@ class Mode:
     opaque_while: bool = False  # hysteresis: post-gather fixpoint pads by design
     all_paddings: bool = False  # sweep every padding in full mode
     export: bool = False  # part of the Mosaic export battery
-    pipelined: bool = False  # manual DMA ring requested: PIPE001 applies
     gray_only: bool = False  # integer lane: RGB is ineligible by design
     frame_dtype: str = "uint8"  # dtype of the traced frames
 
@@ -80,14 +79,7 @@ MODES: Dict[str, Mode] = {
         Mode("hysteresis", (("hysteresis", True),), opaque_while=True),
         Mode("stream", (), stream=True),
         Mode("stream-nms", (("nms", True),), stream=True),
-        Mode("pipelined", (("pipeline_depth", 2),), pipelined=True,
-             export=True),
         Mode("lowprec", (("precision", "int"),), gray_only=True, export=True),
-        # The full PR-9 path: manual DMA ring feeding the integer lane,
-        # NMS fused — exercises the in-kernel sink scratch too.
-        Mode("lowprec-pipelined",
-             (("precision", "int"), ("pipeline_depth", 3), ("nms", True)),
-             pipelined=True, gray_only=True, export=True),
         # 12/16-bit sensor frames read raw: the integer lane at the u16
         # bound (i32 for sobel5), traced only where the ladder admits it.
         Mode("lowprec-u16", (("precision", "int"),), gray_only=True,
@@ -201,9 +193,6 @@ def _combo_violations(
                 channels=3 if layout == "rgb" else None,
             )
             report.checks += 2
-        if mode.pipelined and not mode.stream:
-            out += rules.check_dma_pipeline(jaxpr, location=location)
-            report.checks += 1
     # Vacuous on f32-lane traces; on the integer lane (either backend) it
     # pins the actual accumulation dtype to the ladder proof.
     out += rules.check_kernel_accum_dtype(jaxpr, location=location, spec=spec)
@@ -419,8 +408,6 @@ def analyze(
                     mode = MODES[mode_name]
                     if mode.stream and backend == "xla":
                         continue  # streaming is a fused-path feature
-                    if mode.pipelined and backend == "xla":
-                        continue  # the DMA ring only exists on fused paths
                     if mode.gray_only and layout == "rgb":
                         continue  # explicit int on RGB raises by contract
                     if not _lane_admits(op, mode):

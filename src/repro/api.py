@@ -117,13 +117,6 @@ class EdgeConfig:
                   ``auto`` opts eligible workloads in on the Pallas
                   backends and stays f32 on XLA
                   (``repro.kernels.dispatch.resolve_precision``).
-      pipeline_depth: HBM->VMEM pipelining of the Pallas kernel's input
-                  windows. None = automatic (Pallas double buffering, or a
-                  tuned depth from the cache); 2..8 = an explicit manual
-                  DMA ring of that depth — tile k+1's halo load overlaps
-                  tile k's compute under kernel control (DESIGN.md §11).
-                  Outputs are bit-exact across depths; ignored on the XLA
-                  backend (no DMA to pipeline).
       shard:      :class:`~repro.sharding.halo.ShardConfig` — spread the call
                   over the image mesh ``(data, row, col)`` with halo
                   exchange between spatial neighbors; None = single device.
@@ -169,7 +162,6 @@ class EdgeConfig:
     block_h: Optional[int] = None
     block_w: Optional[int] = None
     precision: str = "auto"
-    pipeline_depth: Optional[int] = None
     shard: Optional[ShardConfig] = None
     nms: bool = False
     hysteresis: bool = False
@@ -200,14 +192,6 @@ class EdgeConfig:
             raise ValueError(
                 f"unknown precision {self.precision!r}; expected 'auto', "
                 "'f32' or 'int'"
-            )
-        if self.pipeline_depth is not None and not (
-            isinstance(self.pipeline_depth, int)
-            and 2 <= self.pipeline_depth <= 8
-        ):
-            raise ValueError(
-                f"pipeline_depth must be None (automatic) or an int in "
-                f"2..8 (manual DMA ring depth), got {self.pipeline_depth!r}"
             )
         if not 0.0 <= self.decay <= 1.0:
             raise ValueError(

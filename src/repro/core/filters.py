@@ -731,7 +731,7 @@ jax.tree_util.register_static(StencilPlan)
 def plan_identity(plan: StencilPlan) -> str:
     """Stable cache identity: plan name + hash of stage names and radii.
 
-    This is the TuneKey v6 plan segment — multi-stage tunings cannot
+    This is the TuneKey plan segment — multi-stage tunings cannot
     collide with single-operator entries or with a differently-shaped plan
     that reuses a name.
     """

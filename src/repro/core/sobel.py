@@ -182,9 +182,7 @@ def _sym_rowpass(xp, dense: np.ndarray, h, w):
     return acc
 
 
-def spec_components(
-    xp, spec: F.OperatorSpec, h, w, variant: str, directions: int, *, sink=None
-):
+def spec_components(xp, spec: F.OperatorSpec, h, w, variant: str, directions: int):
     """Direction components of ``spec`` on the pre-padded image ``xp``.
 
     ``variant``/``directions`` must already be resolved against the spec
@@ -195,19 +193,7 @@ def spec_components(
     frames cast to the i16/i32 budget ``repro.core.ladder`` proves) runs
     plain integer mul-add, bit-identical to the f32 lane because both
     compute the same exact integers.
-
-    ``sink`` (optional ``sink(name, array) -> array``) is applied to the
-    named separable row-pass intermediates — ``"f"``/``"s"`` (Eq. 5-7's
-    horizontal passes) and v2's 2-tap difference ``"d"`` — before their
-    column passes consume them. The fused Pallas kernel's DMA-pipelined
-    path uses it to spill each row pass into a dedicated VMEM scratch
-    buffer and read it back (deterministic VMEM residency for the reused
-    factors); a sink must return its input's values unchanged, so the
-    default identity and any store/load round-trip are bit-identical.
     """
-    if sink is None:
-        def sink(_name, arr):
-            return arr
     if variant == "direct":
         return tuple(_correlate2d(xp, k, h, w) for k in spec.bank(directions))
 
@@ -215,8 +201,8 @@ def spec_components(
     # leading factor a.
     col_x, row_x = spec.sep_factors(0)
     col_y, row_y = spec.sep_factors(1)
-    f = sink("f", _hpass(xp, row_x, w))  # the reused F pass (4 MACs: zero centre)
-    s = sink("s", _hpass(xp, row_y, w))
+    f = _hpass(xp, row_x, w)  # the reused F pass (4 MACs: zero centre)
+    s = _hpass(xp, row_y, w)
     gx = _vpass(f, col_x, h)
     gy = _vpass(s, col_y, h)
     if directions == 2:
@@ -234,7 +220,7 @@ def spec_components(
         gd_minus = _sym_rowpass(xp, spec.kd_minus_dense(), h, w)
     elif variant == "v2":
         col_f, col_d, row_d = spec.v2_arrays()
-        d = sink("d", _hpass(xp, row_d, w))  # 2-tap difference D = p3 - p1
+        d = _hpass(xp, row_d, w)  # 2-tap difference D = p3 - p1
         gd_minus = _vpass(f, col_f, h) - _vpass(d, col_d, h)
     else:
         raise ValueError(f"unknown variant {variant!r}; expected one of {VARIANTS}")
@@ -289,8 +275,7 @@ def _stage_apply(x, stage, out_h, out_w):
                      "single-plane stage")
 
 
-def plan_components(ext, plan, h, w, variant: str, directions: int, *,
-                    sink=None, stage_sink=None):
+def plan_components(ext, plan, h, w, variant: str, directions: int):
     """Direction components of ``plan`` on ``ext``, the input extended by
     ``plan.linear_reach`` on each side (``(h + 2R, w + 2R)``).
 
@@ -304,25 +289,16 @@ def plan_components(ext, plan, h, w, variant: str, directions: int, *,
 
     ``variant``/``directions`` apply to the gradient stage; plans without
     a gradient return the single smoothed plane as a 1-tuple.
-
-    ``sink`` forwards to :func:`spec_components` (the gradient row-pass
-    spill); ``stage_sink`` (optional ``stage_sink(idx, array) -> array``)
-    is applied to each pre-stage's output plane — the fused kernel's
-    DMA-pipelined path spills the inter-stage planes into dedicated VMEM
-    scratch. A stage_sink must return its input's values unchanged, so
-    the identity default and a store/load round-trip are bit-identical.
     """
     cur = ext
     remaining = plan.linear_reach
-    for idx, stage in enumerate(plan.pre_stages):
+    for stage in plan.pre_stages:
         remaining -= stage.radius
         cur = _stage_apply(cur, stage, h + 2 * remaining, w + 2 * remaining)
-        if stage_sink is not None:
-            cur = stage_sink(idx, cur)
     spec = plan.gradient
     if spec is None:
         return (cur,)
-    return spec_components(cur, spec, h, w, variant, directions, sink=sink)
+    return spec_components(cur, spec, h, w, variant, directions)
 
 
 # ---------------------------------------------------------------------------

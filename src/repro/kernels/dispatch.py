@@ -144,10 +144,9 @@ def choose_block_shape(
     kernel_h: Optional[int] = None,
     kernel_w: Optional[int] = None,
     precision: str = "f32",
-    pipeline_depth: Optional[int] = None,
     plan=None,
-) -> Tuple[int, int, int, str]:
-    """Resolve (block_h, block_w, depth, source) for a Pallas backend.
+) -> Tuple[int, int, str]:
+    """Resolve (block_h, block_w, source) for a Pallas backend.
 
     ``source`` is ``"explicit"``, ``"tuned"`` or ``"default"`` — tests and
     benchmarks use it to verify the tuning cache actually steers dispatch.
@@ -155,34 +154,29 @@ def choose_block_shape(
     sharding ``kernel_h``/``kernel_w`` name the halo-extended local block
     the kernel actually tiles (they size the fallback default), and
     ``devices``/``mesh`` keep sharded tunings from colliding with
-    single-device entries (TuneKey schema v4). ``precision`` (resolved
-    lane) and ``pipeline_depth`` slot the v5 key dimensions: an explicit
-    depth pins the returned depth (and its own cache slot); ``None`` lets
-    a tuned entry supply the depth the sweep measured faster, defaulting
-    to 0 (automatic pipelining). ``plan`` (a resolved
-    :class:`~repro.core.filters.StencilPlan`) slots the v6 plan-identity
+    single-device entries. ``precision`` (the resolved lane) has a key
+    slot of its own. ``plan`` (a resolved
+    :class:`~repro.core.filters.StencilPlan`) slots the plan-identity
     dimension and sizes the fallback default by the composed reach.
     """
     if block_h and block_w:
-        return block_h, block_w, pipeline_depth or 0, "explicit"
+        return block_h, block_w, "explicit"
     cache = cache if cache is not None else tuning.get_default_cache()
     hit = cache.lookup(
         tuning.TuneKey(backend, dtype, operator, variant, h, w, padding,
-                       layout, devices, mesh, precision, pipeline_depth or 0,
+                       layout, devices, mesh, precision,
                        plan_identity(plan) if plan is not None else "-")
     )
     if hit is not None:
-        bh, bw, depth = hit
-        if pipeline_depth is not None:
-            depth = pipeline_depth
-        return block_h or bh, block_w or bw, depth, "tuned"
+        bh, bw = hit
+        return block_h or bh, block_w or bw, "tuned"
     size = (2 * plan.linear_reach + 1 if plan is not None
             else get_operator(operator).size)
     dbh, dbw = ekern.default_block_shape(
         kernel_h or h, kernel_w or w, size,
         channels=3 if layout == "rgb" else None,
     )
-    return block_h or dbh, block_w or dbw, pipeline_depth or 0, "default"
+    return block_h or dbh, block_w or dbw, "default"
 
 
 def _kernel_dtype_name(x: jnp.ndarray) -> str:
@@ -196,7 +190,7 @@ def _kernel_dtype_name(x: jnp.ndarray) -> str:
 
 def _backend_compute(
     config, backend, *, rgb, need_comps, need_raw, block_h, block_w,
-    precision="f32", pipeline_depth=0,
+    precision="f32",
 ):
     """The backend compute: ``(B, h, w[, 3]) -> (primary, stacked
     components | None, raw magnitude | None)``.
@@ -213,9 +207,7 @@ def _backend_compute(
     kernel; the sharded path computes its peak from the cropped raw
     magnitude instead, an exact max either way.)
 
-    ``precision`` is the *resolved* lane (:func:`resolve_precision`);
-    ``pipeline_depth`` the resolved DMA ring depth (0 = automatic; Pallas
-    backends only — XLA has no DMA to pipeline).
+    ``precision`` is the *resolved* lane (:func:`resolve_precision`).
     """
     if backend == "xla":
         from repro.core import nms
@@ -256,8 +248,7 @@ def _backend_compute(
         operator=config.operator, variant=config.variant,
         params=config.params, directions=config.directions,
         padding=config.padding, block_h=block_h, block_w=block_w, rgb=rgb,
-        precision=precision, pipeline_depth=pipeline_depth,
-        plan=config.plan,
+        precision=precision, plan=config.plan,
         interpret=(backend == "pallas-interpret"),
     )
 
@@ -293,8 +284,7 @@ def _edge_sharded(
 
     Both new kernel lanes compose with sharding unchanged: the halo
     exchange is dtype-preserving, so the per-shard kernel still sees raw
-    u8/u16 (the integer lane's input contract), and the DMA ring tiles the
-    halo-extended local block exactly like the automatic pipeline."""
+    u8/u16 (the integer lane's input contract)."""
     from repro.sharding import halo
 
     spec = config.spec
@@ -311,9 +301,8 @@ def _edge_sharded(
     we = sw + (2 * r if cc > 1 else 0)
 
     bh = bw = None
-    depth = 0
     if backend != "xla":
-        bh, bw, depth, _src = choose_block_shape(
+        bh, bw, _src = choose_block_shape(
             h, w, operator=config.operator, variant=config.variant,
             dtype=_kernel_dtype_name(x), backend=backend,
             padding=config.padding, layout="rgb" if rgb else "gray",
@@ -321,13 +310,12 @@ def _edge_sharded(
             cache=tuning_cache,
             devices=d * rr * cc, mesh=f"{d}x{rr}x{cc}",
             kernel_h=he, kernel_w=we,
-            precision=precision, pipeline_depth=config.pipeline_depth,
-            plan=config.plan,
+            precision=precision, plan=config.plan,
         )
     run = _backend_compute(
         config, backend, rgb=rgb, need_comps=need_comps,
         need_raw=config.nms and need_peak, block_h=bh, block_w=bw,
-        precision=precision, pipeline_depth=depth,
+        precision=precision,
     )
     mag, comps, peak = halo.sharded_edge(
         x, mesh, radius=r, padding=config.padding, compute=run,
@@ -414,16 +402,14 @@ def edge(
         )
     else:
         bh = bw = None
-        depth = 0
         if backend != "xla":
-            bh, bw, depth, _src = choose_block_shape(
+            bh, bw, _src = choose_block_shape(
                 h, w, operator=config.operator, variant=config.variant,
                 dtype=_kernel_dtype_name(x), backend=backend,
                 padding=config.padding, layout="rgb" if rgb else "gray",
                 block_h=config.block_h, block_w=config.block_w,
                 cache=tuning_cache,
-                precision=precision, pipeline_depth=config.pipeline_depth,
-                plan=config.plan,
+                precision=precision, plan=config.plan,
             )
         if backend != "xla" and need_peak:
             # Fused Pallas fast path: the kernel emits per-block maxima of
@@ -435,8 +421,7 @@ def edge(
                 operator=config.operator, variant=config.variant,
                 params=config.params, directions=config.directions,
                 padding=config.padding, block_h=bh, block_w=bw, rgb=rgb,
-                precision=precision, pipeline_depth=depth,
-                plan=config.plan,
+                precision=precision, plan=config.plan,
                 interpret=(backend == "pallas-interpret"),
             )
             if config.nms:
@@ -465,7 +450,7 @@ def edge(
             run = _backend_compute(
                 config, backend, rgb=rgb, need_comps=need_comps,
                 need_raw=config.nms and need_peak, block_h=bh, block_w=bw,
-                precision=precision, pipeline_depth=depth,
+                precision=precision,
             )
             mag, comps, raw = run(x)
             if need_peak:
@@ -543,7 +528,7 @@ def stream_block_shape(
         return ekern.default_block_shape(
             h, w, spec.size, channels=3 if rgb else None
         )
-    bh, bw, _depth, _src = choose_block_shape(
+    bh, bw, _src = choose_block_shape(
         h, w, operator=config.operator, variant=config.variant,
         dtype=dtype, backend=backend, padding=config.padding,
         layout="rgb" if rgb else "gray", block_h=config.block_h,
@@ -714,16 +699,13 @@ def _check_stream_config(config: "EdgeConfig") -> None:
             "streaming caches the primary map only; with_components/"
             "with_orientation are not supported on the stream path"
         )
-    if config.precision == "int" or config.pipeline_depth is not None:
-        # The masked streaming kernel stays on the automatic-pipelining f32
-        # path: its per-tile lax.cond branches around the whole compute,
-        # which a cross-step DMA ring (whose copies must be unconditional)
-        # cannot coexist with, and the delta-splice caches are f32.
-        # precision="auto" is fine — it resolves to f32 here.
+    if config.precision == "int":
+        # The masked streaming kernel runs the f32 lane: the delta-splice
+        # caches are f32. precision="auto" is fine — it resolves to f32
+        # here.
         raise ValueError(
-            "streaming runs the automatic-pipelining f32 kernel; explicit "
-            "precision='int' / pipeline_depth are not supported on the "
-            "stream path"
+            "streaming runs the f32 kernel; explicit precision='int' is not "
+            "supported on the stream path"
         )
 
 

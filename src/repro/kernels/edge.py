@@ -18,25 +18,17 @@ size-specialized kernels this module replaced:
   * warp-shuffle register taps (§4.3.3)  ->  static strided slices of the
     VMEM-resident tile feeding the VPU.
   * explicit prefetch (§4.3.4)  ->  Pallas's automatic double buffering
-    (``pipeline_depth=0``, the default), or — the paper's trick made
-    explicit — a manual HBM->VMEM DMA ring (``pipeline_depth >= 2``): the
-    input stays in ``pl.ANY`` memory and each grid step issues
-    ``pltpu.make_async_copy`` for the window ``depth - 1`` steps ahead
-    into a ``(depth, tile_h, tile_w)`` VMEM scratch ring, so tile k+1's
-    halo load overlaps tile k's compute under our control (DESIGN.md §11).
+    of the input windows: tile k+1's halo load overlaps tile k's compute.
 
-Two orthogonal lanes thread through both pipelines:
+``precision="int"`` is the exact low-precision lane: u8/u16 frames x
+integer taps accumulated in the i16/i32 dtype ``repro.core.ladder``
+proves, cast to f32 only at the magnitude/NMS boundary. Bit-identical to
+the f32 lane by construction (both compute the same exact integers);
+gated per-operator by the same budget DTYPE001 checks.
 
-  * ``precision="int"`` — the exact low-precision lane: u8/u16 frames x
-    integer taps accumulated in the i16/i32 dtype ``repro.core.ladder``
-    proves, cast to f32 only at the magnitude/NMS boundary. Bit-identical
-    to the f32 lane by construction (both compute the same exact
-    integers); gated per-operator by the same budget DTYPE001 checks.
-  * the registry's separable col (x) row factors exploited in-kernel: on
-    the manual-DMA path the row passes F/S (and v2's D) spill into a
-    dedicated VMEM scratch buffer (``spec_components``'s ``sink``) and
-    the column passes read them back — deterministic VMEM residency for
-    the reused factors, still one launch, values unchanged.
+Both kernel bodies — the image kernel (:func:`edge_pallas`) and the
+masked stream kernel (:func:`edge_stream_pallas`) — compute a tile through
+one function, :func:`_tile_outputs`.
 
 The kernel is a megakernel for the full edge-detection pipeline: raw u8
 gray or RGB frame in (BT.601 luma per-tile in VMEM), in-kernel boundary
@@ -78,13 +70,9 @@ from repro.kernels.tiling import (
     as_f32,
     extend_tile,
     luma,
-    pad_to_windows,
-    padded_shape,
     tile_vmem_bytes,
     valid_mask,
-    window_origin,
     window_radius,
-    window_shape,
     window_spec,
 )
 
@@ -208,43 +196,43 @@ def kernel_name(dtype, lane: str, *, stream: bool = False) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Kernel body — pure math on the VMEM-resident halo'd tile
+# Kernel bodies — one tile function on the VMEM-resident halo'd tile
 # ---------------------------------------------------------------------------
 
-def _compute_dtype(acc_dtype):
-    """Kernel compute dtype: the integer lane's proven i16/i32, else f32."""
-    return jnp.dtype(acc_dtype) if acc_dtype else jnp.float32
-
-
-def _emit_outputs(
-    x, o_refs, k, j, *,
-    spec, variant, directions, bh, bw, h, w, padding, out_components,
-    out_nms, out_mag, with_max, sink=None, plan=None, stage_sink=None,
+def _tile_outputs(
+    x_raw, k, j, *,
+    spec, variant, directions, bh, bw, h, w, padding, rgb, out_nms,
+    out_components=False, out_mag=False, with_max=False, acc_dtype=None,
+    plan=None,
 ):
-    """Shared tail of both fused kernel bodies: gray tile -> stored outputs.
+    """The tile math of both kernel bodies: raw halo'd window -> outputs.
 
-    ``x`` is the grayscale window in the compute dtype (f32, or the integer
-    lane's i16/i32). The gradient ladder runs in that dtype; components are
-    cast to f32 before the magnitude/NMS stage either way, so both lanes
-    store bit-identical f32 outputs (``repro.core.ladder`` proves every
-    integer intermediate is f32-exact). ``sink`` forwards to
-    ``spec_components`` (the manual-DMA path's row-pass VMEM spill).
+    Yields the tile's outputs in :func:`edge_pallas`'s order — primary,
+    then what ``out_components``/``out_mag``/``with_max`` ask for — each as
+    soon as it is computed, so a body that stores while it iterates keeps
+    no finished output live while the next is computed.
+
+    ``x_raw`` is the window as loaded (gray, or planar RGB reduced to luma
+    here); a gray window is cast to the compute dtype (f32, or the integer
+    lane's ``acc_dtype``). The gradient ladder runs in that dtype;
+    components are cast to f32 before the magnitude/NMS stage either way,
+    so both lanes yield bit-identical f32 outputs (``repro.core.ladder``
+    proves every integer intermediate is f32-exact).
 
     ``plan`` (a multi-stage :class:`~repro.core.filters.StencilPlan`)
     chains the plan's single-plane pre-stages ahead of the gradient ladder
     on the same halo'd tile — the tile is extended by the *composed* linear
     reach and each stage consumes its own radius off the margin
     (``core.sobel.plan_components``, the same walk the XLA reference
-    runs). ``stage_sink`` spills the inter-stage planes (pipelined path).
+    runs).
     """
+    x = luma(x_raw, axis=0) if rgb else _to_compute(x_raw, acc_dtype)
     reach = plan.linear_reach if plan is not None else spec.radius
 
     def components(y, hh, ww):
         if plan is not None and plan.pre_stages:
-            return plan_components(y, plan, hh, ww, variant, directions,
-                                   sink=sink, stage_sink=stage_sink)
-        return spec_components(y, spec, hh, ww, variant, directions,
-                               sink=sink)
+            return plan_components(y, plan, hh, ww, variant, directions)
+        return spec_components(y, spec, hh, ww, variant, directions)
 
     def comps_f32(comps):
         return tuple(as_f32(c) for c in comps)
@@ -267,17 +255,14 @@ def _emit_outputs(
         comps = tuple(
             jax.lax.slice(g, (1, 1), (1 + bh, 1 + bw)) for g in comps_ext
         )
-        o = 0
-        o_refs[o][0] = nms_thin(mag_ext, nms_sector(comps))
+        yield nms_thin(mag_ext, nms_sector(comps))
         if out_components:
-            o += 1
-            o_refs[o][0] = jnp.stack(comps, axis=0)  # (directions, bh, bw)
+            yield jnp.stack(comps, axis=0)  # (directions, bh, bw)
         mag = jax.lax.slice(mag_ext, (1, 1), (1 + bh, 1 + bw))
         if out_mag:
-            o += 1
-            o_refs[o][0] = mag
+            yield mag
         if with_max:
-            o_refs[o + 1][0, 0] = block_max(mag)
+            yield block_max(mag)
         return
 
     y = extend_tile(
@@ -286,183 +271,50 @@ def _emit_outputs(
     )
     comps = comps_f32(components(y, bh, bw))
     if out_components:
-        o_refs[0][0] = jnp.stack(comps, axis=0)     # (directions, bh, bw)
+        yield jnp.stack(comps, axis=0)     # (directions, bh, bw)
         if with_max:
             # Per-block maxima ride along with the components, so callers
             # needing components AND the peak pay no second whole-image
             # reduction read (dispatch's fused normalization fast path).
-            o_refs[1][0, 0] = block_max(magnitude(comps))
+            yield block_max(magnitude(comps))
         return
     mag = magnitude(comps)
-    o_refs[0][0] = mag
+    yield mag
     if with_max:
-        o_refs[1][0, 0] = block_max(mag)
+        yield block_max(mag)
 
 
-def _kernel(
-    x_ref, *o_refs,
-    spec, variant, directions, bh, bw, h, w, padding, rgb, out_components,
-    out_nms, out_mag, with_max, acc_dtype=None, plan=None,
-):
+def _kernel(x_ref, *o_refs, **tile):
     k = pl.program_id(1)
     j = pl.program_id(2)
-    x = luma(x_ref[0], axis=0) if rgb else _to_compute(x_ref[0], acc_dtype)
-    _emit_outputs(
-        x, o_refs, k, j,
-        spec=spec, variant=variant, directions=directions, bh=bh, bw=bw,
-        h=h, w=w, padding=padding, out_components=out_components,
-        out_nms=out_nms, out_mag=out_mag, with_max=with_max, plan=plan,
-    )
-
-
-def _sink_slots(variant: str, directions: int) -> int:
-    """Row-pass VMEM spill slots the manual-DMA path allocates.
-
-    The separable ladder materializes the horizontal passes F and S
-    (Eq. 5-7); RG-v2 adds the 2-tap difference D (Eq. 18-19). ``direct``
-    has no row passes; 2-direction v2 never reaches D. Slot order is
-    fixed: f=0, s=1, d=2.
-    """
-    if variant == "direct":
-        return 0
-    return 3 if (variant == "v2" and directions != 2) else 2
-
-
-def _pipelined_kernel(
-    x_hbm, *refs,
-    spec, variant, directions, bh, bw, h, w, padding, rgb, out_components,
-    out_nms, out_mag, with_max, acc_dtype, depth, th, tw, n_sink,
-    plan=None, n_pre=0,
-):
-    """Manual double-buffered DMA body (``pipeline_depth >= 2``).
-
-    The input stays in ``pl.ANY`` (HBM); a ``(depth, [3,] th, tw)``
-    VMEM scratch ring plus a ``depth``-wide DMA semaphore array implement
-    the paper's prefetch explicitly. Grid step j (j fastest, sequential
-    under ``dimension_semantics=("arbitrary",)*3``):
-
-      * j == 0 — refill: start copies for windows 0..depth-2 (new grid
-        row; every prior copy was already waited, the ring is clean);
-      * start the copy for window j+depth-1 (when it exists), keeping
-        depth-1 loads in flight ahead of compute;
-      * wait window j's copy, then compute from ring slot ``j % depth``.
-
-    Each window's copy is started exactly once and waited exactly once;
-    the window offsets are ``tiling.window_origin`` — the very function
-    the automatic path's ``pl.Element`` index map uses — so both paths
-    read byte-identical windows and the outputs are bit-exact across
-    ``pipeline_depth`` settings. Analyzer rule PIPE001 checks the
-    start/wait pairing and ring depth on the traced jaxpr.
-    """
-    n_scratch = 2 + (1 if n_sink else 0) + n_pre
-    o_refs = refs[:len(refs) - n_scratch]
-    scratch = refs[len(refs) - n_scratch:]
-    buf, sem = scratch[0], scratch[1]
-    rows = scratch[2] if n_sink else None
-    pre_refs = scratch[2 + (1 if n_sink else 0):]
-
-    i = pl.program_id(0)
-    k = pl.program_id(1)
-    j = pl.program_id(2)
-    gw = pl.num_programs(2)
-    reach = plan.linear_reach if plan is not None else spec.radius
-    r_in = window_radius(reach, out_nms)
-
-    hp, wp = padded_shape(h, w, bh, bw, r_in)
-
-    def window_copy(j2, slot):
-        row0, col0 = window_origin(k, j2, hp, wp, bh, bw, r_in, th, tw)
-        win = (pl.ds(row0, th), pl.ds(col0, tw))
-        src = x_hbm.at[(i, slice(None)) + win if rgb else (i,) + win]
-        return pltpu.make_async_copy(src, buf.at[slot], sem.at[slot])
-
-    @pl.when(j == 0)
-    def _refill():
-        for ahead in range(min(depth - 1, gw)):
-            window_copy(ahead, ahead).start()
-
-    @pl.when(j + depth - 1 < gw)
-    def _prefetch():
-        window_copy(j + depth - 1, jax.lax.rem(j + depth - 1, depth)).start()
-
-    slot = jax.lax.rem(j, depth)
-    window_copy(j, slot).wait()
-    x_win = buf[slot]
-    x = luma(x_win, axis=0) if rgb else _to_compute(x_win, acc_dtype)
-
-    sink = None
-    if n_sink:
-        slots = {"f": 0, "s": 1, "d": 2}
-
-        def sink(name, arr):
-            rows[slots[name]] = arr
-            return rows[slots[name]]
-
-    stage_sink = None
-    if n_pre:
-        # Inter-stage VMEM spill: each pre-stage plane round-trips through
-        # its dedicated scratch buffer (deterministic VMEM residency for
-        # the chained stages; values unchanged, so still bit-exact).
-        def stage_sink(idx, arr):
-            pre_refs[idx][0] = arr
-            return pre_refs[idx][0]
-
-    _emit_outputs(
-        x, o_refs, k, j,
-        spec=spec, variant=variant, directions=directions, bh=bh, bw=bw,
-        h=h, w=w, padding=padding, out_components=out_components,
-        out_nms=out_nms, out_mag=out_mag, with_max=with_max, sink=sink,
-        plan=plan, stage_sink=stage_sink,
-    )
+    outs = _tile_outputs(x_ref[0], k, j, **tile)
+    for ref, out in zip(o_refs, outs, strict=True):
+        # Every output block is the tile behind leading unit dims.
+        ref[(0,) * (len(ref.shape) - out.ndim)] = out
 
 
 def _stream_kernel(
-    mask_ref, x_ref, prev_ref, prevmax_ref, o_ref, omax_ref, *,
-    spec, variant, directions, bh, bw, h, w, padding, rgb, out_nms,
+    mask_ref, x_ref, prev_ref, prevmax_ref, o_ref, omax_ref, **tile
 ):
     """Masked-grid streaming body: per-tile recompute-or-splice.
 
     The delta dispatcher marks each tile changed/unchanged in an SMEM mask
     (``(N, gh, gw)`` int32, one flag per grid step). A changed tile runs
-    the exact same math as :func:`_kernel`'s primary path; an unchanged
-    tile splices the cached output tile and per-block max (both per-block
-    maxima in the lane-row layout of ``_bmax_spec``) instead — one
-    ``lax.cond`` per grid step, so Mosaic branches over the whole tile
-    compute and the skipped tile costs only the (unavoidable) window DMA
-    plus a VMEM copy. Splice == recompute bit-exactly because an unchanged
-    input window reproduces identical arithmetic, inductively across
-    frames.
+    :func:`_tile_outputs`, the math of :func:`_kernel`, for its primary map
+    and block max; an unchanged tile splices the cached output tile and
+    per-block max (both per-block maxima in the lane-row layout of
+    ``_bmax_spec``) instead — one ``lax.cond`` per grid step, so Mosaic
+    branches over the whole tile compute and the skipped tile costs only
+    the (unavoidable) window DMA plus a VMEM copy. Splice == recompute
+    bit-exactly because an unchanged input window reproduces identical
+    arithmetic, inductively across frames.
     """
     k = pl.program_id(1)
     j = pl.program_id(2)
     changed = mask_ref[0, k, j] != 0
 
-    def block_max(mag):
-        return _block_max(mag, k, j, h=h, w=w, bh=bh, bw=bw)
-
     def fresh(x_raw):
-        x = luma(x_raw, axis=0) if rgb else as_f32(x_raw)
-        if out_nms:
-            y = extend_tile(
-                x, k, j, h=h, w=w, block_h=bh, block_w=bw,
-                r=spec.radius + 1, padding=padding,
-            )
-            comps_ext = spec_components(
-                y, spec, bh + 2, bw + 2, variant, directions
-            )
-            mag_ext = magnitude(comps_ext)
-            comps = tuple(
-                jax.lax.slice(g, (1, 1), (1 + bh, 1 + bw)) for g in comps_ext
-            )
-            thin = nms_thin(mag_ext, nms_sector(comps))
-            mag = jax.lax.slice(mag_ext, (1, 1), (1 + bh, 1 + bw))
-            return thin, block_max(mag)
-        y = extend_tile(
-            x, k, j, h=h, w=w, block_h=bh, block_w=bw, r=spec.radius,
-            padding=padding,
-        )
-        mag = magnitude(spec_components(y, spec, bh, bw, variant, directions))
-        return mag, block_max(mag)
+        return tuple(_tile_outputs(x_raw, k, j, with_max=True, **tile))
 
     def cached(_x_raw):
         return prev_ref[0], prevmax_ref[0, 0]
@@ -473,8 +325,25 @@ def _stream_kernel(
 
 
 # ---------------------------------------------------------------------------
-# pallas_call wrapper (operates on the raw, unpadded batch)
+# pallas_call wrappers (operate on the raw, unpadded batch)
 # ---------------------------------------------------------------------------
+
+def _grid(x, *, rgb, block_h, block_w, reach, out_nms):
+    """Grid and input-window geometry of both wrappers: ``(n, h, w, bh, bw,
+    gh, gw, in_spec)``. NMS compares the magnitude against a 1-px
+    neighborhood, so its input window carries one extra ring on top of the
+    (composed) stencil halo ``reach``."""
+    if rgb:
+        n, h, w, _c = x.shape
+    else:
+        n, h, w = x.shape
+    bh = block_h
+    bw = block_w if block_w else w
+    gh, gw = pl.cdiv(h, bh), pl.cdiv(w, bw)
+    r_in = window_radius(reach, out_nms)
+    in_spec = window_spec(h, w, bh, bw, r_in, channels=3 if rgb else None)
+    return n, h, w, bh, bw, gh, gw, in_spec
+
 
 @functools.partial(
     jax.jit,
@@ -492,7 +361,6 @@ def _stream_kernel(
         "out_mag",
         "with_max",
         "precision",
-        "pipeline_depth",
         "plan",
         "interpret",
     ),
@@ -513,7 +381,6 @@ def edge_pallas(
     out_mag: bool = False,
     with_max: bool = False,
     precision: str = "f32",
-    pipeline_depth: int = 0,
     plan: "StencilPlan | str | None" = None,
     interpret: bool = False,
 ):
@@ -542,9 +409,7 @@ def edge_pallas(
     ``precision="int"`` runs the exact integer lane (u8/u16 gray input only;
     raises with the first failing eligibility gate otherwise — see
     ``repro.core.ladder``); outputs stay f32 and bit-identical to the
-    default lane. ``pipeline_depth=0`` (default) uses Pallas's automatic
-    double buffering; ``2..8`` switches to the manual DMA ring of that
-    depth (:func:`_pipelined_kernel`), again bit-identical by construction.
+    default lane.
 
     ``plan`` (a :class:`~repro.core.filters.StencilPlan` or registered
     plan name) fuses the whole multi-stage chain into this same single
@@ -563,11 +428,6 @@ def edge_pallas(
         # resolves it before reaching the kernel wrapper).
         raise ValueError(
             f"unknown precision {precision!r}; expected 'f32' or 'int'"
-        )
-    if pipeline_depth and not 2 <= pipeline_depth <= 8:
-        raise ValueError(
-            f"pipeline_depth must be 0 (automatic) or 2..8 (manual DMA "
-            f"ring), got {pipeline_depth}"
         )
     plan = resolve_plan(plan)
     if plan is not None:
@@ -612,20 +472,10 @@ def edge_pallas(
             # widening preserves bit-exactness. Interpret/XLA lanes keep
             # the narrow dtype the ladder licenses.
             acc_dtype = "int32"
-    if rgb:
-        n, h, w, _c = x.shape
-    else:
-        n, h, w = x.shape
-    bh = block_h
-    bw = block_w if block_w else w
-    gh, gw = pl.cdiv(h, bh), pl.cdiv(w, bw)
-    grid = (n, gh, gw)
-
-    # NMS compares the magnitude against a 1-px neighborhood, so its input
-    # window carries one extra ring on top of the (composed) stencil halo.
-    reach = plan.linear_reach if plan is not None else spec.radius
-    r_in = window_radius(reach, out_nms)
-    in_spec = window_spec(h, w, bh, bw, r_in, channels=3 if rgb else None)
+    n, h, w, bh, bw, gh, gw, in_spec = _grid(
+        x, rgb=rgb, block_h=block_h, block_w=block_w, out_nms=out_nms,
+        reach=plan.linear_reach if plan is not None else spec.radius,
+    )
     name = kernel_name(x.dtype, acc_dtype or "f32")
     x = _planar(x, rgb)
 
@@ -653,86 +503,21 @@ def edge_pallas(
         out_specs.append(_bmax_spec())
         out_shape.append(_bmax_shape(n, gh, gw))
 
-    common = dict(
-        spec=spec,
-        variant=variant,
-        directions=directions,
-        bh=bh,
-        bw=bw,
-        h=h,
-        w=w,
-        padding=padding,
-        rgb=rgb,
-        out_components=out_components,
-        out_nms=out_nms,
-        out_mag=out_mag,
-        with_max=with_max,
-        acc_dtype=acc_dtype,
-        plan=plan,
+    kernel = functools.partial(
+        _kernel, spec=spec, variant=variant, directions=directions, bh=bh,
+        bw=bw, h=h, w=w, padding=padding, rgb=rgb,
+        out_components=out_components, out_nms=out_nms, out_mag=out_mag,
+        with_max=with_max, acc_dtype=acc_dtype, plan=plan,
     )
-    if pipeline_depth:
-        # Manual DMA ring: input stays in ANY/HBM, the kernel copies each
-        # clamped window itself (same window_origin offsets as in_spec's
-        # index map — byte-identical reads). The grid must run sequentially
-        # for cross-step prefetch to be legal, hence "arbitrary" semantics.
-        th, tw = window_shape(h, w, bh, bw, r_in)
-        n_sink = _sink_slots(variant, directions)
-        # Gradient row-pass sink extents are relative to the gradient
-        # stage's input tile — bh/bw plus the NMS ring plus the *gradient*
-        # radius (pre-stages have already consumed the rest of the reach).
-        eh = bh + (2 if out_nms else 0) + 2 * spec.radius
-        ew = bw + (2 if out_nms else 0)
-        buf_shape = (pipeline_depth,) + ((3,) if rgb else ()) + (th, tw)
-        scratch = [
-            pltpu.VMEM(buf_shape, x.dtype),
-            pltpu.SemaphoreType.DMA((pipeline_depth,)),
-        ]
-        if n_sink:
-            scratch.append(
-                pltpu.VMEM((n_sink, eh, ew), _compute_dtype(acc_dtype))
-            )
-        # Inter-stage VMEM scratch: one buffer per pre-stage plane, sized
-        # to that stage's (shrinking) output extent.
-        pre_shapes = []
-        if plan is not None:
-            pad2 = 2 if out_nms else 0
-            remaining = plan.linear_reach
-            for stage in plan.pre_stages:
-                remaining -= stage.radius
-                pre_shapes.append(
-                    (1, bh + pad2 + 2 * remaining, bw + pad2 + 2 * remaining)
-                )
-        for shp in pre_shapes:
-            scratch.append(pltpu.VMEM(shp, _compute_dtype(acc_dtype)))
-        kernel = functools.partial(
-            _pipelined_kernel, **common,
-            depth=pipeline_depth, th=th, tw=tw, n_sink=n_sink,
-            n_pre=len(pre_shapes),
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",) * 3
-            ),
-            interpret=interpret,
-            name=name,
-        )(pad_to_windows(x, bh, bw, r_in))
-    else:
-        kernel = functools.partial(_kernel, **common)
-        out = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[in_spec],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-            name=name,
-        )(x)
+    out = pl.pallas_call(
+        kernel,
+        grid=(n, gh, gw),
+        in_specs=[in_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+        name=name,
+    )(x)
     out = list(out)
     if with_max:
         out[-1] = _bmax_unpack(out[-1])
@@ -792,43 +577,27 @@ def edge_stream_pallas(
     spec: OperatorSpec = get_operator(operator, params)
     variant = spec.resolve_variant(variant)
     directions = spec.resolve_directions(directions)
-    if rgb:
-        n, h, w, _c = x.shape
-    else:
-        n, h, w = x.shape
-    bh = block_h
-    bw = block_w if block_w else w
-    gh, gw = pl.cdiv(h, bh), pl.cdiv(w, bw)
+    n, h, w, bh, bw, gh, gw, in_spec = _grid(
+        x, rgb=rgb, block_h=block_h, block_w=block_w, reach=spec.radius,
+        out_nms=out_nms,
+    )
     if prev_bmax.shape != (n, gh, gw) or mask.shape != (n, gh, gw):
         raise ValueError(
             f"prev_bmax/mask {prev_bmax.shape}/{mask.shape} do not match the "
             f"({n}, {gh}, {gw}) tile grid of block ({bh}, {bw})"
         )
-    grid = (n, gh, gw)
-
-    r_in = window_radius(spec.radius, out_nms)
-    in_spec = window_spec(h, w, bh, bw, r_in, channels=3 if rgb else None)
     mask_spec = pl.BlockSpec(
         (1, gh, gw), lambda i, k, j: (i, 0, 0), memory_space=pltpu.SMEM
     )
     plane = pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j))
 
     kernel = functools.partial(
-        _stream_kernel,
-        spec=spec,
-        variant=variant,
-        directions=directions,
-        bh=bh,
-        bw=bw,
-        h=h,
-        w=w,
-        padding=padding,
-        rgb=rgb,
-        out_nms=out_nms,
+        _stream_kernel, spec=spec, variant=variant, directions=directions,
+        bh=bh, bw=bw, h=h, w=w, padding=padding, rgb=rgb, out_nms=out_nms,
     )
     primary, bmax = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n, gh, gw),
         in_specs=[mask_spec, in_spec, plane, _bmax_spec()],
         out_specs=[plane, _bmax_spec()],
         out_shape=[

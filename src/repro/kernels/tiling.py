@@ -39,9 +39,7 @@ masked stores, and ``valid_mask`` excludes them from in-kernel reductions
 than one window but is not a multiple of its tile is read as if padded up to
 one (:func:`padded_shape`): the window spec declares the padding
 (``pl.Element(t, (0, pad))``), so no copy is made, and the kernel zeroes the
-undefined padded cells before its selection matmul ever sees them. The
-manual DMA ring has no such declaration and reads a zero-padded copy
-(:func:`pad_to_windows`) of such frames instead.
+undefined padded cells before its selection matmul ever sees them.
 """
 from __future__ import annotations
 
@@ -57,7 +55,6 @@ __all__ = [
     "ALIGN",
     "window_shape",
     "padded_shape",
-    "pad_to_windows",
     "window_spec",
     "window_origin",
     "reflect_index",
@@ -126,19 +123,6 @@ def padded_shape(h: int, w: int, block_h: int, block_w: int, r: int
     """(H_pad, W_pad) of the plane the windows are read from (>= (h, w))."""
     return (_axis_window(h, block_h, r, ALIGN[0])[1],
             _axis_window(w, block_w, r, ALIGN[1])[1])
-
-
-def pad_to_windows(x: jnp.ndarray, block_h: int, block_w: int, r: int
-                   ) -> jnp.ndarray:
-    """Zero-pad the last two dims of ``x`` to :func:`padded_shape` (a no-op
-    for frames whose windowed axes are already tile multiples) — the manual
-    DMA ring's input; BlockSpec windows declare the padding instead."""
-    h, w = x.shape[-2:]
-    hp, wp = padded_shape(h, w, block_h, block_w, r)
-    if (hp, wp) == (h, w):
-        return x
-    pad = [(0, 0)] * (x.ndim - 2) + [(0, hp - h), (0, wp - w)]
-    return jnp.pad(x, pad)
 
 
 def _axis_origin(idx, n_pad: int, b: int, r: int, t: int, a: int):

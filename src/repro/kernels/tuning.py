@@ -10,35 +10,18 @@ The paper's key tuning knob is the CUDA block geometry; ours is the Pallas
     loop), and
   * persists the winner in a JSON cache keyed by
     ``(backend, dtype, operator, variant, padding, layout, H, W, devices,
-    mesh, precision, depth, plan)`` (:class:`TuningCache`), which
-    ``repro.kernels.dispatch`` consults on every call.
-    ``plan`` entered with the multi-stage stencil platform (schema v6): a
-    fused plan kernel (e.g. ``canny5``) tiles a larger composed halo and
-    holds inter-stage scratch, so its tunings must not collide with the
-    bare operator's; the segment is the plan identity
+    mesh, precision, plan)`` (:class:`TuningCache`), which
+    ``repro.kernels.dispatch`` consults on every call. Every segment names
+    something that changes the kernel's tiling or arithmetic: the operator
+    (its halo radius), padding and gray/RGB layout, the device count and
+    image mesh (a sharded kernel tiles the halo-extended *local* block),
+    the resolved lane (an integer-lane tuning has other VMEM pressure and
+    arithmetic than the f32 one) and the plan identity
     (``repro.core.filters.plan_identity`` — name + a stable hash of the
-    stage structure, so a redefined plan gets a fresh slot) or ``-`` for
-    plain single-operator calls.
-    ``precision``/``depth`` entered with the DMA-pipelined low-precision
-    megakernel (schema v5): an integer-lane tuning or a manual-depth ring
-    has different VMEM pressure and arithmetic than the f32/automatic
-    path, so their slots must not collide — and the tuned pipeline depth
-    itself became part of the cached *value* (``depth``, 0 = automatic).
-    ``devices``/``mesh`` entered with the
-    multi-device edge engine (schema v4): under spatial sharding the kernel
-    runs on the halo-extended *local* block, so a tuning taken on a
-    ``1x2x2`` mesh must not collide with the single-device entry for the
-    same frame size. ``operator`` entered the key with the declarative
-    operator registry (schema v3): tunings for ``sobel5`` vs ``scharr3`` vs
-    the 7x7 extended operator must not collide — the halo radius and
-    in-kernel arithmetic differ per spec. ``padding`` and ``layout``
-    (gray/rgb) entered with the fused zero-copy pipeline (schema v2). Older
-    files migrate on load: v1 entries land in the reflect/gray slot, v2
-    entries map their ``SxS`` size segment onto the Sobel operator of that
-    size, v3 entries land in the single-device (``1/1x1x1``) slot, v4
-    entries in the ``f32/0`` precision/depth slot, v5 entries in the
-    single-operator (``-``) plan slot; the next
-    :meth:`TuningCache.save` rewrites the file as v6.
+    stage structure — or ``-`` for plain single-operator calls; a fused
+    plan tiles a larger composed halo). The file carries a schema version
+    (now 7); a file of any other schema is skipped with a warning, and the
+    tunings it held are measured again on demand.
 
 Cache location: ``$REPRO_TUNE_CACHE`` if set, else
 ``~/.cache/repro/sobel_blocks.json``. The file is plain JSON so it can be
@@ -95,15 +78,13 @@ class TuneKey:
     devices: int = 1           # devices the call spans (1 = single-device)
     mesh: str = "1x1x1"        # image mesh shape "DxRxC" (data x row x col)
     precision: str = "f32"     # resolved lane: f32 | int
-    depth: int = 0             # requested pipeline depth (0 = auto)
     plan: str = "-"            # plan identity (filters.plan_identity) or "-"
 
     def to_str(self) -> str:
         return (
             f"{self.backend}/{self.dtype}/{self.operator}/{self.variant}"
             f"/{self.padding}/{self.layout}/{self.h}x{self.w}"
-            f"/{self.devices}/{self.mesh}/{self.precision}/{self.depth}"
-            f"/{self.plan}"
+            f"/{self.devices}/{self.mesh}/{self.precision}/{self.plan}"
         )
 
 
@@ -129,79 +110,16 @@ def _file_lock(path: str):
             fcntl.flock(lk, fcntl.LOCK_UN)
 
 
-# v1/v2 key size segments ("5x5") -> operator registry names.
-_SIZE_TO_OPERATOR = {"3x3": "sobel3", "5x5": "sobel5", "7x7": "sobel7"}
-
-
-def _migrate_v1_key(key: str) -> Optional[str]:
-    """v1 keys were ``backend/dtype/SxS/variant/HxW``; the v1 kernels always
-    behaved as reflect padding on grayscale input, so that is the slot their
-    tunings carry over to (then through v2->v3->v4). Returns None for
-    unrecognizable keys."""
-    parts = key.split("/")
-    if len(parts) != 5:
-        return None
-    backend, dtype, size, variant, hw = parts
-    return _migrate_v2_key(f"{backend}/{dtype}/{size}/{variant}/reflect/gray/{hw}")
-
-
-def _migrate_v2_key(key: str) -> Optional[str]:
-    """v2 keys carried an ``SxS`` size segment; v3 names the operator — the
-    v2 kernels were the Sobel family, so ``5x5 -> sobel5`` etc."""
-    parts = key.split("/")
-    if len(parts) != 7:
-        return None
-    op = _SIZE_TO_OPERATOR.get(parts[2])
-    if op is None:
-        return None
-    parts[2] = op
-    return _migrate_v3_key("/".join(parts))
-
-
-def _migrate_v3_key(key: str) -> Optional[str]:
-    """v3 keys predate the multi-device engine — every tuning was taken on
-    one device, so they land in the ``1/1x1x1`` slot of the v4 key space
-    (then through v4->v5)."""
-    parts = key.split("/")
-    if len(parts) != 7:
-        return None
-    return _migrate_v4_key("/".join(parts + ["1", "1x1x1"]))
-
-
-def _migrate_v4_key(key: str) -> Optional[str]:
-    """v4 keys predate the precision/pipeline dimensions — every tuning was
-    the f32 lane with automatic (implicit) pipelining, so they land in the
-    ``f32/0`` slot of the v5 key space (then through v5->v6); integer-lane
-    and manual-depth tunings can never collide with them."""
-    parts = key.split("/")
-    if len(parts) != 9:
-        return None
-    return _migrate_v5_key("/".join(parts + ["f32", "0"]))
-
-
-def _migrate_v5_key(key: str) -> Optional[str]:
-    """v5 keys predate the stencil-plan dimension — every tuning was a
-    plain single-operator kernel, so they land in the ``-`` plan slot of
-    the v6 key space; fused-plan tunings can never collide with them."""
-    parts = key.split("/")
-    if len(parts) != 11:
-        return None
-    return "/".join(parts + ["-"])
-
-
 class TuningCache:
     """JSON-backed best-known-config store.
 
-    Schema: ``{key: {"block_h": int, "block_w": int, "depth": int,
-    "us": float}}`` with a ``__meta__`` entry recording the schema version
-    (``depth`` is the tuned pipeline depth, 0 = automatic; absent reads as
-    0). Older files (v1: no padding/layout key segments; v2: size segment
-    instead of operator name; v3: no device-count/mesh segments; v4: no
-    precision/pipeline-depth segments; v5: no plan segment) are migrated
-    in-memory on load and rewritten as v6 on the next :meth:`save`.
+    Schema: ``{key: {"block_h": int, "block_w": int, "us": float}}`` with
+    a ``__meta__`` entry recording the schema version. A file of another
+    schema (or with no ``__meta__``) is skipped with a warning: its key
+    layout is not this one's.
     """
 
-    VERSION = 6
+    VERSION = 7
 
     def __init__(self, path: Optional[str] = None):
         self.path = path or default_cache_path()
@@ -219,13 +137,13 @@ class TuningCache:
             return False
 
     def load(self) -> "TuningCache":
-        """Load (and migrate) the cache file; never raises.
+        """Load the cache file; never raises.
 
         A tuning cache is an optional accelerant, so a bad file must not
         take ``edge_detect`` down: unreadable/truncated JSON, a non-dict
-        payload, an unknown *future* schema version (a newer deployment's
-        file on a shared path), and individually corrupted entries are all
-        skipped with a warning rather than raised.
+        payload, a file of another schema version (an older or newer
+        deployment's file on a shared path), and individually corrupted
+        entries are all skipped with a warning rather than raised.
         """
         self._entries = {}
         try:
@@ -247,31 +165,18 @@ class TuningCache:
             )
             return self
         meta = raw.get("__meta__")
-        version = meta.get("version", 1) if isinstance(meta, dict) else 1
-        if not isinstance(version, int) or version > self.VERSION:
-            # A future schema's key layout is unknowable here — dropping the
+        version = meta.get("version") if isinstance(meta, dict) else None
+        if version != self.VERSION:
+            # Another schema's key layout is not this one's — dropping the
             # entries (tunings re-measure on demand) beats misreading them.
             warnings.warn(
                 f"ignoring tuning cache {self.path}: schema version "
-                f"{version!r} is newer than supported ({self.VERSION}); "
-                "run with a matching build or delete the file",
+                f"{version!r} is not the supported {self.VERSION}; run with "
+                "a matching build or delete the file",
                 RuntimeWarning, stacklevel=2,
             )
             return self
         entries = {k: v for k, v in raw.items() if not k.startswith("__")}
-        if version < self.VERSION:
-            migrate = {
-                1: _migrate_v1_key,
-                2: _migrate_v2_key,
-                3: _migrate_v3_key,
-                4: _migrate_v4_key,
-            }.get(version, _migrate_v5_key)
-            migrated = {}
-            for k, v in entries.items():
-                mk = migrate(k)
-                if mk is not None:
-                    migrated[mk] = v
-            entries = migrated
         bad = [k for k, v in entries.items() if not self._valid_entry(v)]
         if bad:
             warnings.warn(
@@ -315,9 +220,8 @@ class TuningCache:
                 f.write("\n")
             os.replace(tmp, self.path)
 
-    def lookup(self, key: TuneKey) -> Optional[Tuple[int, int, int]]:
-        """(block_h, block_w, depth) for the key, or None. ``depth`` is the
-        tuned pipeline depth (0 = automatic; pre-v5 entries read as 0)."""
+    def lookup(self, key: TuneKey) -> Optional[Tuple[int, int]]:
+        """(block_h, block_w) for the key, or None."""
         e = self._entries.get(key.to_str())
         if not e:
             return None
@@ -328,20 +232,12 @@ class TuningCache:
                 RuntimeWarning, stacklevel=2,
             )
             return None
-        try:
-            depth = int(e.get("depth", 0))
-        except (TypeError, ValueError):
-            depth = 0
-        return int(e["block_h"]), int(e["block_w"]), depth
+        return int(e["block_h"]), int(e["block_w"])
 
-    def record(
-        self, key: TuneKey, block_h: int, block_w: int, us: float,
-        depth: int = 0,
-    ) -> None:
+    def record(self, key: TuneKey, block_h: int, block_w: int, us: float) -> None:
         self._entries[key.to_str()] = {
             "block_h": int(block_h),
             "block_w": int(block_w),
-            "depth": int(depth),
             "us": float(us),
         }
 
@@ -446,7 +342,7 @@ def legal_block_shapes(
 
 def _run_shape(
     img, operator, variant, directions, padding, backend, bh, bw,
-    precision="f32", depth=0, plan=None,
+    precision="f32", plan=None,
 ):
     from repro.core.filters import resolve_plan
     from repro.kernels.edge import edge_pallas
@@ -463,7 +359,6 @@ def _run_shape(
         block_w=bw,
         rgb=rgb,
         precision=precision,
-        pipeline_depth=depth,
         plan=plan,
         out_nms=plan.nms if plan is not None else False,
         interpret=(backend != "pallas-tpu"),
@@ -486,16 +381,14 @@ def sweep(
     iters: int = 3,
     seed: int = 0,
     precision: str = "f32",
-    depths: Sequence[int] = (0,),
     plan=None,
 ) -> List[Dict]:
     """Time every candidate block shape on a random HxW image.
 
-    Returns one row per (shape, pipeline depth): ``{"block_h", "block_w",
-    "depth", "us", "vmem_bytes", "halo_overhead", "grid_steps"}`` — the
-    structural columns of the paper's Fig. 6 sweep, generalized to both
-    block dimensions plus the DMA pipeline depth (0 = Pallas automatic,
-    >= 2 = manual ring). ``layout="rgb"`` times the full fused gray->Sobel
+    Returns one row per shape: ``{"block_h", "block_w", "us",
+    "vmem_bytes", "halo_overhead", "grid_steps"}`` — the structural
+    columns of the paper's Fig. 6 sweep, generalized to both block
+    dimensions. ``layout="rgb"`` times the full fused gray->Sobel
     megakernel on an ``(1, h, w, 3)`` frame. ``operator`` (registry name)
     overrides the legacy ``size`` selector; ``plan`` (a
     :class:`~repro.core.filters.StencilPlan` or registered plan name)
@@ -536,23 +429,21 @@ def sweep(
     img = jnp.asarray(rng.integers(0, 256, shape).astype(dtype))
     rows = []
     for bh, bw in shapes:
-        for depth in depths:
-            us = measure_us(
-                _run_shape, img, operator, variant, directions, padding,
-                backend, bh, bw, precision, depth, plan, iters=iters,
-            )
-            gh, gw = -(-h // bh), -(-w // bw)
-            rows.append(
-                {
-                    "block_h": bh,
-                    "block_w": bw,
-                    "depth": depth,
-                    "us": us,
-                    "vmem_bytes": tile_vmem_bytes(bh, bw, r, channels=channels),
-                    "halo_overhead": halo_amplification(bh, bw, r),
-                    "grid_steps": gh * gw,
-                }
-            )
+        us = measure_us(
+            _run_shape, img, operator, variant, directions, padding,
+            backend, bh, bw, precision, plan, iters=iters,
+        )
+        gh, gw = -(-h // bh), -(-w // bw)
+        rows.append(
+            {
+                "block_h": bh,
+                "block_w": bw,
+                "us": us,
+                "vmem_bytes": tile_vmem_bytes(bh, bw, r, channels=channels),
+                "halo_overhead": halo_amplification(bh, bw, r),
+                "grid_steps": gh * gw,
+            }
+        )
     return rows
 
 
@@ -576,11 +467,9 @@ def autotune(
     devices: int = 1,
     mesh: str = "1x1x1",
     precision: str = "f32",
-    pipeline_depth: Optional[int] = None,
     plan=None,
-) -> Tuple[int, int, int]:
-    """Best (block_h, block_w, depth) for the workload; cached across
-    processes.
+) -> Tuple[int, int]:
+    """Best (block_h, block_w) for the workload; cached across processes.
 
     Consults ``cache`` (default: the process-wide JSON cache) unless
     ``refresh``; on a miss, sweeps the legal shapes, records the winner, and
@@ -588,15 +477,12 @@ def autotune(
     ``operator`` (registry name) overrides the legacy ``size`` selector;
     ``plan`` (a :class:`~repro.core.filters.StencilPlan` or registered plan
     name) overrides both — the tuning times the fused multi-stage kernel
-    and lands in the plan-identity cache slot (schema v6).
+    and lands in the plan-identity cache slot.
     ``devices``/``mesh`` slot the tuning for a sharded deployment — the
     sweep itself times the per-shard (h, w) block, which for a spatial mesh
     is the halo-extended local shape (see ``dispatch.choose_block_shape``).
 
     ``precision`` keys (and times) the resolved arithmetic lane.
-    ``pipeline_depth=None`` (auto) lets the sweep choose between automatic
-    pipelining (depth 0) and a manual depth-2 DMA ring, recording the
-    faster; an explicit depth pins the sweep (and the cache slot) to it.
     """
     from repro.core.filters import (
         get_operator, operator_for_size, plan_identity, resolve_plan,
@@ -621,23 +507,20 @@ def autotune(
         plan_seg = "-"
     cache = cache if cache is not None else get_default_cache()
     key = TuneKey(backend, dtype, operator, variant, h, w, padding, layout,
-                  devices, mesh, precision, pipeline_depth or 0, plan_seg)
+                  devices, mesh, precision, plan_seg)
     if not refresh:
         hit = cache.lookup(key)
         if hit is not None:
             return hit
-    depths = (0, 2) if pipeline_depth is None else (pipeline_depth,)
     rows = sweep(
         h, w, operator=operator, variant=variant, directions=directions,
         dtype=dtype, backend=backend, padding=padding, layout=layout,
-        shapes=shapes, iters=iters, precision=precision, depths=depths,
-        plan=plan,
+        shapes=shapes, iters=iters, precision=precision, plan=plan,
     )
     if not rows:
         raise ValueError(f"no legal block shapes for {key.to_str()}")
     best = min(rows, key=lambda r: r["us"])
-    cache.record(key, best["block_h"], best["block_w"], best["us"],
-                 best["depth"])
+    cache.record(key, best["block_h"], best["block_w"], best["us"])
     if save:
         cache.save()
-    return best["block_h"], best["block_w"], best["depth"]
+    return best["block_h"], best["block_w"]

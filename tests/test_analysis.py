@@ -86,24 +86,6 @@ def test_clean_fused_kernel_passes_all_rules():
     assert _all_trace_rules(jaxpr, spec=get_operator("sobel5")) == []
 
 
-def test_clean_pipelined_int_kernel_passes_all_rules():
-    """The manual-DMA + integer-lane kernel satisfies the full rule set,
-    including PIPE001 and the ring-based HALO001 probe (no Element
-    window exists on the ANY-space input)."""
-    spec = get_operator("sobel5")
-    x = jnp.zeros((1, 64, 96), jnp.uint8)
-    jaxpr = jax.make_jaxpr(
-        lambda a: ekern.edge_pallas(
-            a, block_h=16, block_w=32, precision="int", pipeline_depth=2,
-            interpret=True,
-        )
-    )(x)
-    vios = _all_trace_rules(jaxpr, spec=spec)
-    vios += analysis.check_dma_pipeline(jaxpr, location="test")
-    vios += analysis.check_kernel_accum_dtype(jaxpr, location="test", spec=spec)
-    assert vios == []
-
-
 # ---------------------------------------------------------------------------
 # Golden known-bad battery: each artifact trips exactly its rule
 # ---------------------------------------------------------------------------
@@ -275,74 +257,6 @@ def test_bad_over_range_integer_taps_trip_dtype001_only():
         assert bounds["fits_i32"], name
 
 
-def _toy_pipelined_jaxpr(*, wait=True, depth=2, sem_depth=None):
-    """A minimal manual-DMA pallas_call: ANY-space input, one ring slot
-    copied per grid step. Knobs deliberately break the PIPE001 contract."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    h, w, bh, bw = 64, 96, 16, 32
-    sem_depth = depth if sem_depth is None else sem_depth
-
-    def kernel(x_hbm, o_ref, buf, sem):
-        i, k, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        cp = pltpu.make_async_copy(
-            x_hbm.at[i, pl.ds(k * bh, bh), pl.ds(j * bw, bw)],
-            buf.at[0],
-            sem.at[0],
-        )
-        cp.start()
-        if wait:
-            cp.wait()
-        o_ref[...] = buf[0].astype(jnp.float32)[None]
-
-    def run(x):
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pl.pallas_call(
-            kernel,
-            grid=(1, h // bh, w // bw),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j)),
-            out_shape=jax.ShapeDtypeStruct((1, h, w), jnp.float32),
-            scratch_shapes=[
-                pltpu.VMEM((depth, bh, bw), jnp.uint8),
-                pltpu.SemaphoreType.DMA((sem_depth,)),
-            ],
-            interpret=True,
-        )(x)
-
-    return jax.make_jaxpr(run)(jnp.zeros((1, h, w), jnp.uint8))
-
-
-def test_bad_dma_start_without_wait_trips_pipe001_only():
-    """A started copy that is never waited on: the consumer races the
-    DMA engine. PIPE001 must flag it; no other rule fires."""
-    jaxpr = _toy_pipelined_jaxpr(wait=False)
-    vios = analysis.check_dma_pipeline(jaxpr, location="t")
-    assert _rule_ids(vios) == {"PIPE001"}
-    assert "no dma_wait" in vios[0].message
-    # The same kernel with the wait restored is PIPE001-clean.
-    assert analysis.check_dma_pipeline(_toy_pipelined_jaxpr(), location="t") == []
-
-
-def test_bad_single_slot_ring_trips_pipe001():
-    """depth=1 means the compute phase always blocks on the copy it just
-    issued — no overlap, no pipeline. The depth floor is 2."""
-    vios = analysis.check_dma_pipeline(_toy_pipelined_jaxpr(depth=1), location="t")
-    assert _rule_ids(vios) == {"PIPE001"}
-    assert any("depth 1 < 2" in v.message for v in vios)
-
-
-def test_bad_semaphore_ring_mismatch_trips_pipe001():
-    """One semaphore shared by two ring slots: waits cannot pair with
-    starts per slot, so back-to-back copies serialize (or worse)."""
-    vios = analysis.check_dma_pipeline(
-        _toy_pipelined_jaxpr(depth=2, sem_depth=1), location="t"
-    )
-    assert _rule_ids(vios) == {"PIPE001"}
-    assert "1 DMA semaphore(s) for a depth-2 ring" in vios[0].message
-
-
 def test_bad_narrow_accumulation_trips_dtype001_only():
     """A trace that accumulates sobel5 taps in i16 — the ladder proves
     the v2 pairwise bound needs i32, so i16 wraps. The kernel half of
@@ -418,29 +332,33 @@ def test_dtype001_covers_u16_frames(monkeypatch):
     assert _rule_ids(vios) == {"DTYPE001"} and "no proven" in vios[0].message
 
 
-def test_bad_wrong_radius_ring_trips_halo001():
-    """HALO001's ring branch: a manual-DMA kernel whose ring slots are
-    sized for r=1 cannot be feeding an r=2 stencil — probed against the
-    sobel3-pipelined trace under the sobel5 contract. (block_h=14: a
-    height at which the tile-aligned r=1 and r=2 windows differ.)"""
-    x = jnp.zeros((1, 64, 96), jnp.uint8)
-    jaxpr = jax.make_jaxpr(
-        lambda a: ekern.edge_pallas(
-            a, operator="sobel3", block_h=14, block_w=32, pipeline_depth=2,
+def test_bad_launch_without_window_trips_halo001():
+    """A stencil launch whose input is a plain block, with no halo'd
+    ``pl.Element`` window: it cannot be reading its halo, and HALO001
+    says so rather than passing a launch it has nothing to probe on."""
+    h, w, bh, bw = 64, 96, 16, 32
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...].astype(jnp.float32)
+
+    def bad(x):
+        block = pl.BlockSpec((1, bh, bw), lambda i, k, j: (i, k, j))
+        return pl.pallas_call(
+            kernel,
+            grid=(1, h // bh, w // bw),
+            in_specs=[block],
+            out_specs=block,
+            out_shape=jax.ShapeDtypeStruct((1, h, w), jnp.float32),
             interpret=True,
-        )
-    )(x)
+        )(x)
+
+    jaxpr = jax.make_jaxpr(bad)(jnp.zeros((1, h, w), jnp.uint8))
     vios = analysis.check_halo_window(
         jaxpr, location="t", spec=get_operator("sobel5"), nms=False,
-        block_h=14, block_w=32, image_hw=(64, 96),
+        block_h=bh, block_w=bw, image_hw=(h, w),
     )
     assert _rule_ids(vios) == {"HALO001"}
-    assert "DMA ring slot tile" in vios[0].message
-    # ...and under its own (sobel3) contract the same trace is clean.
-    assert analysis.check_halo_window(
-        jaxpr, location="t", spec=get_operator("sobel3"), nms=False,
-        block_h=14, block_w=32, image_hw=(64, 96),
-    ) == []
+    assert "no halo'd Element input window" in vios[0].message
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,8 @@
-"""Exact integer lane + DMA-pipelined megakernel: bit-exactness battery.
+"""Exact integer lane of the megakernel: bit-exactness battery.
 
-The PR 9 contract is *exactness by construction*: the integer lane (u8
-taps accumulated in the i16/i32 dtype the ladder licenses, f32 only at
-normalize/NMS) and the double/triple-buffered manual-DMA schedule must
-both be bit-identical to the f32 unpipelined kernel — every output
+The contract is *exactness by construction*: the integer lane (u8 taps
+accumulated in the i16/i32 dtype the ladder licenses, f32 only at
+normalize/NMS) must be bit-identical to the f32 lane — every output
 field, every operator, every padding, on ragged shapes, on both
 backends, and under an 8-device mesh (subprocess case, mirrored from
 test_halo_sharding; the CI multi-device job runs it directly).
@@ -76,22 +75,6 @@ def test_int_lane_batched_and_ragged_shapes():
 
 
 # ---------------------------------------------------------------------------
-# DMA-pipelined schedule == unpipelined schedule, bit for bit
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("precision", ["f32", "int"])
-def test_pipelined_bit_exact(precision):
-    img = _gray(37, 29, seed=2)
-    for padding in PADDINGS:
-        base = EdgeConfig(backend="pallas-interpret", padding=padding,
-                          precision=precision, nms=True, **FULL)
-        ref = edge_detect(img, base)
-        for depth in (2, 3):
-            out = edge_detect(img, base.replace(pipeline_depth=depth))
-            _assert_bit_identical(out, ref, (precision, padding, depth))
-
-
-# ---------------------------------------------------------------------------
 # Lane resolution + validation
 # ---------------------------------------------------------------------------
 
@@ -131,10 +114,6 @@ def test_explicit_int_rejected_when_unprovable():
 
 def test_config_validation():
     # EdgeConfig validates in resolved() (the dispatch entry), not __init__
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        EdgeConfig(pipeline_depth=1).resolved()
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        EdgeConfig(pipeline_depth=9).resolved()
     with pytest.raises(ValueError, match="precision"):
         EdgeConfig(precision="fp8").resolved()
 
@@ -177,7 +156,7 @@ def assert_same(out, ref, what):
 full = dict(nms=True, with_max=True, with_components=True,
             with_orientation=True)
 
-# 1) Integer lane under shard_map — batch and 2-D spatial meshes — vs the
+# Integer lane under shard_map — batch and 2-D spatial meshes — vs the
 #    single-device f32 fused reference: same bits, every operator.
 for op in list_operators():
     ref = edge_detect(x, EdgeConfig(operator=op, backend="pallas-interpret",
@@ -191,19 +170,6 @@ for op in list_operators():
                                         precision="int", shard=shard, **full))
         assert_same(out, ref, (op, backend, shard))
 print("INT_SHARDED_OK")
-
-# 2) The DMA-pipelined integer kernel inside each shard of a spatial mesh:
-#    the per-device ring runs over the halo'd local block, so depth must
-#    not perturb a single bit either.
-ref = edge_detect(x, EdgeConfig(backend="pallas-interpret", precision="int",
-                                **full))
-for depth in (2, 3):
-    out = edge_detect(x, EdgeConfig(backend="pallas-interpret",
-                                    precision="int", pipeline_depth=depth,
-                                    shard=ShardConfig(data=2, rows=2, cols=2),
-                                    **full))
-    assert_same(out, ref, ("pipelined", depth))
-print("PIPELINED_SHARDED_OK")
 """
 
 
@@ -211,5 +177,4 @@ print("PIPELINED_SHARDED_OK")
 @slow_host
 def test_int_lane_sharded_bit_exact_8_devices():
     out = _run(INT_SHARDED)
-    for marker in ("INT_SHARDED_OK", "PIPELINED_SHARDED_OK"):
-        assert marker in out, out
+    assert "INT_SHARDED_OK" in out, out

@@ -47,7 +47,7 @@ def test_builtin_plan_registry():
     assert F.resolve_plan(None) is None
     assert F.resolve_plan("canny5") is canny
     assert F.resolve_plan(canny) is canny
-    # identity = name + stage-signature hash (the TuneKey v6 segment)
+    # identity = name + stage-signature hash (the TuneKey plan segment)
     ident = F.plan_identity(canny)
     assert ident.startswith("canny5.") and len(ident.split(".")[1]) == 8
     assert ident != F.plan_identity(blur)
@@ -265,13 +265,13 @@ def test_plan_autotune_lands_in_plan_slot(tmp_path):
     from repro.kernels import tuning
 
     cache = tuning.TuningCache(str(tmp_path / "blocks.json"))
-    bh, bw, depth = tuning.autotune(32, 48, plan="canny5", shapes=[(8, 16)],
-                                    iters=1, cache=cache, save=False)
+    bh, bw = tuning.autotune(32, 48, plan="canny5", shapes=[(8, 16)],
+                             iters=1, cache=cache, save=False)
     assert (bh, bw) == (8, 16)
     key = tuning.TuneKey(
         "pallas-interpret", "float32", "sobel5", "v2", 32, 48,
         plan=F.plan_identity(F.get_plan("canny5")))
-    assert cache.lookup(key) == (8, 16, depth)
+    assert cache.lookup(key) == (8, 16)
     # the single-operator slot is untouched
     assert cache.lookup(tuning.TuneKey(
         "pallas-interpret", "float32", "sobel5", "v2", 32, 48)) is None

@@ -39,6 +39,7 @@ from repro.api import (
     edge_detect,
     edge_detect_stream,
 )
+from repro.core.filters import list_operators
 from repro.kernels import dispatch
 from repro.runtime.chaos import FaultPlan, Straggler
 from repro.serve import GuardPolicy, StreamEngine, StreamRequest
@@ -134,8 +135,13 @@ class TestStatelessEquivalence:
             res, state = edge_detect_stream(f, cfg, state)
             _assert_same(res, edge_detect(f, ref_cfg))
 
-    def test_plain_stream_matches_plain_detect(self):
-        cfg = EdgeConfig(backend="xla")
+    @pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+    @pytest.mark.parametrize("operator", list_operators())
+    @pytest.mark.parametrize("nms", [False, True])
+    def test_plain_stream_matches_plain_detect(self, backend, operator, nms):
+        """The stream kernel computes a tile through the same tile math as
+        the image kernel, for every operator, with and without NMS."""
+        cfg = EdgeConfig(backend=backend, operator=operator, nms=nms)
         f = _frame(seed=3)
         res, _ = edge_detect_stream(f, cfg)
         _assert_same(res, edge_detect(f, cfg))

@@ -68,14 +68,12 @@ def _compile(topo, fn, *shapes):
         (HD, jnp.uint8, dict(out_nms=True, with_max=True)),
         # exact integer lane (u8 -> i16/i32 widening)
         (HD, jnp.uint8, dict(precision="int", with_max=True)),
-        # manual HBM->VMEM DMA ring
-        (HD, jnp.uint8, dict(pipeline_depth=2, with_max=True)),
         # fused multi-stage plan at 1080p (ragged row blocks)
         (P1080, jnp.uint8, dict(plan="canny5", out_nms=True, with_max=True)),
         # interleaved RGB u8 at 1080p (planar windows + in-kernel luma)
         (P1080 + (3,), jnp.uint8, dict(rgb=True, with_max=True)),
     ],
-    ids=["sobel_hd_f32_mag_max", "nms_u8", "int_lane_u8", "dma_ring_d2",
+    ids=["sobel_hd_f32_mag_max", "nms_u8", "int_lane_u8",
          "canny5_1080p", "rgb_u8_1080p"],
 )
 def test_edge_pallas_compiles_for_v5e(topo, shape, dtype, kw):

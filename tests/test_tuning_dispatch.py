@@ -92,7 +92,7 @@ def test_save_merges_concurrent_writers(tmp_path):
     merged = tuning.TuningCache(path)
     assert len(merged) == n
     for i in range(n):
-        assert merged.lookup(_key(i)) == (8, 32, 0)
+        assert merged.lookup(_key(i)) == (8, 32)
     with open(path) as f:
         assert json.load(f)["__meta__"]["version"] == tuning.TuningCache.VERSION
 
@@ -107,7 +107,7 @@ def test_save_merge_keeps_faster_tuning(tmp_path):
     fast.record(_key(0), 8, 32, us=50.0)
     slow.save()
     fast.save()
-    assert tuning.TuningCache(path).lookup(_key(0)) == (8, 32, 0)
+    assert tuning.TuningCache(path).lookup(_key(0)) == (8, 32)
 
     path2 = str(tmp_path / "blocks2.json")
     slow = tuning.TuningCache(path2)
@@ -116,9 +116,9 @@ def test_save_merge_keeps_faster_tuning(tmp_path):
     fast.record(_key(0), 8, 32, us=50.0)
     fast.save()
     slow.save()                     # slower result arrives second: ignored
-    assert tuning.TuningCache(path2).lookup(_key(0)) == (8, 32, 0)
+    assert tuning.TuningCache(path2).lookup(_key(0)) == (8, 32)
     # and the losing saver's in-memory view was refreshed with the winner
-    assert slow.lookup(_key(0)) == (8, 32, 0)
+    assert slow.lookup(_key(0)) == (8, 32)
 
 
 # ---------------------------------------------------------------------------
@@ -131,24 +131,23 @@ def test_autotune_cache_roundtrip(tmp_path, rng):
     path = str(tmp_path / "blocks.json")
     cache = tuning.TuningCache(path)
     shapes = [(8, 16), (16, 16)]
-    bh, bw, depth = tuning.autotune(32, 48, shapes=shapes, iters=1, cache=cache)
+    bh, bw = tuning.autotune(32, 48, shapes=shapes, iters=1, cache=cache)
     assert (bh, bw) in shapes
-    assert depth in (0, 2)          # auto sweep tries the manual d=2 ring too
 
     # The JSON on disk round-trips through a fresh cache object.
     raw = json.load(open(path))
-    assert any(k.endswith("/32x48/1/1x1x1/f32/0/-")
+    assert any(k.endswith("/32x48/1/1x1x1/f32/-")
                for k in raw if not k.startswith("__"))
     reloaded = tuning.TuningCache(path)
     key = tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 32, 48)
-    assert reloaded.lookup(key) == (bh, bw, depth)
+    assert reloaded.lookup(key) == (bh, bw)
 
     # A second autotune is a pure cache hit (no sweep: empty shape list ok).
-    assert tuning.autotune(32, 48, shapes=[], iters=1, cache=reloaded) == (bh, bw, depth)
+    assert tuning.autotune(32, 48, shapes=[], iters=1, cache=reloaded) == (bh, bw)
 
     # Dispatch consults the cache...
     got = dispatch.choose_block_shape(32, 48, backend="pallas-interpret", cache=reloaded)
-    assert got == (bh, bw, depth, "tuned")
+    assert got == (bh, bw, "tuned")
     # ...and produces the reference output with the tuned shape.
     img = _img(rng, (1, 32, 48))
     out = _dsobel(img, backend="pallas-interpret", tuning_cache=reloaded)
@@ -158,28 +157,28 @@ def test_autotune_cache_roundtrip(tmp_path, rng):
 def test_choose_block_shape_priority(tmp_path):
     cache = tuning.TuningCache(str(tmp_path / "c.json"))
     # no entry -> default
-    bh, bw, depth, src = dispatch.choose_block_shape(64, 512, backend="pallas-interpret", cache=cache)
-    assert src == "default" and bh and bw and depth == 0
-    # cached entry -> tuned (the tuned DMA depth rides along)
-    cache.record(tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 64, 512), 16, 32, 1.0, depth=2)
+    bh, bw, src = dispatch.choose_block_shape(64, 512, backend="pallas-interpret", cache=cache)
+    assert src == "default" and bh and bw
+    # cached entry -> tuned
+    cache.record(tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 64, 512), 16, 32, 1.0)
     assert dispatch.choose_block_shape(
         64, 512, backend="pallas-interpret", cache=cache
-    ) == (16, 32, 2, "tuned")
-    # an explicit pipeline_depth keys its own tuning slot: it does not see
-    # the depth-0 entry, and once tuned it returns the pinned depth
-    bh3, bw3, d3, src3 = dispatch.choose_block_shape(
-        64, 512, backend="pallas-interpret", cache=cache, pipeline_depth=3)
-    assert (d3, src3) == (3, "default")
+    ) == (16, 32, "tuned")
+    # the resolved lane keys its own tuning slot: it does not see the f32
+    # entry, and once tuned it returns its own blocks
+    assert dispatch.choose_block_shape(
+        64, 512, backend="pallas-interpret", cache=cache, precision="int"
+    )[2] == "default"
     cache.record(
         tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 64, 512,
-                       depth=3), 8, 64, 1.0, depth=3)
+                       precision="int"), 8, 64, 1.0)
     assert dispatch.choose_block_shape(
-        64, 512, backend="pallas-interpret", cache=cache, pipeline_depth=3
-    ) == (8, 64, 3, "tuned")
+        64, 512, backend="pallas-interpret", cache=cache, precision="int"
+    ) == (8, 64, "tuned")
     # explicit args always win
     assert dispatch.choose_block_shape(
         64, 512, backend="pallas-interpret", cache=cache, block_h=8, block_w=8
-    ) == (8, 8, 0, "explicit")
+    ) == (8, 8, "explicit")
 
 
 def test_cache_ignores_corrupt_file(tmp_path):
@@ -196,25 +195,25 @@ def _cur_payload(**entries):
     return payload
 
 
-_CUR_KEY = "pallas-interpret/float32/sobel5/v2/reflect/gray/64x64/1/1x1x1/f32/0/-"
+_CUR_KEY = "pallas-interpret/float32/sobel5/v2/reflect/gray/64x64/1/1x1x1/f32/-"
 
 
 def test_cache_from_the_future_skips_and_warns(tmp_path):
-    """A v6 file (newer deployment, shared cache path) must not raise — and
+    """A newer schema's file (newer deployment, shared cache path) must not raise — and
     must not be misread either: its entries are dropped with a warning, and
     dispatch falls back to the default block shape."""
-    path = tmp_path / "v6.json"
+    path = tmp_path / "future.json"
     path.write_text(json.dumps({
         "__meta__": {"version": tuning.TuningCache.VERSION + 1},
         # plausible future key layout + value schema drift
-        "pallas-tpu/float32/sobel5/v2/reflect/gray/64x64/1/1x1x1/f32/0/extra":
+        "pallas-tpu/float32/sobel5/v2/reflect/gray/64x64/1/1x1x1/f32/-/extra":
             {"block": [32, 128], "us": 1.0},
         _CUR_KEY: {"block_h": 8, "block_w": 32, "us": 1.0},
     }))
-    with pytest.warns(RuntimeWarning, match="newer than supported"):
+    with pytest.warns(RuntimeWarning, match="is not the supported"):
         cache = tuning.TuningCache(str(path))
     assert len(cache) == 0
-    bh, bw, _depth, src = dispatch.choose_block_shape(
+    bh, bw, src = dispatch.choose_block_shape(
         64, 64, backend="pallas-interpret", cache=cache
     )
     assert src == "default" and bh > 0 and bw > 0
@@ -239,11 +238,11 @@ def test_cache_corrupted_entries_skipped_individually(tmp_path):
     the healthy ones."""
     good_key = _CUR_KEY
     bad_keys = {
-        "pallas-interpret/float32/sobel5/v2/reflect/gray/32x32/1/1x1x1/f32/0/-":
+        "pallas-interpret/float32/sobel5/v2/reflect/gray/32x32/1/1x1x1/f32/-":
             {"block": "8x32"},                      # missing block_h/block_w
-        "pallas-interpret/float32/sobel5/v2/reflect/gray/16x16/1/1x1x1/f32/0/-":
+        "pallas-interpret/float32/sobel5/v2/reflect/gray/16x16/1/1x1x1/f32/-":
             {"block_h": "eight", "block_w": 32},    # non-numeric
-        "pallas-interpret/float32/sobel5/v2/reflect/gray/8x8/1/1x1x1/f32/0/-":
+        "pallas-interpret/float32/sobel5/v2/reflect/gray/8x8/1/1x1x1/f32/-":
             [8, 32],                                # not a dict
     }
     path = tmp_path / "mixed.json"
@@ -253,7 +252,7 @@ def test_cache_corrupted_entries_skipped_individually(tmp_path):
         cache = tuning.TuningCache(str(path))
     assert len(cache) == 1
     assert cache.lookup(tuning.TuneKey(
-        "pallas-interpret", "float32", "sobel5", "v2", 64, 64)) == (8, 32, 0)
+        "pallas-interpret", "float32", "sobel5", "v2", 64, 64)) == (8, 32)
 
 
 def test_cache_non_object_payload_skips_and_warns(tmp_path):
@@ -264,189 +263,44 @@ def test_cache_non_object_payload_skips_and_warns(tmp_path):
     assert len(cache) == 0
 
 
-def test_cache_v1_migration(tmp_path):
-    """v1 cache files (no padding/layout key segments) must migrate through
-    the chain to the reflect/gray single-device slot of the current key
-    space and be rewritten as the current schema on save."""
-    path = tmp_path / "v1.json"
-    v1_key = "pallas-interpret/float32/5x5/v2/64x512"
-    path.write_text(json.dumps({
-        "__meta__": {"version": 1},
-        v1_key: {"block_h": 16, "block_w": 128, "us": 12.5},
-        "garbage-key": {"block_h": 1, "block_w": 1, "us": 1.0},
-    }))
-    cache = tuning.TuningCache(str(path))
-    # v1 tunings land in the reflect/gray single-device slot...
-    key = tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 64, 512)
-    assert key.padding == "reflect" and key.layout == "gray"
-    assert key.devices == 1 and key.mesh == "1x1x1"
-    assert key.precision == "f32" and key.depth == 0
-    assert cache.lookup(key) == (16, 128, 0)
-    # ...and do NOT shadow other padding/layout slots.
-    assert cache.lookup(
-        tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 64, 512,
-                       padding="zero", layout="rgb")
-    ) is None
-    # Unrecognizable keys are dropped, not corrupted into the new space.
-    assert len(cache) == 1
-    cache.save()
-    raw = json.load(open(path))
-    assert raw["__meta__"]["version"] == tuning.TuningCache.VERSION == 6
-    assert ("pallas-interpret/float32/sobel5/v2/reflect/gray/64x512/1/1x1x1/f32/0/-"
-            in raw)
+# One file of each schema before this one, each holding a tuning of the
+# same workload in that schema's key layout (v1 files were written with no
+# ``__meta__`` at first, then with ``{"version": 1}``).
+_SLOT = "pallas-interpret/float32/sobel5/v2/reflect/gray/64x512"
+_OLD_SCHEMA_FILES = {
+    "no-meta": (None, "pallas-interpret/float32/5x5/v2/64x512"),
+    "v1": (1, "pallas-interpret/float32/5x5/v2/64x512"),
+    "v2": (2, "pallas-interpret/float32/5x5/v2/reflect/gray/64x512"),
+    "v3": (3, _SLOT),
+    "v4": (4, _SLOT + "/1/1x1x1"),
+    "v5": (5, _SLOT + "/1/1x1x1/f32/0"),
+    "v6": (6, _SLOT + "/1/1x1x1/f32/0/-"),
+}
 
 
-def test_cache_v1_files_without_meta(tmp_path):
-    """Pre-versioning files (no __meta__ at all) are treated as v1."""
-    path = tmp_path / "old.json"
-    path.write_text(json.dumps(
-        {"pallas-tpu/uint8/3x3/separable/1024x2048": {"block_h": 32, "block_w": 256, "us": 3.0}}
-    ))
-    cache = tuning.TuningCache(str(path))
-    assert cache.lookup(
-        tuning.TuneKey("pallas-tpu", "uint8", "sobel3", "separable", 1024, 2048)
-    ) == (32, 256, 0)
-
-
-def test_cache_v2_to_v3_migration(tmp_path, rng):
-    """A v2 JSON cache on disk loads cleanly, old entries resolve for
-    operator="sobel5" (the SxS size segment maps onto the Sobel operator of
-    that size), dispatch consults them, and re-save writes the current
-    schema."""
-    path = tmp_path / "v2.json"
-    path.write_text(json.dumps({
-        "__meta__": {"version": 2},
-        "pallas-interpret/float32/5x5/v2/reflect/gray/32x48":
-            {"block_h": 16, "block_w": 16, "us": 10.0},
-        "pallas-tpu/uint8/3x3/separable/zero/rgb/1024x2048":
-            {"block_h": 32, "block_w": 256, "us": 3.0},
-        "pallas-tpu/uint8/9x9/separable/zero/rgb/1024x2048":  # no such operator
-            {"block_h": 8, "block_w": 128, "us": 9.0},
-    }))
-    cache = tuning.TuningCache(str(path))
-    # Old entries resolve under the operator-named keys...
-    assert cache.lookup(
-        tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 32, 48)
-    ) == (16, 16, 0)
-    assert cache.lookup(
-        tuning.TuneKey("pallas-tpu", "uint8", "sobel3", "separable", 1024, 2048,
-                       padding="zero", layout="rgb")
-    ) == (32, 256, 0)
-    # ...unmappable sizes are dropped, and no non-Sobel operator is shadowed.
-    assert len(cache) == 2
-    assert cache.lookup(
-        tuning.TuneKey("pallas-interpret", "float32", "scharr3", "separable", 32, 48)
-    ) is None
-    # Dispatch consults the migrated entry end to end.
-    got = dispatch.choose_block_shape(32, 48, backend="pallas-interpret", cache=cache)
-    assert got == (16, 16, 0, "tuned")
-    img = _img(rng, (1, 32, 48))
-    out = _dsobel(img, backend="pallas-interpret", tuning_cache=cache)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(core_sobel(img)))
-    # Re-save writes the current schema.
-    cache.save()
-    raw = json.load(open(path))
-    assert raw["__meta__"]["version"] == 6
-    assert ("pallas-interpret/float32/sobel5/v2/reflect/gray/32x48/1/1x1x1/f32/0/-"
-            in raw)
-    assert not any("5x5" in k for k in raw if not k.startswith("__"))
-
-
-def test_cache_v3_to_v4_migration(tmp_path):
-    """v3 files (operator-named, no device/mesh segments) land in the
-    single-device ``1/1x1x1`` slot of the current key space — and do not
-    shadow sharded slots for the same workload."""
-    path = tmp_path / "v3.json"
-    path.write_text(json.dumps({
-        "__meta__": {"version": 3},
-        "pallas-interpret/float32/scharr3/separable/edge/rgb/720x1280":
-            {"block_h": 16, "block_w": 64, "us": 7.0},
-        "not/enough/segments": {"block_h": 1, "block_w": 1, "us": 1.0},
-    }))
-    cache = tuning.TuningCache(str(path))
-    base = dict(backend="pallas-interpret", dtype="float32", operator="scharr3",
-                variant="separable", h=720, w=1280, padding="edge", layout="rgb")
-    assert cache.lookup(tuning.TuneKey(**base)) == (16, 64, 0)
-    assert cache.lookup(
-        tuning.TuneKey(**base, devices=8, mesh="2x2x2")
-    ) is None
-    assert len(cache) == 1
-    cache.save()
-    raw = json.load(open(path))
-    assert raw["__meta__"]["version"] == 6
-    assert ("pallas-interpret/float32/scharr3/separable/edge/rgb/720x1280/1/1x1x1/f32/0/-"
-            in raw)
-
-
-def test_cache_v4_to_v5_migration(tmp_path):
-    """v4 files (no precision/depth segments) land in the ``f32/0`` slot of
-    the v5 key space with depth 0 — and do not shadow the integer-lane or
-    manual-DMA-depth slots for the same workload."""
-    path = tmp_path / "v4.json"
-    path.write_text(json.dumps({
-        "__meta__": {"version": 4},
-        "pallas-interpret/uint8/sobel5/v2/reflect/gray/720x1280/1/1x1x1":
-            {"block_h": 16, "block_w": 64, "us": 7.0},
-        "pallas-tpu/float32/sobel7/v1/edge/rgb/512x640/4/1x2x2":
-            {"block_h": 32, "block_w": 128, "us": 3.0},
-        "not/enough/segments": {"block_h": 1, "block_w": 1, "us": 1.0},
-    }))
-    cache = tuning.TuningCache(str(path))
-    base = dict(backend="pallas-interpret", dtype="uint8", operator="sobel5",
-                variant="v2", h=720, w=1280)
-    assert cache.lookup(tuning.TuneKey(**base)) == (16, 64, 0)
-    assert cache.lookup(
-        tuning.TuneKey("pallas-tpu", "float32", "sobel7", "v1", 512, 640,
-                       padding="edge", layout="rgb", devices=4, mesh="1x2x2")
-    ) == (32, 128, 0)
-    # Pre-v5 tunings never claim int-lane or pinned-depth slots.
-    assert cache.lookup(tuning.TuneKey(**base, precision="int")) is None
-    assert cache.lookup(tuning.TuneKey(**base, depth=2)) is None
-    assert len(cache) == 2
-    cache.save()
-    raw = json.load(open(path))
-    assert raw["__meta__"]["version"] == 6
-    assert ("pallas-interpret/uint8/sobel5/v2/reflect/gray/720x1280/1/1x1x1/f32/0/-"
-            in raw)
-
-
-def test_cache_v5_to_v6_migration(tmp_path):
-    """v5 files (no plan segment) land in the single-operator ``-`` plan
-    slot of the v6 key space — and do not shadow plan-identified slots for
-    the same workload."""
-    path = tmp_path / "v5.json"
-    path.write_text(json.dumps({
-        "__meta__": {"version": 5},
-        "pallas-interpret/uint8/sobel5/v2/reflect/gray/720x1280/1/1x1x1/int/2":
-            {"block_h": 16, "block_w": 64, "depth": 2, "us": 7.0},
-        "pallas-tpu/float32/sobel5/v2/reflect/gray/1024x1024/4/1x2x2/f32/0":
-            {"block_h": 32, "block_w": 128, "us": 3.0},
-        "not/enough/segments": {"block_h": 1, "block_w": 1, "us": 1.0},
-    }))
-    cache = tuning.TuningCache(str(path))
-    base = dict(backend="pallas-interpret", dtype="uint8", operator="sobel5",
-                variant="v2", h=720, w=1280, precision="int", depth=2)
-    assert cache.lookup(tuning.TuneKey(**base)) == (16, 64, 2)
-    assert cache.lookup(
-        tuning.TuneKey("pallas-tpu", "float32", "sobel5", "v2", 1024, 1024,
-                       devices=4, mesh="1x2x2")
-    ) == (32, 128, 0)
-    # Pre-v6 tunings never claim plan-identified slots: a fused-plan kernel
-    # has a different inner loop, so its block tuning must re-measure.
-    from repro.core.filters import get_plan, plan_identity
-
-    plan_seg = plan_identity(get_plan("canny5"))
-    assert cache.lookup(tuning.TuneKey(**base, plan=plan_seg)) is None
-    assert len(cache) == 2
-    cache.save()
-    raw = json.load(open(path))
-    assert raw["__meta__"]["version"] == 6
-    assert ("pallas-interpret/uint8/sobel5/v2/reflect/gray/720x1280/1/1x1x1/int/2/-"
-            in raw)
+@pytest.mark.parametrize("schema", sorted(_OLD_SCHEMA_FILES))
+def test_cache_of_another_schema_skips_and_warns(tmp_path, schema):
+    """A file of an older schema is not migrated: its entries are dropped
+    with a warning (their key layout is not this one's), the workload's
+    lookup misses, and dispatch falls back to the default block shape."""
+    version, key = _OLD_SCHEMA_FILES[schema]
+    payload = {key: {"block_h": 16, "block_w": 128, "us": 12.5}}
+    if version is not None:
+        payload["__meta__"] = {"version": version}
+    path = tmp_path / f"{schema}.json"
+    path.write_text(json.dumps(payload))
+    with pytest.warns(RuntimeWarning, match="is not the supported 7"):
+        cache = tuning.TuningCache(str(path))
+    assert len(cache) == 0
+    assert cache.lookup(tuning.TuneKey(
+        "pallas-interpret", "float32", "sobel5", "v2", 64, 512)) is None
+    assert dispatch.choose_block_shape(
+        64, 512, backend="pallas-interpret", cache=cache
+    )[2] == "default"
 
 
 def test_key_distinguishes_plan(tmp_path):
-    """Schema v6: the same gradient operator tuned standalone vs inside a
+    """The same gradient operator tuned standalone vs inside a
     fused plan — slots must not collide, and two plans sharing a gradient
     stage keep separate slots (the plan identity hashes the full stage
     sequence, not just the name)."""
@@ -459,11 +313,11 @@ def test_key_distinguishes_plan(tmp_path):
     blur = plan_identity(get_plan("blur_sobel5"))
     assert canny != blur and canny.startswith("canny5.")
     cache.record(tuning.TuneKey(**base), 8, 32, 1.0)
-    cache.record(tuning.TuneKey(**base, plan=canny), 16, 64, 2.0, depth=2)
+    cache.record(tuning.TuneKey(**base, plan=canny), 16, 64, 2.0)
     cache.record(tuning.TuneKey(**base, plan=blur), 32, 128, 3.0)
-    assert cache.lookup(tuning.TuneKey(**base)) == (8, 32, 0)
-    assert cache.lookup(tuning.TuneKey(**base, plan=canny)) == (16, 64, 2)
-    assert cache.lookup(tuning.TuneKey(**base, plan=blur)) == (32, 128, 0)
+    assert cache.lookup(tuning.TuneKey(**base)) == (8, 32)
+    assert cache.lookup(tuning.TuneKey(**base, plan=canny)) == (16, 64)
+    assert cache.lookup(tuning.TuneKey(**base, plan=blur)) == (32, 128)
     # a re-registered plan with different stages gets a different identity
     variant_plan = make_plan("canny5x", ("gaussian3", "sobel5", "nms"))
     assert plan_identity(variant_plan) != canny
@@ -471,25 +325,22 @@ def test_key_distinguishes_plan(tmp_path):
         tuning.TuneKey(**base, plan=plan_identity(variant_plan))) is None
 
 
-def test_key_distinguishes_precision_and_depth(tmp_path):
-    """Schema v5: the same workload tuned per arithmetic lane and per DMA
-    ring depth — slots must not collide, and the recorded depth rides the
-    value back out of lookup."""
+def test_key_distinguishes_precision(tmp_path):
+    """The same workload tuned per arithmetic lane — slots must not
+    collide."""
     cache = tuning.TuningCache(str(tmp_path / "c.json"))
     base = dict(backend="pallas-interpret", dtype="uint8", operator="sobel5",
                 variant="v2", h=128, w=256)
     cache.record(tuning.TuneKey(**base), 8, 32, 1.0)
-    cache.record(tuning.TuneKey(**base, precision="int"), 16, 64, 2.0, depth=2)
-    cache.record(tuning.TuneKey(**base, depth=4), 32, 128, 3.0, depth=4)
-    assert cache.lookup(tuning.TuneKey(**base)) == (8, 32, 0)
-    assert cache.lookup(tuning.TuneKey(**base, precision="int")) == (16, 64, 2)
-    assert cache.lookup(tuning.TuneKey(**base, depth=4)) == (32, 128, 4)
-    assert cache.lookup(tuning.TuneKey(**base, precision="int", depth=4)) is None
+    assert cache.lookup(tuning.TuneKey(**base, precision="int")) is None
+    cache.record(tuning.TuneKey(**base, precision="int"), 16, 64, 2.0)
+    assert cache.lookup(tuning.TuneKey(**base)) == (8, 32)
+    assert cache.lookup(tuning.TuneKey(**base, precision="int")) == (16, 64)
 
 
 def test_key_distinguishes_mesh(tmp_path):
     """Same workload, different device count / mesh shape -> different
-    tuning slots (schema v4: under spatial sharding the kernel tiles the
+    tuning slots (under spatial sharding the kernel tiles the
     halo-extended local block, so a 1x2x2 tuning must not collide with the
     single-device entry), and dispatch passes the mesh through."""
     cache = tuning.TuningCache(str(tmp_path / "c.json"))
@@ -498,25 +349,25 @@ def test_key_distinguishes_mesh(tmp_path):
     cache.record(tuning.TuneKey(**base), 8, 32, 1.0)
     cache.record(tuning.TuneKey(**base, devices=4, mesh="1x2x2"), 16, 64, 2.0)
     cache.record(tuning.TuneKey(**base, devices=4, mesh="4x1x1"), 32, 128, 3.0)
-    assert cache.lookup(tuning.TuneKey(**base)) == (8, 32, 0)
-    assert cache.lookup(tuning.TuneKey(**base, devices=4, mesh="1x2x2")) == (16, 64, 0)
-    assert cache.lookup(tuning.TuneKey(**base, devices=4, mesh="4x1x1")) == (32, 128, 0)
+    assert cache.lookup(tuning.TuneKey(**base)) == (8, 32)
+    assert cache.lookup(tuning.TuneKey(**base, devices=4, mesh="1x2x2")) == (16, 64)
+    assert cache.lookup(tuning.TuneKey(**base, devices=4, mesh="4x1x1")) == (32, 128)
     assert cache.lookup(tuning.TuneKey(**base, devices=8, mesh="2x2x2")) is None
     # choose_block_shape consults the mesh-specific slot...
     got = dispatch.choose_block_shape(
         128, 256, backend="pallas-interpret", cache=cache,
         devices=4, mesh="1x2x2",
     )
-    assert got == (16, 64, 0, "tuned")
+    assert got == (16, 64, "tuned")
     # ...and autotune records into it.
-    bh, bw, depth = tuning.autotune(24, 32, shapes=[(8, 16)], iters=1,
+    bh, bw = tuning.autotune(24, 32, shapes=[(8, 16)], iters=1,
                                     cache=cache, save=False,
                                     devices=4, mesh="1x2x2")
     assert (bh, bw) == (8, 16)
     assert cache.lookup(
         tuning.TuneKey("pallas-interpret", "float32", "sobel5", "v2", 24, 32,
                        devices=4, mesh="1x2x2")
-    ) == (8, 16, depth)
+    ) == (8, 16)
 
 
 def test_key_distinguishes_padding_and_layout(tmp_path):
@@ -525,8 +376,8 @@ def test_key_distinguishes_padding_and_layout(tmp_path):
                 variant="v2", h=128, w=256)
     cache.record(tuning.TuneKey(**base, padding="reflect", layout="gray"), 8, 32, 1.0)
     cache.record(tuning.TuneKey(**base, padding="zero", layout="rgb"), 16, 64, 2.0)
-    assert cache.lookup(tuning.TuneKey(**base, padding="reflect", layout="gray")) == (8, 32, 0)
-    assert cache.lookup(tuning.TuneKey(**base, padding="zero", layout="rgb")) == (16, 64, 0)
+    assert cache.lookup(tuning.TuneKey(**base, padding="reflect", layout="gray")) == (8, 32)
+    assert cache.lookup(tuning.TuneKey(**base, padding="zero", layout="rgb")) == (16, 64)
     assert cache.lookup(tuning.TuneKey(**base, padding="edge", layout="gray")) is None
 
 
@@ -584,18 +435,18 @@ def test_key_distinguishes_operator(tmp_path):
                 h=128, w=256)
     cache.record(tuning.TuneKey(operator="sobel3", **base), 8, 32, 1.0)
     cache.record(tuning.TuneKey(operator="scharr3", **base), 16, 64, 2.0)
-    assert cache.lookup(tuning.TuneKey(operator="sobel3", **base)) == (8, 32, 0)
-    assert cache.lookup(tuning.TuneKey(operator="scharr3", **base)) == (16, 64, 0)
+    assert cache.lookup(tuning.TuneKey(operator="sobel3", **base)) == (8, 32)
+    assert cache.lookup(tuning.TuneKey(operator="scharr3", **base)) == (16, 64)
     assert cache.lookup(tuning.TuneKey(operator="sobel7", **base)) is None
 
 
 def test_autotune_operator_keyed(tmp_path):
     cache = tuning.TuningCache(str(tmp_path / "blocks.json"))
-    bh, bw, depth = tuning.autotune(24, 32, operator="scharr3", shapes=[(8, 16)],
-                                    iters=1, cache=cache, save=False)
+    bh, bw = tuning.autotune(24, 32, operator="scharr3", shapes=[(8, 16)],
+                             iters=1, cache=cache, save=False)
     assert (bh, bw) == (8, 16)
     key = tuning.TuneKey("pallas-interpret", "float32", "scharr3", "separable", 24, 32)
-    assert cache.lookup(key) == (8, 16, depth)
+    assert cache.lookup(key) == (8, 16)
 
 
 def test_default_block_shape_folds_halo():
